@@ -11,7 +11,7 @@ import glob as globmod
 import json
 import os
 import time
-from typing import Dict, List
+from typing import Any, Dict, List
 
 import numpy as np
 
@@ -56,6 +56,7 @@ def cloud_status(params):
         "build_too_old": False,
         "cloud_name": c.args.name,
         "cloud_size": c.n_nodes,
+        **_backend(c),
         "cloud_uptime_millis": int((time.time() - _START_TIME) * 1000),
         # healthy = stable membership (no reform in flight, no lost
         # devices in the last liveness probe)
@@ -87,11 +88,21 @@ def cloud_status(params):
     }
 
 
+def _backend(c) -> Dict[str, Any]:
+    """What the mesh actually runs on, as JAX reports it."""
+    dev = c.mesh.devices.flat[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": int(c.mesh.devices.size)}
+
+
 @route("GET", r"/3/About")
 def about(params):
+    b = _backend(cloud())
     return {"entries": [
         {"name": "Build project version", "value": __version__},
-        {"name": "Backend", "value": "jax/XLA TPU"},
+        {"name": "Backend",
+         "value": f"jax/XLA {b['platform']} ({b['device_kind']} "
+                  f"x{b['device_count']})"},
     ]}
 
 

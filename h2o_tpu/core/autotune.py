@@ -67,8 +67,11 @@ import numpy as np
 from h2o_tpu.core.exec_store import (SCHEMA_VERSION, backend_fingerprint,
                                      code_fingerprint, store_dir)
 from h2o_tpu.core.lockwitness import make_rlock
+from h2o_tpu.core.log import get_logger
 from h2o_tpu.ops.histogram import (N_STATS, _pallas_eligible,
                                    histogram_build_traced)
+
+log = get_logger("autotune")
 
 _TRUE = ("1", "on", "true", "yes")
 _FALSE = ("0", "off", "false", "no")
@@ -303,10 +306,9 @@ def _store_decision(rec: dict) -> None:
 
 
 def _complete(out):
-    """Host-fetch barrier (bench.py's timing idiom): a tunneled/async
-    PJRT backend can resolve block_until_ready at enqueue time, faking
-    the timing — a device->host scalar fetch cannot complete until the
-    whole dependency chain has executed."""
+    """Host-fetch barrier (bench.py's timing idiom): a device->host
+    scalar fetch cannot complete until the whole dependency chain has
+    executed, so the timed region ends when the work does."""
     leaves = jax.tree_util.tree_leaves(out)
     if leaves:
         float(jnp.sum(leaves[0]))
@@ -442,6 +444,8 @@ def resolve_flag(site: str, bucket=None) -> bool:
         return bool(resolve(site, bucket)["flag"])
     except Exception:  # noqa: BLE001 — degrade, never kill training
         _STATS["resolve_errors"] += 1
+        log.warning("autotune: resolving %s at %s failed; using the "
+                    "reference variant", site, bucket, exc_info=True)
         return lv.reference_flag
 
 

@@ -45,10 +45,11 @@ owning modules, like the chaos flags, so they work before a cloud boots):
   by principals trusted to run code in every process that warms from
   it; the store writes 0o600 files in a 0o700 dir and warns if the
   dir is group/other-writable), and
-  ``H2O_TPU_COMPILE_CACHE`` (XLA persistent compile cache directory /
-  on-off switch, core/cloud.py — the fallback warm-start layer for
-  entries executable serialization cannot cover, e.g. jit-level
-  shape-polymorphic programs and closure map fns);
+  ``H2O_TPU_COMPILE_CACHE`` (XLA persistent compile cache on-off
+  switch, core/cloud.py — the fallback warm-start layer for entries
+  executable serialization cannot cover, e.g. jit-level
+  shape-polymorphic programs and closure map fns; the directory is
+  ``JAX_COMPILATION_CACHE_DIR`` where set, else ``<checkout>/.jax_cache``);
 - buffer donation: ``H2O_TPU_DONATE`` (the store's donation policy;
   default on-TPU-only — donating and non-donating variants are
   distinct store entries and OOM retries auto-route to the

@@ -89,7 +89,7 @@ def _audit_enabled() -> bool:
     return os.environ.get("H2O_TPU_AUDIT", "").strip().lower() \
         in _AUDIT_TRUE
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2     # 2: header carries the execution devices
 _MAGIC = b"H2OEXEC1"
 _DEFAULT_ENTRIES = 256
 
@@ -496,8 +496,13 @@ class ExecStore:
                 self.serialize_unsupported += 1
             log.debug("executable serialization unsupported (%r)", e)
             return
-        header = json.dumps({"schema": SCHEMA_VERSION,
-                             "key": keystr}).encode()
+        # the executable is bound to the devices it was compiled for (one
+        # device for a serve predict, the whole mesh for a sharded
+        # kernel); the loader must hand the same ones back
+        devices = [d.id for d in
+                   compiled._executable._unloaded_executable.device_list]
+        header = json.dumps({"schema": SCHEMA_VERSION, "key": keystr,
+                             "devices": devices}).encode()
         try:
             os.makedirs(store_dir(), mode=0o700, exist_ok=True)
             self._check_dir_trust()
@@ -540,8 +545,12 @@ class ExecStore:
                     header.get("key") != keystr:
                 raise ValueError("schema/key mismatch")
             payload, in_tree, out_tree = pickle.loads(buf.read())
+            import jax
             from jax.experimental import serialize_executable as se
-            fn = se.deserialize_and_load(payload, in_tree, out_tree)
+            by_id = {d.id: d for d in jax.devices()}
+            fn = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in header["devices"]])
         except Exception as e:  # noqa: BLE001 — an unreadable entry is
             # an invalidation: drop it and rebuild fresh
             with self._lock:

@@ -166,9 +166,9 @@ def _pad_rows(arr: jax.Array, n: int, fill) -> jax.Array:
     """Eager device pad of rows to length ``n`` (never touches host).
 
     Spelled as ``jnp.pad``, NOT ``jnp.concatenate([arr, filler])``:
-    concatenating a row-sharded operand with a fresh filler miscompiles
-    on meshes with a model axis (XLA:CPU GSPMD emits a strided/summed
-    mess on jax 0.4.x) — the pad op lowers correctly."""
+    concatenating a row-sharded operand with a fresh filler miscompiled
+    on meshes with a model axis (XLA:CPU GSPMD emitted a strided/summed
+    mess) — the pad op lowers correctly."""
     if arr.shape[0] >= n:
         return arr
     pad_width = [(0, n - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)

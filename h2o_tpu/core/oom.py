@@ -79,10 +79,9 @@ class OOMError(RuntimeError):
             self.site = site
 
 
-# message markers of a Mosaic/Pallas custom-kernel compile failure — the
-# opt-in fused histogram is interpret-mode verified but Mosaic-untested,
-# so a lowering bug must degrade to the portable XLA path, not kill the
-# training job with no fallback (ADVICE.md VMEM-gate follow-up)
+# message markers of a Mosaic/Pallas custom-kernel compile failure — a
+# shape Mosaic refuses must degrade to the portable XLA path, not kill
+# the training job (chip_smoke.py proves the bench shapes do compile)
 _KERNEL_MARKERS = ("Mosaic", "mosaic", "Pallas", "pallas", "VMEM",
                    "custom_call_target", "tpu_custom_call")
 

@@ -156,13 +156,17 @@ def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int):
     """Bin raw values AND pack to the narrowest dtype the fine bin
     count permits — the one binning entry every trainer and scorer
     shares.  The ``tree.bins_dtype`` lever is resolved HERE, outside
-    the jit trace (the packed dtype is part of every downstream
+    ``_bin_all``'s trace (the packed dtype is part of every downstream
     executable's aval signature, so a lever flip selects a different
     executable instead of silently hitting a stale one).  Scoring a
     model under a different lever state than it trained with is safe:
     packed and int32 matrices hold identical integers (ops/binpack.py
     decode contract), so descent and histograms agree bitwise."""
-    packed = bins_pack_enabled(
+    # a TRACED matrix means a caller is compiling its whole predict
+    # around this call (serve/engine.py): the bins are an intermediate
+    # of that program, not an HBM-resident input, and a lever cannot be
+    # probed mid-trace — the int32 reference holds the same integers
+    packed = not isinstance(matrix, jax.core.Tracer) and bins_pack_enabled(
         bins_bucket(matrix.shape[0], matrix.shape[1], fine_nbins))
     return _bin_all(matrix, split_points_dev, jnp.asarray(is_cat),
                     fine_nbins,

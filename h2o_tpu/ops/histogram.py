@@ -68,18 +68,17 @@ def pallas_env_enabled(bucket=None) -> bool:
 
 
 def _pallas_eligible(C: int, B1: int, n_leaves: int, S: int,
-                     fine_map, allowed: bool) -> bool:
+                     fine_map, allowed: bool,
+                     mm_dtype=jnp.float32) -> bool:
     """Static choice of the fused Pallas kernel (ops/hist_pallas.py):
-    TPU backend only (CPU tests keep the portable XLA path), global-grid
-    binning only (the adaptive fine_map fuses map_buckets into the XLA
-    scan body), and the kernel's COMBINED per-tile working set — the
-    one-hot, the (TR, L*S) A-matrix temporary, the leaf-hot, and the
-    accumulator block — must fit VMEM (~12 MiB working-set budget; the
-    original gate left the A temporary unbounded in L, so a wide
-    frontier over few columns could pass and then Mosaic-fail with no
-    fallback — the ADVICE.md VMEM-gate bug).  ``allowed`` is the env
-    OPT-IN and must be resolved OUTSIDE the trace by the caller — it is
-    part of the executable's static signature, never re-read here."""
+    TPU backend only (CPU tests keep the portable XLA path), a matmul
+    dtype (``hist_pallas.matmul_dtype`` of the stats carrier) Mosaic has
+    a matmul for (``hist_pallas.mosaic_supports``: int16 has none), and
+    the kernel's per-tile working set and output window must fit VMEM
+    (``hist_pallas.plan_tile_rows``).
+    ``allowed`` is the env OPT-IN and must be resolved OUTSIDE the trace
+    by the caller — it is part of the executable's static signature,
+    never re-read here."""
     if allowed is None:
         raise TypeError(
             "pallas must be an explicit bool resolved outside the trace "
@@ -91,15 +90,17 @@ def _pallas_eligible(C: int, B1: int, n_leaves: int, S: int,
     from h2o_tpu.core.cloud import backend_is_tpu
     if not backend_is_tpu():
         return False
-    from h2o_tpu.ops.hist_pallas import min_tile_fits
+    from h2o_tpu.ops.hist_pallas import min_tile_fits, mosaic_supports
+    if not mosaic_supports(mm_dtype):
+        return False
     if fine_map is not None:
-        # adaptive kernel streams column groups (width never blocks it),
-        # but the leaf-hot and A tiles still bound the live frontier —
-        # the halving schedule's wide-B levels are exactly the small-L
-        # top levels where it matters most; min_tile_fits at Cg=1 is the
-        # floor the group-shrinking loop can always reach
-        return n_leaves <= 128 and min_tile_fits(1, B1, n_leaves, S)
-    return min_tile_fits(C, B1, n_leaves, S)
+        # adaptive kernel streams column groups of 8 or more (width
+        # never blocks it), but the per-leaf range picks unroll over the
+        # live frontier — the halving schedule's wide-B levels are
+        # exactly the small-L top levels where it matters most
+        return n_leaves <= 128 and min_tile_fits(8, B1, n_leaves, S, C,
+                                                 mm_dtype)
+    return min_tile_fits(C, B1, n_leaves, S, mm_dtype=mm_dtype)
 
 
 def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
@@ -116,9 +117,10 @@ def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
                an integer dot_general with int32 accumulation: both
                operands at the carrier itemsize, the (C*B1, L*S) table
                exact by the statpack qmax row bound
-    mm_dtype:  matmul input dtype (f32 path only); bf16 doubles MXU
-               throughput at the cost of ~3 mantissa digits on the
-               per-row stats (the one-hot side is exact either way).
+    mm_dtype:  matmul input dtype (f32 path only); bf16 is one MXU pass
+               where f32 (HIGHEST) is several, at the cost of ~3
+               mantissa digits on the per-row stats (the one-hot side
+               is exact either way).
     """
     B1 = nbins + 1
     C = bins_blk.shape[1]
@@ -143,10 +145,15 @@ def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
             binhot.astype(stats_blk.dtype), a,
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)                 # (C*B1, L*S)
+    # the one-hot side is exact in any dtype; HIGHEST keeps the f32
+    # stats side f32 — a TPU's default precision rounds f32 operands to
+    # bf16, which is what ``bf16`` asks for and f32 must not get
     return jax.lax.dot_general(
         binhot.astype(mm_dtype), a.astype(mm_dtype),
         dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                   # (C*B1, L*S)
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST
+        if mm_dtype == jnp.float32 else None)                 # (C*B1, L*S)
 
 
 def map_buckets(bins_blk, leaf_blk, lo, hi, off, is_cat, nbins: int,
@@ -220,8 +227,10 @@ def histogram_build_traced(bins, leaf, stats, n_leaves: int, nbins: int,
         extra_specs = (P(), P(), P(), P())
         extra = (lo, hi, off, is_cat_m)
 
+    from h2o_tpu.ops.hist_pallas import matmul_dtype
     use_pallas = _pallas_eligible(C, B1, n_leaves, S, fine_map,
-                                  allowed=pallas)
+                                  allowed=pallas,
+                                  mm_dtype=matmul_dtype(stats.dtype, bf16))
 
     dp = cloud().data_pspec
     @functools.partial(shard_map_compat, mesh=mesh,

@@ -285,6 +285,27 @@ def test_cpu_auto_stays_int32_reference():
     assert at.stats()["probes"] == 0
 
 
+def test_traced_matrix_resolves_no_lever(monkeypatch):
+    """The serve engine compiles a model's whole predict, so bin_matrix
+    sees a tracer there.  A probe cannot run mid-trace (on the chip,
+    where probing is the default, it raised and was swallowed as a
+    resolve error on every /score bucket): a traced matrix takes the
+    int32 reference and asks the autotuner nothing."""
+    import jax
+    import jax.numpy as jnp
+    from h2o_tpu.core import autotune as at
+    from h2o_tpu.models.tree import shared_tree as st
+    monkeypatch.setenv("H2O_TPU_AUTOTUNE", "force")
+    before = at.stats()
+    sp = jnp.linspace(-1.0, 1.0, 63, dtype=jnp.float32)[None, :]
+    x = jnp.zeros((8, 1), jnp.float32)
+    b = jax.jit(lambda m: st.bin_matrix(m, sp, np.zeros(1, bool), 64))(x)
+    assert b.dtype == jnp.int32
+    after = at.stats()
+    assert (after["probes"], after["resolve_errors"]) == \
+        (before["probes"], before["resolve_errors"])
+
+
 # ------------------------------------------- no-HBM-upcast structure
 
 
@@ -306,7 +327,7 @@ def test_no_full_matrix_int32_convert_in_traced_histogram():
         lambda b, l, s: histogram_build_traced(b, l, s, L, B)
     )(bins, leaf, stats)
 
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def walk(jx):
         for eqn in jx.eqns:

@@ -181,9 +181,27 @@ def test_engine_bookkeeping_reconciles_with_store():
 # --------------------------------------------------- persistent layer
 
 
-def test_disk_roundtrip_and_fresh_store_loads(tmp_path, monkeypatch):
+def _placed(kind, cl):
+    """The three placements a stored executable can be bound to on the
+    8-device mesh: the default device, another single device, and the
+    whole mesh (row-sharded)."""
+    import jax
+    a = jnp.arange(64, dtype=jnp.float32)
+    if kind == "default":
+        return a
+    if kind == "device3":
+        return jax.device_put(a, jax.devices()[3])
+    return jax.device_put(a, cl.row_sharding)
+
+
+@pytest.mark.parametrize("kind", ["default", "device3", "row_sharded"])
+def test_disk_roundtrip_and_fresh_store_loads(tmp_path, monkeypatch, cl,
+                                              kind):
+    """A disk entry must load onto the devices it was compiled for and
+    RUN there — jax binds a deserialized executable to every local
+    device unless told otherwise, which only ever worked on one."""
     monkeypatch.setenv("H2O_TPU_EXEC_STORE_DIR", str(tmp_path))
-    a = jnp.arange(32, dtype=jnp.float32)
+    a = _placed(kind, cl)
     st1 = ExecStore(max_entries=8)
     fn = st1.get_or_build("t", ("p1",), lambda: _scale,
                           persist="test:p1", args=(a,))
@@ -196,7 +214,9 @@ def test_disk_roundtrip_and_fresh_store_loads(tmp_path, monkeypatch):
                            persist="test:p1", args=(a,))
     s2 = st2.stats()
     assert s2["disk_hits"] == 1 and s2["serialized_bytes_read"] > 0
-    np.testing.assert_array_equal(np.asarray(fn2(a)), ref)
+    out = fn2(a)
+    assert out.sharding.device_set == a.sharding.device_set
+    np.testing.assert_array_equal(np.asarray(out), ref)
 
 
 def test_disk_key_mismatch_invalidates_cleanly(tmp_path, monkeypatch):
@@ -360,20 +380,26 @@ def test_kernel_fallback_degrades_to_xla_path():
 
 
 def test_vmem_gate_bounds_a_matrix_temporary():
-    """The ADVICE.md bug: the old gate bounded the one-hot and the
-    accumulator but not the (TR, L*S) A temporary, so narrow-feature /
-    wide-frontier shapes passed and then blew VMEM.  The combined
-    working-set plan must reject (or shrink to reject) them."""
+    """The first gate bounded the one-hot and the accumulator but not
+    the (TR, L*S) A temporary, so narrow-feature / wide-frontier shapes
+    passed and then blew VMEM.  The kernel now builds A one 128-lane
+    slab at a time, so what a wide frontier costs is the resident
+    (C*B1p, L*S) output window — and the plan must reject on it."""
     from h2o_tpu.ops.hist_pallas import min_tile_fits, plan_tile_rows
     # modest shape: fits, and fits at a useful tile height
     t = plan_tile_rows(28, 65, 32, 4, jnp.float32)
-    assert t is not None and t >= 512
-    # narrow features, huge frontier: the A temporary alone at the
-    # minimum tile is 512*16384*4 = 32 MiB >> VMEM — must be rejected
-    assert plan_tile_rows(1, 65, 4096, 4, jnp.float32) is None
-    assert not min_tile_fits(1, 65, 4096, 4)
-    # the old gate's own case still holds: very wide features rejected
+    assert t is not None and t >= 512 and t % 128 == 0
+    # one column, huge frontier: the A slab is 128 rows whatever L is,
+    # and the 72 x 16384 f32 window is 4.5 MiB — fits
+    assert plan_tile_rows(1, 65, 4096, 4, jnp.float32) is not None
+    # the bench columns at that frontier: a 2016 x 16384 f32 window is
+    # 126 MiB of the chip's 128 — must be rejected
+    assert plan_tile_rows(28, 65, 4096, 4, jnp.float32) is None
+    assert not min_tile_fits(28, 65, 4096, 4)
+    # very wide features: the one-hot alone overflows the minimum tile
     assert not min_tile_fits(4096, 65, 1, 4)
+    # a narrow one-hot pads B+1 to 32 sublanes: more rows, fewer bytes
+    assert plan_tile_rows(28, 65, 32, 4, jnp.int8, 1, 1) >= t
 
 
 def test_pallas_flag_must_be_explicit_bool():
@@ -431,7 +457,8 @@ _WARM_SRC = textwrap.dedent("""
 def _run_warm_proc(store_dir, xla_dir):
     env = dict(os.environ)
     env["H2O_TPU_EXEC_STORE_DIR"] = str(store_dir)
-    env["H2O_TPU_COMPILE_CACHE"] = str(xla_dir)
+    env["H2O_TPU_COMPILE_CACHE"] = "1"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(xla_dir)
     env["H2O_TPU_ROW_ALIGN"] = "8"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=8")

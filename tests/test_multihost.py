@@ -93,7 +93,7 @@ def test_boot_multihost_two_processes(tmp_path):
     worker = os.path.join(os.path.dirname(__file__),
                           "multihost_worker.py")
     env = dict(os.environ)
-    # children must not inherit the parent's latched single-TPU platform
+    # each worker forces its own 4-device CPU platform
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"
     # stdout is a log file now, not a pipe: defeat block buffering so
