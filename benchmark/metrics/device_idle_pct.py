@@ -1,0 +1,11 @@
+"""Share of the traced slice of the window in which no operation ran on
+the device: 1 - union of device-op intervals / traced span."""
+
+UNIT, LAYER, MOVES, SOURCE = "%", "device", "train_rate", "device_trace"
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    if not tr or not tr.get("window_s"):
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
