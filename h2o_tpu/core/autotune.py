@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.exec_store import (SCHEMA_VERSION, backend_fingerprint,
                                      code_fingerprint, store_dir)
 from h2o_tpu.core.lockwitness import make_rlock
@@ -360,6 +361,8 @@ def _probe(lv: Lever, bucket: Tuple) -> dict:
             out, ms = _measure(lv, name, w, reps)
         except Exception as e:  # noqa: BLE001 — OOM/compile kills the
             _STATS["probe_failures"] += 1       # candidate, not the job
+            TimeLine.record("safety", "probe_failures", site=lv.site,
+                            variant=name)
             cands[name] = {"status": "error",
                            "error": f"{type(e).__name__}: {e}"[:300]}
             continue
@@ -367,6 +370,8 @@ def _probe(lv: Lever, bucket: Tuple) -> dict:
         if not np.allclose(np.asarray(out), np.asarray(r_out),
                            rtol=rtol, atol=atol, equal_nan=True):
             _STATS["parity_disqualified"] += 1
+            TimeLine.record("safety", "parity_disqualified", site=lv.site,
+                            variant=name)
             cands[name] = {"status": "parity_fail", "median_ms": ms,
                            "ref": rname}
             continue
@@ -444,6 +449,7 @@ def resolve_flag(site: str, bucket=None) -> bool:
         return bool(resolve(site, bucket)["flag"])
     except Exception:  # noqa: BLE001 — degrade, never kill training
         _STATS["resolve_errors"] += 1
+        TimeLine.record("safety", "resolve_errors", site=site)
         log.warning("autotune: resolving %s at %s failed; using the "
                     "reference variant", site, bucket, exc_info=True)
         return lv.reference_flag

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import re
 import threading
 from typing import Optional
 
@@ -59,9 +60,9 @@ _cache_enabled = False
 # <checkout>/.jax_cache — resolved from this package's own location, so
 # every process started from one checkout agrees on it and the second
 # one hits what the first one wrote
-_DEFAULT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".jax_cache")
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+_DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 _OFF = ("0", "off", "false", "none", "no", "disable", "disabled")
 _ON = ("1", "on", "true", "yes")
@@ -130,6 +131,15 @@ def _enable_compile_cache() -> None:
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.makedirs(_DEFAULT_CACHE_DIR, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
+    # names are part of the key: the cache strips locations by default,
+    # so a program whose only change is its ``h2o.*`` scopes would load
+    # the older executable and every profile would show its stale names.
+    # File names go in relative to the checkout, so that the key does not
+    # move with the directory the checkout lies in.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if not jax.config.jax_hlo_source_file_canonicalization_regex:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(_CHECKOUT + os.sep))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     _cache_enabled = True
@@ -394,14 +404,21 @@ def _note(kind: str, tag: str, ici: int, dcn: int) -> None:
                                   ici, dcn)
 
 
+def _coll_scope(tag: str):
+    """``h2o.coll.<tag>``: the name a collective's device operations
+    carry in a profile — the site tag ``note_collective`` gets."""
+    return jax.named_scope(f"h2o.coll.{tag or 'untagged'}")
+
+
 def _preduce(op, x, tag: str):
     c = cloud()
     nb = _static_nbytes(x)
-    if c.n_slices == 1:
-        _note(op.__name__, tag, ici=nb, dcn=0)
-        return op(x, DATA_AXIS)
-    _note(op.__name__, tag, ici=nb, dcn=nb)
-    return op(x, (SLICE_AXIS, DATA_AXIS))
+    with _coll_scope(tag):
+        if c.n_slices == 1:
+            _note(op.__name__, tag, ici=nb, dcn=0)
+            return op(x, DATA_AXIS)
+        _note(op.__name__, tag, ici=nb, dcn=nb)
+        return op(x, (SLICE_AXIS, DATA_AXIS))
 
 
 def hpsum(x, tag: str = ""):
@@ -430,15 +447,16 @@ def hall_gather(x, tag: str = ""):
     import jax.numpy as jnp
     c = cloud()
     nb = _static_nbytes(x)
-    if c.n_slices == 1:
-        _note("all_gather", tag, ici=nb * (c.n_nodes - 1), dcn=0)
-        return jax.lax.all_gather(x, DATA_AXIS)
-    s = c.n_slices
-    q = c.n_nodes // s
-    _note("all_gather", tag, ici=nb * (q - 1), dcn=nb * q * (s - 1))
-    g = jax.lax.all_gather(x, DATA_AXIS)          # (q, ...)   ICI
-    g = jax.lax.all_gather(g, SLICE_AXIS)         # (s, q, ...) DCN
-    return g.reshape((c.n_nodes,) + tuple(jnp.shape(x)))
+    with _coll_scope(tag):
+        if c.n_slices == 1:
+            _note("all_gather", tag, ici=nb * (c.n_nodes - 1), dcn=0)
+            return jax.lax.all_gather(x, DATA_AXIS)
+        s = c.n_slices
+        q = c.n_nodes // s
+        _note("all_gather", tag, ici=nb * (q - 1), dcn=nb * q * (s - 1))
+        g = jax.lax.all_gather(x, DATA_AXIS)          # (q, ...)   ICI
+        g = jax.lax.all_gather(g, SLICE_AXIS)         # (s, q, ...) DCN
+        return g.reshape((c.n_nodes,) + tuple(jnp.shape(x)))
 
 
 def hall_to_all(x, tag: str = ""):
@@ -452,17 +470,19 @@ def hall_to_all(x, tag: str = ""):
     c = cloud()
     nb = _static_nbytes(x)
     n = c.n_nodes
-    if c.n_slices == 1:
-        _note("all_to_all", tag, ici=nb * (n - 1) // n, dcn=0)
-        return jax.lax.all_to_all(x, DATA_AXIS, 0, 0)
-    s = c.n_slices
-    q = n // s
-    _note("all_to_all", tag, ici=nb * (q - 1) // q, dcn=nb * (s - 1) // s)
-    rest = tuple(jnp.shape(x))[1:]
-    b = x.reshape((s, q) + rest)
-    b = jax.lax.all_to_all(b, SLICE_AXIS, 0, 0)   # DCN: per-slice blocks
-    b = jax.lax.all_to_all(b, DATA_AXIS, 1, 1)    # ICI: within-slice scatter
-    return b.reshape((n,) + rest)
+    with _coll_scope(tag):
+        if c.n_slices == 1:
+            _note("all_to_all", tag, ici=nb * (n - 1) // n, dcn=0)
+            return jax.lax.all_to_all(x, DATA_AXIS, 0, 0)
+        s = c.n_slices
+        q = n // s
+        _note("all_to_all", tag, ici=nb * (q - 1) // q,
+              dcn=nb * (s - 1) // s)
+        rest = tuple(jnp.shape(x))[1:]
+        b = x.reshape((s, q) + rest)
+        b = jax.lax.all_to_all(b, SLICE_AXIS, 0, 0)   # DCN: per-slice blocks
+        b = jax.lax.all_to_all(b, DATA_AXIS, 1, 1)    # ICI: in-slice scatter
+        return b.reshape((n,) + rest)
 
 
 def hshard_index():
@@ -486,7 +506,8 @@ def hall_gather_inner(x, tag: str = ""):
     c = cloud()
     q = c.n_nodes // c.n_slices
     _note("all_gather", tag, ici=nb * (q - 1), dcn=0)
-    return jax.lax.all_gather(x, DATA_AXIS)
+    with _coll_scope(tag):
+        return jax.lax.all_gather(x, DATA_AXIS)
 
 
 def hpsum_slices(x, tag: str = ""):
@@ -499,6 +520,7 @@ def hpsum_slices(x, tag: str = ""):
         return x
     nb = _static_nbytes(x)
     _note("psum", tag, ici=0, dcn=nb)
-    return jax.lax.psum(x, SLICE_AXIS)
+    with _coll_scope(tag):
+        return jax.lax.psum(x, SLICE_AXIS)
 
 

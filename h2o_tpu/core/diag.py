@@ -19,6 +19,8 @@ device-side tracing to jax.profiler.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import os
 import sys
 import threading
@@ -27,7 +29,16 @@ import traceback
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 MAX_EVENTS = 2048
+
+# the jax monitoring events whose durations DispatchStats keeps, by their
+# last path component (jax/_src/dispatch.py, jax/_src/compiler.py).
+# backend_compile wraps the persistent-cache lookup, so cache_retrieval
+# is a PART of it, not an addend.
+_COMPILE_EVENTS = ("jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+                   "backend_compile_duration", "cache_retrieval_time_sec")
 
 
 class DispatchStats:
@@ -63,6 +74,7 @@ class DispatchStats:
     _collectives: Dict[str, Dict[str, Dict[str, int]]] = {}
     _phase_local = threading.local()
     _xla_compiles = 0
+    _compile_seconds: Dict[str, float] = {}
     _listener_installed = False
 
     @classmethod
@@ -165,23 +177,11 @@ class DispatchStats:
             d["dcn_bytes"] += int(dcn_bytes)
 
     @classmethod
-    def collective_bytes(cls, phase: Optional[str] = None) -> Dict[str, int]:
-        """Summed {ici_bytes, dcn_bytes} for one phase (or all phases)."""
-        out = {"ici_bytes": 0, "dcn_bytes": 0}
-        with cls._lock:
-            for p, kinds in cls._collectives.items():
-                if phase is not None and p != phase:
-                    continue
-                for d in kinds.values():
-                    out["ici_bytes"] += d["ici_bytes"]
-                    out["dcn_bytes"] += d["dcn_bytes"]
-        return out
-
-    @classmethod
     def install_xla_listener(cls) -> None:
         """Idempotent: register a jax monitoring listener that counts
         backend compiles (the '/jax/core/compile/backend_compile_
-        duration' event — one per XLA executable actually built)."""
+        duration' event — one per XLA executable actually built) and
+        sums the seconds of each ``_COMPILE_EVENTS`` event."""
         with cls._lock:
             if cls._listener_installed:
                 return
@@ -189,8 +189,13 @@ class DispatchStats:
         from jax._src import monitoring
 
         def on_event(event: str, duration: float, **kw) -> None:
-            if event.endswith("backend_compile_duration"):
-                with cls._lock:
+            name = event.rsplit("/", 1)[-1]
+            if name not in _COMPILE_EVENTS:
+                return
+            with cls._lock:
+                cls._compile_seconds[name] = \
+                    cls._compile_seconds.get(name, 0.0) + float(duration)
+                if name == "backend_compile_duration":
                     cls._xla_compiles += 1
 
         monitoring.register_event_duration_secs_listener(on_event)
@@ -199,6 +204,15 @@ class DispatchStats:
     def xla_compiles(cls) -> int:
         with cls._lock:
             return cls._xla_compiles
+
+    @classmethod
+    def compile_seconds(cls) -> Dict[str, float]:
+        """Seconds this process spent getting programs ready, summed per
+        jax monitoring event since ``install_xla_listener``: tracing,
+        lowering to MLIR, the backend compile (which holds the
+        persistent-cache lookup) and, of that, the cache retrievals."""
+        with cls._lock:
+            return dict(cls._compile_seconds)
 
     @classmethod
     def snapshot(cls) -> Dict[str, Any]:
@@ -219,12 +233,14 @@ class DispatchStats:
                                     for p, kinds in cls._collectives.items()},
                     "stats_pack": statpack.stats(),
                     "xla_compiles": cls._xla_compiles,
+                    "compile_seconds": dict(cls._compile_seconds),
                     "xla_listener": cls._listener_installed}
 
     @classmethod
     def reset(cls) -> None:
         """Zero the per-phase counters (the global xla_compiles counter
-        keeps running — it is a monotone process-lifetime count)."""
+        and compile_seconds keep running — monotone process-lifetime
+        totals)."""
         with cls._lock:
             cls._compiles.clear()
             cls._dispatches.clear()
@@ -238,20 +254,57 @@ class DispatchStats:
 
 
 class TimeLine:
-    """Fixed-size event ring (water/TimeLine.java)."""
+    """Fixed-size event ring (water/TimeLine.java).
+
+    Two kinds of entry share the ring: point events (``record``) and
+    SPANS (``span``), which add ``dur_ns``, ``id``, ``parent`` and
+    ``job``.  A span's duration is HOST time between entering and
+    leaving the ``with`` block; device time comes from the ``h2o.*``
+    named scopes in a profile, never from a sync added to a span."""
 
     _events: deque = deque(maxlen=MAX_EVENTS)
     _lock = threading.Lock()
-    _enabled = True
+    _ids = itertools.count(1)
+    _open = threading.local()       # .stack: [(span id, job key)] per thread
 
     @classmethod
     def record(cls, kind: str, what: str, **info) -> None:
-        if not cls._enabled:
-            return
         ev = {"ns": time.time_ns(), "kind": kind, "what": what,
               "thread": threading.get_ident(), **info}
         with cls._lock:
             cls._events.append(ev)
+
+    @classmethod
+    @contextlib.contextmanager
+    def span(cls, kind: str, what: str, job: Optional[str] = None, **info):
+        """Context manager: ONE ring event when the block is left (also
+        by an exception), stamped with the block's start (``ns``) and
+        host duration (``dur_ns``), its ``id``, the ``parent`` span open
+        on this thread and the ``job`` key — given by a root span,
+        inherited by everything opened under it.  The block also runs
+        under ``TraceAnnotation("h2o:<kind>.<what>")``: while a profile
+        is being taken the span lies in its host plane on the device
+        events' clock; otherwise that is a flag test."""
+        stack = getattr(cls._open, "stack", None)
+        if stack is None:
+            stack = cls._open.stack = []
+        parent, inherited = stack[-1] if stack else (None, None)
+        if job is None:
+            job = inherited
+        sid = next(cls._ids)
+        stack.append((sid, job))
+        ns, t0 = time.time_ns(), time.perf_counter_ns()
+        try:
+            with TraceAnnotation(f"h2o:{kind}.{what}"):
+                yield
+        finally:
+            dur = time.perf_counter_ns() - t0
+            stack.pop()
+            ev = {"ns": ns, "kind": kind, "what": what,
+                  "thread": threading.get_ident(), "dur_ns": dur,
+                  "id": sid, "parent": parent, "job": job, **info}
+            with cls._lock:
+                cls._events.append(ev)
 
     @classmethod
     def snapshot(cls) -> List[Dict[str, Any]]:

@@ -480,7 +480,11 @@ class JobRegistry:
                 if chaos().enabled:
                     chaos().maybe_fail_job(job.description)
                     chaos().maybe_stall(job.description)
-                result = body(job)
+                # root span: everything the body records on this thread
+                # carries the job's key
+                with TimeLine.span("job", "run", job=str(job.key),
+                                   description=job.description):
+                    result = body(job)
                 with job._state_lock:
                     if not job._timed_out:
                         job.result = result

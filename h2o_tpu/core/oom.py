@@ -44,6 +44,7 @@ import os
 import threading
 from typing import Callable, Dict, Optional
 
+from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.log import get_logger
 
 log = get_logger("oom")
@@ -213,6 +214,7 @@ def _note(site: str, rung: str, n: int = 1) -> None:
     with _stats_lock:
         d = _sites.setdefault(site, {r: 0 for r in _RUNGS})
         d[rung] += n
+    TimeLine.record("safety", rung, site=site)
 
 
 def stats() -> dict:

@@ -25,6 +25,7 @@ EPS = 1e-15
 
 
 @functools.partial(jax.jit, static_argnames=("nbins",))
+@jax.named_scope("h2o.score.metrics")
 def _binomial_kernel(p, y, w, valid, nbins: int = _NBINS_AUC):
     """p: P(class 1); y: {0,1}; returns scalars + per-bin pos/neg counts."""
     w = jnp.where(valid, w, 0.0)
@@ -70,6 +71,7 @@ def _auc_from_hist(pos: np.ndarray, neg: np.ndarray) -> Dict[str, float]:
 
 
 @jax.jit
+@jax.named_scope("h2o.score.metrics")
 def _regression_kernel(pred, y, w, valid, dev):
     w = jnp.where(valid, w, 0.0)
     # NaN-proof the payloads too: invalid rows carry NaN and 0*NaN = NaN
@@ -93,6 +95,7 @@ def _regression_kernel(pred, y, w, valid, dev):
 
 
 @functools.partial(jax.jit, static_argnames=("nclass",))
+@jax.named_scope("h2o.score.metrics")
 def _multinomial_kernel(probs, y, w, valid, nclass: int):
     """probs: (rows, K); y: int class; confusion + logloss + hit ratios."""
     w = jnp.where(valid, w, 0.0)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import Frame
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
 from h2o_tpu.models.tree import shared_tree as st
@@ -241,7 +242,9 @@ class DRF(ModelBuilder):
                                 prior_trees=prior,
                                 recovery=getattr(self, "_recovery", None),
                                 data_frame=train)
-        model.output["training_metrics"] = model.model_metrics(train)
-        if valid is not None:
-            model.output["validation_metrics"] = model.model_metrics(valid)
+        with TimeLine.span("train", "final_metrics"):
+            model.output["training_metrics"] = model.model_metrics(train)
+            if valid is not None:
+                model.output["validation_metrics"] = \
+                    model.model_metrics(valid)
         return model

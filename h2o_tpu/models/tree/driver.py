@@ -125,11 +125,13 @@ class IncrementalScorer:
 
 
 @jax.jit
+@jax.named_scope("h2o.score.metrics")
 def _accum(F, delta):
     return F + delta
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope("h2o.score.metrics")
 def _accum_donate(F, delta):
     return F + delta
 
@@ -356,10 +358,11 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
             nonlocal no_donate
             no_donate = True
 
-        tf = oom_ladder("tree.block", attempt, shrink=shrink,
-                        on_oom=on_oom)
-        F = tf.f_final
-        _start_host_pull(tf)
+        with TimeLine.span("train", "block.launch", t0=prior_trees + off):
+            tf = oom_ladder("tree.block", attempt, shrink=shrink,
+                            on_oom=on_oom)
+            F = tf.f_final
+            _start_host_pull(tf)
         TimeLine.record("dispatch", "tree_block_launch",
                         t0=prior_trees + off, n=state["n"])
         # key_after: the master key is block-invariant, so a checkpoint
@@ -374,55 +377,63 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         already-completed previous block)."""
         nonlocal vi_total, done
         tf, n = cur["tf"], cur["n"]
-        chaos().maybe_slow_transfer("tree_block")
-        scs.append(np.asarray(tf.split_col))
-        bss.append(np.asarray(tf.bitset))
-        vls.append(np.asarray(tf.value))
-        if tf.child is not None:
-            chs.append(np.asarray(tf.child))
-        gns.append(np.asarray(tf.node_gain))
-        nws.append(np.asarray(tf.node_w))
-        ths.append(np.asarray(tf.thr_bin))
-        nas.append(np.asarray(tf.na_left))
-        vi = np.asarray(tf.varimp)
-        TimeLine.record("dispatch", "tree_block_materialize",
-                        t0=prior_trees + cur["off"], n=n)
-        DispatchStats.note_transfer("tree_block", _block_nbytes(tf))
-        vi_total = vi if vi_total is None else vi_total + vi
-        done += n
-        stop = False
-        if scorer is not None:
-            scorer.add(tf.split_col, tf.bitset, tf.value, tf.child,
-                       tf.thr_bin, tf.na_left)
-            mm = scorer.metrics(prior_trees + done)
-            row = {"number_of_trees": prior_trees + done,
-                   "timestamp": time.time()}
-            for k in ("mse", "logloss", "AUC", "mean_residual_deviance",
-                      "err"):
-                if mm.get(k) is not None:
-                    row[prefix + k.lower()] = mm.get(k)
-            sk.add(mm, row)
-            job.update(0.05 + 0.85 * done / ntrees,
-                       f"{prior_trees + done} trees, "
-                       f"{sk.metric_name}={sk.history[-1]:.5g}")
-            if sk.stop_early():
-                job.update(0.9, f"early stop at {prior_trees + done} trees")
-                stop = True
-        else:
-            job.update(0.05 + 0.85 * done / ntrees,
-                       f"{prior_trees + done} trees")
-        if recovery is not None:
-            recovery.save_iteration(
-                {"kind": "tree", "prior_trees": prior_trees,
-                 "ntrees_target": ntrees, "block": block, "done": done,
-                 "F": np.asarray(tf.f_final),
-                 "key": rng_key_to_np(cur["key_after"]),
-                 "lists": lists, "vi_total": vi_total, "sk": sk,
-                 "scorer_F": np.asarray(scorer.F)
-                 if scorer is not None else None},
-                meta={"kind": "tree",
-                      "trees_done": prior_trees + done,
-                      "ntrees": int(p["ntrees"])})
+        # spans are HOST time: under the async driver block t+1 is
+        # already queued, so "score" also waits for t+1's build
+        with TimeLine.span("train", "block.absorb",
+                           t0=prior_trees + cur["off"], n=n):
+            with TimeLine.span("train", "block.pull"):
+                chaos().maybe_slow_transfer("tree_block")
+                scs.append(np.asarray(tf.split_col))
+                bss.append(np.asarray(tf.bitset))
+                vls.append(np.asarray(tf.value))
+                if tf.child is not None:
+                    chs.append(np.asarray(tf.child))
+                gns.append(np.asarray(tf.node_gain))
+                nws.append(np.asarray(tf.node_w))
+                ths.append(np.asarray(tf.thr_bin))
+                nas.append(np.asarray(tf.na_left))
+                vi = np.asarray(tf.varimp)
+            TimeLine.record("dispatch", "tree_block_materialize",
+                            t0=prior_trees + cur["off"], n=n)
+            DispatchStats.note_transfer("tree_block", _block_nbytes(tf))
+            vi_total = vi if vi_total is None else vi_total + vi
+            done += n
+            stop = False
+            if scorer is not None:
+                with TimeLine.span("train", "block.score"):
+                    scorer.add(tf.split_col, tf.bitset, tf.value, tf.child,
+                               tf.thr_bin, tf.na_left)
+                    mm = scorer.metrics(prior_trees + done)
+                    row = {"number_of_trees": prior_trees + done,
+                           "timestamp": time.time()}
+                    for k in ("mse", "logloss", "AUC",
+                              "mean_residual_deviance", "err"):
+                        if mm.get(k) is not None:
+                            row[prefix + k.lower()] = mm.get(k)
+                    sk.add(mm, row)
+                job.update(0.05 + 0.85 * done / ntrees,
+                           f"{prior_trees + done} trees, "
+                           f"{sk.metric_name}={sk.history[-1]:.5g}")
+                if sk.stop_early():
+                    job.update(0.9,
+                               f"early stop at {prior_trees + done} trees")
+                    stop = True
+            else:
+                job.update(0.05 + 0.85 * done / ntrees,
+                           f"{prior_trees + done} trees")
+            if recovery is not None:
+                with TimeLine.span("train", "block.checkpoint"):
+                    recovery.save_iteration(
+                        {"kind": "tree", "prior_trees": prior_trees,
+                         "ntrees_target": ntrees, "block": block,
+                         "done": done, "F": np.asarray(tf.f_final),
+                         "key": rng_key_to_np(cur["key_after"]),
+                         "lists": lists, "vi_total": vi_total, "sk": sk,
+                         "scorer_F": np.asarray(scorer.F)
+                         if scorer is not None else None},
+                        meta={"kind": "tree",
+                              "trees_done": prior_trees + done,
+                              "ntrees": int(p["ntrees"])})
         return stop
 
     pend = None

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import Frame
 from h2o_tpu.models.distributions import get_distribution
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
@@ -28,6 +29,7 @@ from h2o_tpu.models.tree import shared_tree as st
 EPS = 1e-10
 
 
+@jax.named_scope("h2o.score.metrics")
 def raw_from_F(F, dom, dist_name: str, tweedie_power: float = 1.5,
                threshold: float = 0.5, custom_link: str = None):
     """Link-scale forest sum -> raw predictions (shared by BigScore-style
@@ -380,7 +382,9 @@ class GBM(ModelBuilder):
             # per-tree inner fits (DART driver) discard these; the outer
             # loop scores the final concatenated forest once
             return model
-        model.output["training_metrics"] = model.model_metrics(train)
-        if valid is not None:
-            model.output["validation_metrics"] = model.model_metrics(valid)
+        with TimeLine.span("train", "final_metrics"):
+            model.output["training_metrics"] = model.model_metrics(train)
+            if valid is not None:
+                model.output["validation_metrics"] = \
+                    model.model_metrics(valid)
         return model

@@ -278,6 +278,7 @@ def sibling_subtract_enabled() -> bool:
     return resolve_flag("tree.sibling_subtract")
 
 
+@jax.named_scope("h2o.tree.hist.contract")
 def _hist_level_with_sibling(bins, slot, stats, L: int, B: int, cfg,
                              parent_hist, parent_split):
     """Level-d histograms via sibling subtraction.
@@ -358,8 +359,9 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
         # cost L * Bd stays ~constant
         Bd = max(B, F >> d) if adaptive else B
         if adaptive:
-            key, sub = jax.random.split(key)
-            roff = _rand_offsets(sub, L, C, rlo, rhi, random_mode)
+            with jax.named_scope("h2o.tree.split"):
+                key, sub = jax.random.split(key)
+                roff = _rand_offsets(sub, L, C, rlo, rhi, random_mode)
             hist = _shard_histogram(
                 bins, leaf, stats, L, Bd, cfg["block_rows"], cfg["bf16"],
                 fine_map=(rlo, rhi, roff, is_cat, F),
@@ -374,116 +376,120 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
         # the ONE integer->f32 crossing per level: split finding and
         # range refinement read the dequantized table, sibling
         # subtraction keeps the exact integer one
-        hist_f = hist if inv_scale is None else \
-            statpack.dequant_table(hist, inv_scale)
-        if k_cols < C:
-            key, sub = jax.random.split(key)
-            r = jax.random.uniform(sub, (L, C))
-            kth = jnp.sort(r, axis=1)[:, k_cols - 1][:, None]
-            col_allowed = r <= kth
-        else:
-            col_allowed = jnp.ones((L, C), bool)
-        if tree_col_mask is not None:
-            col_allowed = col_allowed & tree_col_mask[None, :]
-        s = find_splits(hist_f, is_cat, col_allowed,
-                        min_rows=cfg["min_rows"],
-                        min_split_improvement=cfg["min_split_improvement"],
-                        mono=mono, use_mono=use_mono, newton=newton,
-                        reg_lambda=reg_lambda)
-        live = s["leaf"]["w"] > 0
-        do_split = s["do_split"] & live
-        term = live & ~do_split
-        leaf_vals = _node_val(s["leaf"]["wg"], s["leaf"]["wh"],
-                              s["leaf"]["w"], newton, reg_lambda)
-        lvals = _node_val(s["left"]["wg"], s["left"]["wh"],
-                          s["left"]["w"], newton, reg_lambda)
-        rvals = _node_val(s["right"]["wg"], s["right"]["wh"],
-                          s["right"]["w"], newton, reg_lambda)
-        if use_mono:
-            leaf_vals = jnp.clip(leaf_vals, lo_b, hi_b)
-            lvals = jnp.clip(lvals, lo_b, hi_b)
-            rvals = jnp.clip(rvals, lo_b, hi_b)
-            m = mono[s["col"]].astype(jnp.float32)         # (L,)
-            mid = 0.5 * (lvals + rvals)
-            l_hi = jnp.where(m > 0, jnp.minimum(hi_b, mid), hi_b)
-            r_lo = jnp.where(m > 0, jnp.maximum(lo_b, mid), lo_b)
-            l_lo = jnp.where(m < 0, jnp.maximum(lo_b, mid), lo_b)
-            r_hi = jnp.where(m < 0, jnp.minimum(hi_b, mid), hi_b)
-            lo_b = jnp.stack([l_lo, r_lo], axis=1).reshape(2 * L)
-            hi_b = jnp.stack([l_hi, r_hi], axis=1).reshape(2 * L)
-
-        varimp = varimp.at[s["col"]].add(
-            jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0))
-        # record splits + terminal values at this level's heap slots
-        node_gain = jax.lax.dynamic_update_slice(
-            node_gain,
-            jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0), (off,))
-        split_col = jax.lax.dynamic_update_slice(
-            split_col, jnp.where(do_split, s["col"], -1), (off,))
-        cat_choice = is_cat[s["col"]]
-        if adaptive:
-            thr_leaf = _numeric_thr(s, rlo, rhi, roff, Bd)
-            num_split = do_split & ~cat_choice
-            thr_arr = jax.lax.dynamic_update_slice(
-                thr_arr, jnp.where(num_split, thr_leaf, -1), (off,))
-            na_arr = jax.lax.dynamic_update_slice(
-                na_arr, num_split & s["na_left"], (off,))
-            # numeric nodes carry the fine threshold; their BUCKET
-            # bitsets are per-node artifacts and must not be stored.
-            # Cat splits: codes live in the first B buckets whatever Bd
-            # is; keep membership [:B] + the NA bit
-            bset_store = jnp.concatenate(
-                [s["bitset"][:, :B], s["bitset"][:, Bd: Bd + 1]], axis=1)
-            bset_w = bset_store & (do_split & cat_choice)[:, None]
-        else:
-            thr_leaf = None
-            bset_w = s["bitset"] & do_split[:, None]
-        bitset = jax.lax.dynamic_update_slice(bitset, bset_w, (off, 0))
-        value = jax.lax.dynamic_update_slice(
-            value, jnp.where(term, leaf_vals, 0.0), (off,))
-        node_w = jax.lax.dynamic_update_slice(
-            node_w, jnp.where(live, s["leaf"]["w"], 0.0), (off,))
-        # pre-write child values (interleaved left/right) at the next level
-        child_vals = jnp.stack([lvals, rvals], axis=1).reshape(2 * L)
-        child_mask = jnp.repeat(do_split, 2)
-        coff = 2 * L - 1
-        cur = jax.lax.dynamic_slice(value, (coff,), (2 * L,))
-        value = jax.lax.dynamic_update_slice(
-            value, jnp.where(child_mask, child_vals, cur), (coff,))
-        # pre-write child covers too (the depth-D level never runs the
-        # loop body, so its weights only exist via this write)
-        child_ws = jnp.stack([s["left"]["w"], s["right"]["w"]],
-                             axis=1).reshape(2 * L)
-        cur_w = jax.lax.dynamic_slice(node_w, (coff,), (2 * L,))
-        node_w = jax.lax.dynamic_update_slice(
-            node_w, jnp.where(child_mask, child_ws, cur_w), (coff,))
-
-        # route rows
-        active = leaf >= 0
-        lf = jnp.maximum(leaf, 0)
-        if cfg.get("mm_route") and L <= _MM_ROUTE_MAX_TABLE and \
-                (Bd if adaptive else B) < _MM_ROUTE_MAX_TABLE:
-            go_left, do_lf = _mm_route_level(
-                bins, lf, s, do_split, L, Bd if adaptive else B,
-                cat_choice, adaptive, thr_leaf, F)
-        else:
-            c = s["col"][lf]
-            b = jnp.take_along_axis(bins, c[:, None], axis=1)[:, 0]
-            if adaptive:
-                gset = s["bitset"][lf, jnp.minimum(b, Bd)]
-                gthr = jnp.where(b == F, s["na_left"][lf],
-                                 b < thr_leaf[lf])
-                go_left = jnp.where(cat_choice[lf], gset, gthr)
+        with jax.named_scope("h2o.tree.hist.contract"):
+            hist_f = hist if inv_scale is None else \
+                statpack.dequant_table(hist, inv_scale)
+        with jax.named_scope("h2o.tree.split"):
+            if k_cols < C:
+                key, sub = jax.random.split(key)
+                r = jax.random.uniform(sub, (L, C))
+                kth = jnp.sort(r, axis=1)[:, k_cols - 1][:, None]
+                col_allowed = r <= kth
             else:
-                go_left = s["bitset"][lf, b]
-            do_lf = do_split[lf]
-        child = 2 * lf + jnp.where(go_left, 0, 1)
-        leaf = jnp.where(active & do_lf, child,
-                         jnp.where(active, -1, leaf))
-        if adaptive and d + 1 < D:
-            new_lo, new_hi = _refine_ranges(hist_f, rlo, rhi, roff, Bd)
-            rlo, rhi = _child_ranges(new_lo, new_hi, s, thr_leaf,
-                                     is_cat, do_split)
+                col_allowed = jnp.ones((L, C), bool)
+            if tree_col_mask is not None:
+                col_allowed = col_allowed & tree_col_mask[None, :]
+            s = find_splits(hist_f, is_cat, col_allowed,
+                            min_rows=cfg["min_rows"],
+                            min_split_improvement=cfg["min_split_improvement"],
+                            mono=mono, use_mono=use_mono, newton=newton,
+                            reg_lambda=reg_lambda)
+            live = s["leaf"]["w"] > 0
+            do_split = s["do_split"] & live
+            term = live & ~do_split
+            leaf_vals = _node_val(s["leaf"]["wg"], s["leaf"]["wh"],
+                                  s["leaf"]["w"], newton, reg_lambda)
+            lvals = _node_val(s["left"]["wg"], s["left"]["wh"],
+                              s["left"]["w"], newton, reg_lambda)
+            rvals = _node_val(s["right"]["wg"], s["right"]["wh"],
+                              s["right"]["w"], newton, reg_lambda)
+            if use_mono:
+                leaf_vals = jnp.clip(leaf_vals, lo_b, hi_b)
+                lvals = jnp.clip(lvals, lo_b, hi_b)
+                rvals = jnp.clip(rvals, lo_b, hi_b)
+                m = mono[s["col"]].astype(jnp.float32)         # (L,)
+                mid = 0.5 * (lvals + rvals)
+                l_hi = jnp.where(m > 0, jnp.minimum(hi_b, mid), hi_b)
+                r_lo = jnp.where(m > 0, jnp.maximum(lo_b, mid), lo_b)
+                l_lo = jnp.where(m < 0, jnp.maximum(lo_b, mid), lo_b)
+                r_hi = jnp.where(m < 0, jnp.minimum(hi_b, mid), hi_b)
+                lo_b = jnp.stack([l_lo, r_lo], axis=1).reshape(2 * L)
+                hi_b = jnp.stack([l_hi, r_hi], axis=1).reshape(2 * L)
+
+            varimp = varimp.at[s["col"]].add(
+                jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0))
+            # record splits + terminal values at this level's heap slots
+            node_gain = jax.lax.dynamic_update_slice(
+                node_gain,
+                jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0), (off,))
+            split_col = jax.lax.dynamic_update_slice(
+                split_col, jnp.where(do_split, s["col"], -1), (off,))
+            cat_choice = is_cat[s["col"]]
+            if adaptive:
+                thr_leaf = _numeric_thr(s, rlo, rhi, roff, Bd)
+                num_split = do_split & ~cat_choice
+                thr_arr = jax.lax.dynamic_update_slice(
+                    thr_arr, jnp.where(num_split, thr_leaf, -1), (off,))
+                na_arr = jax.lax.dynamic_update_slice(
+                    na_arr, num_split & s["na_left"], (off,))
+                # numeric nodes carry the fine threshold; their BUCKET
+                # bitsets are per-node artifacts and must not be stored.
+                # Cat splits: codes live in the first B buckets whatever Bd
+                # is; keep membership [:B] + the NA bit
+                bset_store = jnp.concatenate(
+                    [s["bitset"][:, :B], s["bitset"][:, Bd: Bd + 1]], axis=1)
+                bset_w = bset_store & (do_split & cat_choice)[:, None]
+            else:
+                thr_leaf = None
+                bset_w = s["bitset"] & do_split[:, None]
+            bitset = jax.lax.dynamic_update_slice(bitset, bset_w, (off, 0))
+            value = jax.lax.dynamic_update_slice(
+                value, jnp.where(term, leaf_vals, 0.0), (off,))
+            node_w = jax.lax.dynamic_update_slice(
+                node_w, jnp.where(live, s["leaf"]["w"], 0.0), (off,))
+            # pre-write child values (interleaved left/right) at the next level
+            child_vals = jnp.stack([lvals, rvals], axis=1).reshape(2 * L)
+            child_mask = jnp.repeat(do_split, 2)
+            coff = 2 * L - 1
+            cur = jax.lax.dynamic_slice(value, (coff,), (2 * L,))
+            value = jax.lax.dynamic_update_slice(
+                value, jnp.where(child_mask, child_vals, cur), (coff,))
+            # pre-write child covers too (the depth-D level never runs the
+            # loop body, so its weights only exist via this write)
+            child_ws = jnp.stack([s["left"]["w"], s["right"]["w"]],
+                                 axis=1).reshape(2 * L)
+            cur_w = jax.lax.dynamic_slice(node_w, (coff,), (2 * L,))
+            node_w = jax.lax.dynamic_update_slice(
+                node_w, jnp.where(child_mask, child_ws, cur_w), (coff,))
+
+        with jax.named_scope("h2o.tree.route"):
+            # route rows
+            active = leaf >= 0
+            lf = jnp.maximum(leaf, 0)
+            if cfg.get("mm_route") and L <= _MM_ROUTE_MAX_TABLE and \
+                    (Bd if adaptive else B) < _MM_ROUTE_MAX_TABLE:
+                go_left, do_lf = _mm_route_level(
+                    bins, lf, s, do_split, L, Bd if adaptive else B,
+                    cat_choice, adaptive, thr_leaf, F)
+            else:
+                c = s["col"][lf]
+                b = jnp.take_along_axis(bins, c[:, None], axis=1)[:, 0]
+                if adaptive:
+                    gset = s["bitset"][lf, jnp.minimum(b, Bd)]
+                    gthr = jnp.where(b == F, s["na_left"][lf],
+                                     b < thr_leaf[lf])
+                    go_left = jnp.where(cat_choice[lf], gset, gthr)
+                else:
+                    go_left = s["bitset"][lf, b]
+                do_lf = do_split[lf]
+            child = 2 * lf + jnp.where(go_left, 0, 1)
+            leaf = jnp.where(active & do_lf, child,
+                             jnp.where(active, -1, leaf))
+        with jax.named_scope("h2o.tree.split"):
+            if adaptive and d + 1 < D:
+                new_lo, new_hi = _refine_ranges(hist_f, rlo, rhi, roff, Bd)
+                rlo, rhi = _child_ranges(new_lo, new_hi, s, thr_leaf,
+                                         is_cat, do_split)
         prev_hist, prev_do = hist, do_split
     return (split_col, bitset, value, varimp, node_gain, node_w,
             thr_arr, na_arr)
@@ -547,8 +553,9 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
         L = widths[d]
         Bd = max(B, F >> d) if adaptive else B
         if adaptive:
-            key, sub = jax.random.split(key)
-            roff = _rand_offsets(sub, L, C, rlo, rhi, random_mode)
+            with jax.named_scope("h2o.tree.split"):
+                key, sub = jax.random.split(key)
+                roff = _rand_offsets(sub, L, C, rlo, rhi, random_mode)
             hist = _shard_histogram(
                 bins, slot, stats, L, Bd, cfg["block_rows"], cfg["bf16"],
                 fine_map=(rlo, rhi, roff, is_cat, F),
@@ -565,139 +572,144 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
                                     cfg["block_rows"], cfg["bf16"],
                                     pallas=cfg.get("pallas"))
         # dequantize once per level at the table (see build_tree_traced)
-        hist_f = hist if inv_scale is None else \
-            statpack.dequant_table(hist, inv_scale)
-        if k_cols < C:
-            key, sub = jax.random.split(key)
-            r = jax.random.uniform(sub, (L, C))
-            kth = jnp.sort(r, axis=1)[:, k_cols - 1][:, None]
-            col_allowed = r <= kth
-        else:
-            col_allowed = jnp.ones((L, C), bool)
-        if tree_col_mask is not None:
-            col_allowed = col_allowed & tree_col_mask[None, :]
-        s = find_splits(hist_f, is_cat, col_allowed,
-                        min_rows=cfg["min_rows"],
-                        min_split_improvement=cfg["min_split_improvement"],
-                        mono=mono, use_mono=use_mono, newton=newton,
-                        reg_lambda=reg_lambda)
-        live = s["leaf"]["w"] > 0
-        do_split = s["do_split"] & live
-        term = live & ~do_split
-        leaf_vals = _node_val(s["leaf"]["wg"], s["leaf"]["wh"],
-                              s["leaf"]["w"], newton, reg_lambda)
-        lvals = _node_val(s["left"]["wg"], s["left"]["wh"],
-                          s["left"]["w"], newton, reg_lambda)
-        rvals = _node_val(s["right"]["wg"], s["right"]["wh"],
-                          s["right"]["w"], newton, reg_lambda)
-        if use_mono:
-            leaf_vals = jnp.clip(leaf_vals, lo_b, hi_b)
-            lvals = jnp.clip(lvals, lo_b, hi_b)
-            rvals = jnp.clip(rvals, lo_b, hi_b)
-            m = mono[s["col"]].astype(jnp.float32)
-            mid = 0.5 * (lvals + rvals)
-            l_hi = jnp.where(m > 0, jnp.minimum(hi_b, mid), hi_b)
-            r_lo = jnp.where(m > 0, jnp.maximum(lo_b, mid), lo_b)
-            l_lo = jnp.where(m < 0, jnp.maximum(lo_b, mid), lo_b)
-            r_hi = jnp.where(m < 0, jnp.minimum(hi_b, mid), hi_b)
-            lo_c = jnp.stack([l_lo, r_lo], axis=1).reshape(2 * L)
-            hi_c = jnp.stack([l_hi, r_hi], axis=1).reshape(2 * L)
+        with jax.named_scope("h2o.tree.hist.contract"):
+            hist_f = hist if inv_scale is None else \
+                statpack.dequant_table(hist, inv_scale)
+        with jax.named_scope("h2o.tree.split"):
+            if k_cols < C:
+                key, sub = jax.random.split(key)
+                r = jax.random.uniform(sub, (L, C))
+                kth = jnp.sort(r, axis=1)[:, k_cols - 1][:, None]
+                col_allowed = r <= kth
+            else:
+                col_allowed = jnp.ones((L, C), bool)
+            if tree_col_mask is not None:
+                col_allowed = col_allowed & tree_col_mask[None, :]
+            s = find_splits(hist_f, is_cat, col_allowed,
+                            min_rows=cfg["min_rows"],
+                            min_split_improvement=cfg["min_split_improvement"],
+                            mono=mono, use_mono=use_mono, newton=newton,
+                            reg_lambda=reg_lambda)
+            live = s["leaf"]["w"] > 0
+            do_split = s["do_split"] & live
+            term = live & ~do_split
+            leaf_vals = _node_val(s["leaf"]["wg"], s["leaf"]["wh"],
+                                  s["leaf"]["w"], newton, reg_lambda)
+            lvals = _node_val(s["left"]["wg"], s["left"]["wh"],
+                              s["left"]["w"], newton, reg_lambda)
+            rvals = _node_val(s["right"]["wg"], s["right"]["wh"],
+                              s["right"]["w"], newton, reg_lambda)
+            if use_mono:
+                leaf_vals = jnp.clip(leaf_vals, lo_b, hi_b)
+                lvals = jnp.clip(lvals, lo_b, hi_b)
+                rvals = jnp.clip(rvals, lo_b, hi_b)
+                m = mono[s["col"]].astype(jnp.float32)
+                mid = 0.5 * (lvals + rvals)
+                l_hi = jnp.where(m > 0, jnp.minimum(hi_b, mid), hi_b)
+                r_lo = jnp.where(m > 0, jnp.maximum(lo_b, mid), lo_b)
+                l_lo = jnp.where(m < 0, jnp.maximum(lo_b, mid), lo_b)
+                r_hi = jnp.where(m < 0, jnp.minimum(hi_b, mid), hi_b)
+                lo_c = jnp.stack([l_lo, r_lo], axis=1).reshape(2 * L)
+                hi_c = jnp.stack([l_hi, r_hi], axis=1).reshape(2 * L)
 
-        varimp = varimp.at[s["col"]].add(
-            jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0))
-        # write this level's frontier nodes into the pool (scatter at
-        # traced pool ids; trash-slot writes are inert)
-        gain_pos = jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0)
-        child_ptr = base + 2 * jnp.arange(L, dtype=jnp.int32)
-        split_col = split_col.at[frontier].set(
-            jnp.where(do_split, s["col"], -1))
-        cat_choice = is_cat[s["col"]]
-        if adaptive:
-            thr_leaf = _numeric_thr(s, rlo, rhi, roff, Bd)
-            num_split = do_split & ~cat_choice
-            thr_pool = thr_pool.at[frontier].set(
-                jnp.where(num_split, thr_leaf, -1))
-            na_pool = na_pool.at[frontier].set(num_split & s["na_left"])
-            bset_store = jnp.concatenate(
-                [s["bitset"][:, :B], s["bitset"][:, Bd: Bd + 1]], axis=1)
-            bset_w = bset_store & (do_split & cat_choice)[:, None]
-        else:
-            thr_leaf = None
-            bset_w = s["bitset"] & do_split[:, None]
-        bitset = bitset.at[frontier].set(bset_w)
-        value = value.at[frontier].set(jnp.where(term, leaf_vals, 0.0))
-        child = child.at[frontier].set(jnp.where(do_split, child_ptr, -1))
-        node_gain = node_gain.at[frontier].set(gain_pos)
-        node_w = node_w.at[frontier].set(
-            jnp.where(live, s["leaf"]["w"], 0.0))
-        # pre-write child values at their (fresh, contiguous) pool slots
-        cvals = jnp.stack([lvals, rvals], axis=1).reshape(2 * L)
-        cmask = jnp.repeat(do_split, 2)
-        value = jax.lax.dynamic_update_slice(
-            value, jnp.where(cmask, cvals, 0.0), (base,))
-        cw = jnp.stack([s["left"]["w"], s["right"]["w"]],
-                       axis=1).reshape(2 * L)
-        node_w = jax.lax.dynamic_update_slice(
-            node_w, jnp.where(cmask, cw, 0.0), (base,))
+            varimp = varimp.at[s["col"]].add(
+                jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0))
+            # write this level's frontier nodes into the pool (scatter at
+            # traced pool ids; trash-slot writes are inert)
+            gain_pos = jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0)
+            child_ptr = base + 2 * jnp.arange(L, dtype=jnp.int32)
+            split_col = split_col.at[frontier].set(
+                jnp.where(do_split, s["col"], -1))
+            cat_choice = is_cat[s["col"]]
+            if adaptive:
+                thr_leaf = _numeric_thr(s, rlo, rhi, roff, Bd)
+                num_split = do_split & ~cat_choice
+                thr_pool = thr_pool.at[frontier].set(
+                    jnp.where(num_split, thr_leaf, -1))
+                na_pool = na_pool.at[frontier].set(num_split & s["na_left"])
+                bset_store = jnp.concatenate(
+                    [s["bitset"][:, :B], s["bitset"][:, Bd: Bd + 1]], axis=1)
+                bset_w = bset_store & (do_split & cat_choice)[:, None]
+            else:
+                thr_leaf = None
+                bset_w = s["bitset"] & do_split[:, None]
+            bitset = bitset.at[frontier].set(bset_w)
+            value = value.at[frontier].set(jnp.where(term, leaf_vals, 0.0))
+            child = child.at[frontier].set(jnp.where(do_split, child_ptr, -1))
+            node_gain = node_gain.at[frontier].set(gain_pos)
+            node_w = node_w.at[frontier].set(
+                jnp.where(live, s["leaf"]["w"], 0.0))
+            # pre-write child values at their (fresh, contiguous) pool slots
+            cvals = jnp.stack([lvals, rvals], axis=1).reshape(2 * L)
+            cmask = jnp.repeat(do_split, 2)
+            value = jax.lax.dynamic_update_slice(
+                value, jnp.where(cmask, cvals, 0.0), (base,))
+            cw = jnp.stack([s["left"]["w"], s["right"]["w"]],
+                           axis=1).reshape(2 * L)
+            node_w = jax.lax.dynamic_update_slice(
+                node_w, jnp.where(cmask, cw, 0.0), (base,))
 
         if d + 1 < D:
-            L_next = widths[d + 1]
-            # best-first frontier selection: keep the children with the
-            # most residual impurity; the rest are finished leaves
-            se_l = s["left"]["wgg"] - s["left"]["wg"] ** 2 / \
-                jnp.maximum(s["left"]["w"], EPS)
-            se_r = s["right"]["wgg"] - s["right"]["wg"] ** 2 / \
-                jnp.maximum(s["right"]["w"], EPS)
-            cse = jnp.stack([se_l, se_r], axis=1).reshape(2 * L)
-            ckey = jnp.where(cmask, jnp.maximum(cse, 0.0), -jnp.inf)
-            if 2 * L <= L_next:
-                sel = jnp.arange(2 * L, dtype=jnp.int32)  # identity: dense
-            else:
-                _, sel = jax.lax.top_k(ckey, L_next)
-                sel = sel.astype(jnp.int32)
-            sel_valid = jnp.take(ckey, sel) > -jnp.inf
-            frontier = jnp.where(sel_valid, base + sel, N)
-            inv = jnp.full((2 * L,), -1, jnp.int32).at[sel].set(
-                jnp.where(sel_valid,
-                          jnp.arange(L_next, dtype=jnp.int32), -1))
-            # route rows: split-parent rows follow the split to a child;
-            # rows whose child fell off the frontier finalize (-1)
-            active = slot >= 0
-            sl = jnp.maximum(slot, 0)
-            if cfg.get("mm_route") and 2 * L <= _MM_ROUTE_MAX_TABLE and \
-                    (Bd if adaptive else B) < _MM_ROUTE_MAX_TABLE:
-                go_left, do_sl = _mm_route_level(
-                    bins, sl, s, do_split, L, Bd if adaptive else B,
-                    cat_choice, adaptive, thr_leaf, F)
-                cand = 2 * sl + jnp.where(go_left, 0, 1)
-                candhot = cand[:, None] == jnp.arange(2 * L)[None, :]
-                inv_c = _mm_pick(candhot, inv.astype(jnp.float32)[:, None]
-                                 )[:, 0].astype(jnp.int32)
-            else:
-                c = s["col"][sl]
-                b = jnp.take_along_axis(bins, c[:, None], axis=1)[:, 0]
-                if adaptive:
-                    gset = s["bitset"][sl, jnp.minimum(b, Bd)]
-                    gthr = jnp.where(b == F, s["na_left"][sl],
-                                     b < thr_leaf[sl])
-                    go_left = jnp.where(cat_choice[sl], gset, gthr)
+            with jax.named_scope("h2o.tree.split"):
+                L_next = widths[d + 1]
+                # best-first frontier selection: keep the children with the
+                # most residual impurity; the rest are finished leaves
+                se_l = s["left"]["wgg"] - s["left"]["wg"] ** 2 / \
+                    jnp.maximum(s["left"]["w"], EPS)
+                se_r = s["right"]["wgg"] - s["right"]["wg"] ** 2 / \
+                    jnp.maximum(s["right"]["w"], EPS)
+                cse = jnp.stack([se_l, se_r], axis=1).reshape(2 * L)
+                ckey = jnp.where(cmask, jnp.maximum(cse, 0.0), -jnp.inf)
+                if 2 * L <= L_next:
+                    sel = jnp.arange(2 * L, dtype=jnp.int32)  # identity: dense
                 else:
-                    go_left = s["bitset"][sl, b]
-                do_sl = do_split[sl]
-                cand = 2 * sl + jnp.where(go_left, 0, 1)
-                inv_c = inv[cand]
-            new_slot = jnp.where(active & do_sl, inv_c, -1)
-            slot = jnp.where(active, new_slot, slot)
-            if use_mono:
-                lo_b = jnp.take(lo_c, sel)
-                hi_b = jnp.take(hi_c, sel)
-            if adaptive:
-                new_lo, new_hi = _refine_ranges(hist_f, rlo, rhi, roff,
-                                                Bd)
-                clo, chi = _child_ranges(new_lo, new_hi, s, thr_leaf,
-                                         is_cat, do_split)
-                rlo = jnp.take(clo, sel, axis=0)
-                rhi = jnp.take(chi, sel, axis=0)
+                    _, sel = jax.lax.top_k(ckey, L_next)
+                    sel = sel.astype(jnp.int32)
+                sel_valid = jnp.take(ckey, sel) > -jnp.inf
+                frontier = jnp.where(sel_valid, base + sel, N)
+                inv = jnp.full((2 * L,), -1, jnp.int32).at[sel].set(
+                    jnp.where(sel_valid,
+                              jnp.arange(L_next, dtype=jnp.int32), -1))
+            with jax.named_scope("h2o.tree.route"):
+                # route rows: split-parent rows follow the split to a child;
+                # rows whose child fell off the frontier finalize (-1)
+                active = slot >= 0
+                sl = jnp.maximum(slot, 0)
+                if cfg.get("mm_route") and 2 * L <= _MM_ROUTE_MAX_TABLE and \
+                        (Bd if adaptive else B) < _MM_ROUTE_MAX_TABLE:
+                    go_left, do_sl = _mm_route_level(
+                        bins, sl, s, do_split, L, Bd if adaptive else B,
+                        cat_choice, adaptive, thr_leaf, F)
+                    cand = 2 * sl + jnp.where(go_left, 0, 1)
+                    candhot = cand[:, None] == jnp.arange(2 * L)[None, :]
+                    inv_c = _mm_pick(candhot, inv.astype(jnp.float32)[:, None]
+                                     )[:, 0].astype(jnp.int32)
+                else:
+                    c = s["col"][sl]
+                    b = jnp.take_along_axis(bins, c[:, None], axis=1)[:, 0]
+                    if adaptive:
+                        gset = s["bitset"][sl, jnp.minimum(b, Bd)]
+                        gthr = jnp.where(b == F, s["na_left"][sl],
+                                         b < thr_leaf[sl])
+                        go_left = jnp.where(cat_choice[sl], gset, gthr)
+                    else:
+                        go_left = s["bitset"][sl, b]
+                    do_sl = do_split[sl]
+                    cand = 2 * sl + jnp.where(go_left, 0, 1)
+                    inv_c = inv[cand]
+                new_slot = jnp.where(active & do_sl, inv_c, -1)
+                slot = jnp.where(active, new_slot, slot)
+            with jax.named_scope("h2o.tree.split"):
+                if use_mono:
+                    lo_b = jnp.take(lo_c, sel)
+                    hi_b = jnp.take(hi_c, sel)
+                if adaptive:
+                    new_lo, new_hi = _refine_ranges(hist_f, rlo, rhi, roff,
+                                                    Bd)
+                    clo, chi = _child_ranges(new_lo, new_hi, s, thr_leaf,
+                                             is_cat, do_split)
+                    rlo = jnp.take(clo, sel, axis=0)
+                    rhi = jnp.take(chi, sel, axis=0)
         prev_hist, prev_do = hist, do_split
         base += 2 * L
 
@@ -705,6 +717,7 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
             node_gain[:N], node_w[:N], thr_pool[:N], na_pool[:N])
 
 
+@jax.named_scope("h2o.tree.predict")
 def _tree_predict(bins, split_col, bitset, value, D: int, child=None,
                   thr=None, na_l=None, fine_na: int = -1,
                   mm: bool = False):
@@ -1001,19 +1014,22 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
 
     def tree_step(F, xs):
         t_idx, key_t = xs
-        ks, kc, kcol = jax.random.split(key_t, 3)
-        if col_sample_rate_per_tree < 1.0:
-            # per-TREE column subsample (colsample_bytree); keep >= 1 col
-            rc = jax.random.uniform(kcol, (C,))
-            kth = jnp.sort(rc)[max(
-                1, int(round(col_sample_rate_per_tree * C))) - 1]
-            tree_cols = rc <= kth
-        else:
-            tree_cols = None
-        samp = jnp.where(
-            jax.random.uniform(ks, (R,)) < sample_rate, True, False) \
-            if sample_rate < 1.0 else jnp.ones((R,), bool)
-        leaf0 = jnp.where(samp & active, 0, -1).astype(jnp.int32)
+        # the tree's inputs: its keys, row/column samples, and (below)
+        # the per-row statistics
+        with jax.named_scope("h2o.tree.stats"):
+            ks, kc, kcol = jax.random.split(key_t, 3)
+            if col_sample_rate_per_tree < 1.0:
+                # per-TREE column subsample (colsample_bytree); keep >= 1
+                rc = jax.random.uniform(kcol, (C,))
+                kth = jnp.sort(rc)[max(
+                    1, int(round(col_sample_rate_per_tree * C))) - 1]
+                tree_cols = rc <= kth
+            else:
+                tree_cols = None
+            samp = jnp.where(
+                jax.random.uniform(ks, (R,)) < sample_rate, True, False) \
+                if sample_rate < 1.0 else jnp.ones((R,), bool)
+            leaf0 = jnp.where(samp & active, 0, -1).astype(jnp.int32)
         scale = learn_rate * (learn_rate_annealing ** t_idx) \
             if mode == "gbm" else 1.0
         if mode == "gbm" and dist_name == "multinomial":
@@ -1021,17 +1037,18 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
         scs, bss, vls, chs, preds, vis, gns, nws, ths, nas = \
             [], [], [], [], [], [], [], [], [], []
         for kcls in range(K):                    # static unroll over classes
-            kc, kk = jax.random.split(kc)
-            stats = stats_for(kcls, F)
-            if stats_dtype != "f32":
-                # quantize ONCE per (tree, class) against the per-class
-                # key kk — which descends from the absolute-tree-index
-                # fold_in below, so any block partition and any mesh
-                # shape draws the identical rounding noise
-                stats, inv_sc = statpack.quantize_stats(
-                    stats, kk, stats_dtype, qmax)
-            else:
-                inv_sc = None
+            with jax.named_scope("h2o.tree.stats"):
+                kc, kk = jax.random.split(kc)
+                stats = stats_for(kcls, F)
+                if stats_dtype != "f32":
+                    # quantize ONCE per (tree, class) against the per-class
+                    # key kk — which descends from the absolute-tree-index
+                    # fold_in below, so any block partition and any mesh
+                    # shape draws the identical rounding noise
+                    stats, inv_sc = statpack.quantize_stats(
+                        stats, kk, stats_dtype, qmax)
+                else:
+                    inv_sc = None
             if kleaves > 0:
                 sc, bs, vl, ch, vi, gn, nw, th, na = build_tree_frontier(
                     bins, stats, leaf0, kk, is_cat, cfg, tree_cols,
@@ -1041,7 +1058,8 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
                     bins, stats, leaf0, kk, is_cat, cfg, tree_cols,
                     mono=mono, inv_scale=inv_sc)
                 ch = None
-            vl = vl * scale
+            with jax.named_scope("h2o.tree.split"):
+                vl = vl * scale
             scs.append(sc)
             bss.append(bs)
             vls.append(vl)
@@ -1055,12 +1073,14 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
                 bins, sc, bs, vl, max_depth, child=ch, thr=th, na_l=na,
                 fine_na=int(cfg.get("fine_nbins") or nbins),
                 mm=bool(cfg.get("mm_route"))))
-        F = F + jnp.stack(preds, axis=1)
-        out = (jnp.stack(scs), jnp.stack(bss), jnp.stack(vls),
-               sum(vis), jnp.stack(gns), jnp.stack(nws),
-               jnp.stack(ths), jnp.stack(nas))
-        if kleaves > 0:
-            out = out + (jnp.stack(chs),)
+        with jax.named_scope("h2o.tree.predict"):
+            F = F + jnp.stack(preds, axis=1)
+        with jax.named_scope("h2o.tree.split"):
+            out = (jnp.stack(scs), jnp.stack(bss), jnp.stack(vls),
+                   sum(vis), jnp.stack(gns), jnp.stack(nws),
+                   jnp.stack(ths), jnp.stack(nas))
+            if kleaves > 0:
+                out = out + (jnp.stack(chs),)
         return F, out
 
     # Per-tree keys fold the ABSOLUTE tree index into the forest master
@@ -1070,16 +1090,18 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
     # ladder (models/tree/driver.py) — reproduces the identical forest
     # bit for bit.  t0 stays a TRACED scalar: per-block calls with
     # varying tree offsets reuse one compiled program.
-    ti = jnp.arange(ntrees, dtype=jnp.int32) + jnp.int32(t0)
-    keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(ti)
-    ts = ti.astype(jnp.float32)
+    with jax.named_scope("h2o.tree.stats"):
+        ti = jnp.arange(ntrees, dtype=jnp.int32) + jnp.int32(t0)
+        keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(ti)
+        ts = ti.astype(jnp.float32)
     F_final, outs = jax.lax.scan(tree_step, F0, (ts, keys))
     if kleaves > 0:
         sc, bs, vl, vi, gn, nw, th, na, ch = outs
     else:
         (sc, bs, vl, vi, gn, nw, th, na), ch = outs, None
-    return TrainedForest(sc, bs, vl, F_final, jnp.sum(vi, axis=0), gn, nw,
-                         th, na, ch)
+    with jax.named_scope("h2o.tree.split"):
+        vi = jnp.sum(vi, axis=0)
+    return TrainedForest(sc, bs, vl, F_final, vi, gn, nw, th, na, ch)
 
 
 # The donating/non-donating executable pair over this one traced body

@@ -40,6 +40,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from h2o_tpu.core.cloud import cloud, shard_map_compat
+from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import Frame
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
 from h2o_tpu.ops.binpack import (bins_bucket, bins_pack_enabled, cast_bins,
@@ -70,6 +71,7 @@ class BinnedData(NamedTuple):
 
 
 @functools.partial(jax.jit, static_argnames=("nbins",))
+@jax.named_scope("h2o.bin.quantile")
 def _quantile_split_points(matrix, nrows, nbins: int):
     """Per-column quantile split points via ONE batched sort.
 
@@ -99,6 +101,7 @@ def resolve_histogram_type(p) -> str:
     return "UniformAdaptive" if ht == "AUTO" else ht
 
 
+@TimeLine.span("train", "bin")
 def prepare_bins(di: DataInfo, nbins: int, nbins_cats: int,
                  histogram_type: str = "QuantilesGlobal",
                  nbins_top_level: int = 1024) -> BinnedData:
@@ -174,6 +177,7 @@ def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int):
 
 
 @functools.partial(jax.jit, static_argnames=("nbins", "out_dtype"))
+@jax.named_scope("h2o.bin.assign")
 def _bin_all(matrix, split_points, is_cat, nbins: int,
              out_dtype: str = "int32"):
     """Raw values -> bin indices in [0, nbins]; nbins = NA bucket.
@@ -204,6 +208,7 @@ def _bin_all(matrix, split_points, is_cat, nbins: int,
 
 
 @jax.jit
+@jax.named_scope("h2o.bin.quantile")
 def _col_min_max(matrix, nrows):
     """Per-column (min, max) over valid rows, NaN-blind — the uniform
     fine grid's span (DHistogram find_maxEx/min analog)."""
@@ -381,6 +386,7 @@ def rng_key_from_np(data: np.ndarray):
 
 @functools.partial(jax.jit, static_argnames=("min_rows", "use_mono",
                                              "newton", "reg_lambda"))
+@jax.named_scope("h2o.tree.split")
 def find_splits(hist, is_cat, col_allowed, min_rows: float = 10.0,
                 min_split_improvement: float = 1e-5, mono=None,
                 use_mono: bool = False, newton: bool = False,
@@ -546,6 +552,7 @@ def _go_left(bs, node, b, th, na, fine_na: int, B: int):
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "fine_na"))
+@jax.named_scope("h2o.score.descent")
 def forest_score(bins, split_col, bitset, value, depth: int, child=None,
                  thr=None, na_l=None, fine_na: int = -1):
     """Sum of tree outputs per (row, k-slot): bins (R,C) -> (R, K).
@@ -560,6 +567,7 @@ def forest_score(bins, split_col, bitset, value, depth: int, child=None,
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "fine_na"))
+@jax.named_scope("h2o.score.descent")
 def forest_tree_values(bins, split_col, bitset, value, depth: int,
                        child=None, thr=None, na_l=None, fine_na: int = -1):
     """Per-TREE outputs (T, K, R) — forest_score without the sum, for

@@ -126,36 +126,40 @@ def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
     C = bins_blk.shape[1]
     S = stats_blk.shape[1]
     quantized = jnp.issubdtype(stats_blk.dtype, jnp.integer)
-    leafhot = (leaf_blk[:, None] == jnp.arange(n_leaves)[None, :])
-    # zero stats of inactive rows BEFORE the product: padded rows carry NaN
-    # payloads and 0 * NaN would poison the accumulator (the quantized
-    # carrier has no NaN, but padded rows still must not count; the weak
-    # 0 keeps the carrier dtype)
-    stats_blk = jnp.where(leaf_blk[:, None] >= 0, stats_blk, 0)
-    a = (leafhot[:, :, None] * stats_blk[:, None, :]).reshape(
-        -1, n_leaves * S)                                     # (R, L*S)
-    binhot = (bins_blk[:, :, None] ==
-              jnp.arange(B1)[None, None, :]).reshape(-1, C * B1)  # (R, C*B1)
-    if quantized:
-        # integer MXU path: one-hot cast to the SAME narrow carrier
-        # in-register (values are 0/1 — exact), int32 accumulator.
-        # Overflow-free by construction: statpack.stats_qmax bounds
-        # |q| * rows below 2**31.
+    with jax.named_scope("h2o.tree.hist.onehot"):
+        leafhot = (leaf_blk[:, None] == jnp.arange(n_leaves)[None, :])
+        # zero stats of inactive rows BEFORE the product: padded rows carry
+        # NaN payloads and 0 * NaN would poison the accumulator (the
+        # quantized carrier has no NaN, but padded rows still must not
+        # count; the weak 0 keeps the carrier dtype)
+        stats_blk = jnp.where(leaf_blk[:, None] >= 0, stats_blk, 0)
+        a = (leafhot[:, :, None] * stats_blk[:, None, :]).reshape(
+            -1, n_leaves * S)                                 # (R, L*S)
+        binhot = (bins_blk[:, :, None] ==
+                  jnp.arange(B1)[None, None, :]).reshape(-1, C * B1)
+        # (R, C*B1)
+    with jax.named_scope("h2o.tree.hist.contract"):
+        if quantized:
+            # integer MXU path: one-hot cast to the SAME narrow carrier
+            # in-register (values are 0/1 — exact), int32 accumulator.
+            # Overflow-free by construction: statpack.stats_qmax bounds
+            # |q| * rows below 2**31.
+            return jax.lax.dot_general(
+                binhot.astype(stats_blk.dtype), a,
+                dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32)             # (C*B1, L*S)
+        # the one-hot side is exact in any dtype; HIGHEST keeps the f32
+        # stats side f32 — a TPU's default precision rounds f32 operands
+        # to bf16, which is what ``bf16`` asks for and f32 must not get
         return jax.lax.dot_general(
-            binhot.astype(stats_blk.dtype), a,
+            binhot.astype(mm_dtype), a.astype(mm_dtype),
             dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)                 # (C*B1, L*S)
-    # the one-hot side is exact in any dtype; HIGHEST keeps the f32
-    # stats side f32 — a TPU's default precision rounds f32 operands to
-    # bf16, which is what ``bf16`` asks for and f32 must not get
-    return jax.lax.dot_general(
-        binhot.astype(mm_dtype), a.astype(mm_dtype),
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST
-        if mm_dtype == jnp.float32 else None)                 # (C*B1, L*S)
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST
+            if mm_dtype == jnp.float32 else None)             # (C*B1, L*S)
 
 
+@jax.named_scope("h2o.tree.hist.onehot")
 def map_buckets(bins_blk, leaf_blk, lo, hi, off, is_cat, nbins: int,
                 fine_na: int):
     """Fine bins -> per-NODE histogram buckets (UniformAdaptive/Random).
@@ -281,9 +285,13 @@ def histogram_build_traced(bins, leaf, stats, n_leaves: int, nbins: int,
                 l_sh[nblk * blk:], s_sh[nblk * blk:], n_leaves, nbins, mmd)
         return hpsum(acc, "hist.table")
 
-    h = run(bins, leaf, stats, *extra)              # (C*B1, L*S)
-    return (h.reshape(C, B1, n_leaves, S)
-             .transpose(2, 0, 1, 3))                # (L, C, B+1, S)
+    # the block scan, its accumulator, the cross-node combine and the
+    # table's relayout are the contraction's; the one-hot build inside
+    # carries its own, deeper scope
+    with jax.named_scope("h2o.tree.hist.contract"):
+        h = run(bins, leaf, stats, *extra)          # (C*B1, L*S)
+        return (h.reshape(C, B1, n_leaves, S)
+                 .transpose(2, 0, 1, 3))            # (L, C, B+1, S)
 
 
 _histogram_build_jit = jax.jit(
