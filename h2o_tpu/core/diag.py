@@ -282,9 +282,10 @@ class TimeLine:
         host duration (``dur_ns``), its ``id``, the ``parent`` span open
         on this thread and the ``job`` key — given by a root span,
         inherited by everything opened under it.  The block also runs
-        under ``TraceAnnotation("h2o:<kind>.<what>")``: while a profile
-        is being taken the span lies in its host plane on the device
-        events' clock; otherwise that is a flag test."""
+        under ``TraceAnnotation("h2o:<kind>.<what>", **info)``: while a
+        profile is being taken the span lies in its host plane on the
+        device events' clock, ``info`` among its stats; otherwise that
+        is a flag test."""
         stack = getattr(cls._open, "stack", None)
         if stack is None:
             stack = cls._open.stack = []
@@ -295,7 +296,7 @@ class TimeLine:
         stack.append((sid, job))
         ns, t0 = time.time_ns(), time.perf_counter_ns()
         try:
-            with TraceAnnotation(f"h2o:{kind}.{what}"):
+            with TraceAnnotation(f"h2o:{kind}.{what}", **info):
                 yield
         finally:
             dur = time.perf_counter_ns() - t0

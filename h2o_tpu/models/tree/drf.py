@@ -242,7 +242,7 @@ class DRF(ModelBuilder):
                                 prior_trees=prior,
                                 recovery=getattr(self, "_recovery", None),
                                 data_frame=train)
-        with TimeLine.span("train", "final_metrics"):
+        with TimeLine.span("train", "final_metrics", source="rescore"):
             model.output["training_metrics"] = model.model_metrics(train)
             if valid is not None:
                 model.output["validation_metrics"] = \

@@ -8,7 +8,10 @@ TPU-native: gradients/hessians are one fused jit over the row-sharded f
 array; trees come from h2o_tpu.models.tree.shared_tree (MXU histogram +
 vectorized split finding, leaf Newton values fused into the histogram);
 the f update is a single-tree forest_score.  Multinomial builds K trees
-per iteration on softmax gradients with the (K-1)/K scaling.
+per iteration on softmax gradients with the (K-1)/K scaling.  The
+training metrics that end ``_fit`` are read from that carried f (the
+build's own predictions, as the reference takes them), not from a
+BigScore of the finished forest.
 """
 
 from __future__ import annotations
@@ -267,7 +270,14 @@ class GBM(ModelBuilder):
         sp_np = np.asarray(binned.split_points)
         ic_np = np.asarray(binned.is_cat)
 
+        # the driver hands make_model the F it carried through the last
+        # kept block: f0 + offset + checkpoint forest + every new tree, on
+        # every row of ``train`` — what _forest_F(train) would recompute
+        F_train = None
+
         def make_model(sc, bs, vl, ch, n_new, F_final):
+            nonlocal F_train
+            F_train = F_final
             if ckpt is not None:
                 sc = np.concatenate([co["split_col"], sc]) if n_new \
                     else np.asarray(co["split_col"])
@@ -382,8 +392,9 @@ class GBM(ModelBuilder):
             # per-tree inner fits (DART driver) discard these; the outer
             # loop scores the final concatenated forest once
             return model
-        with TimeLine.span("train", "final_metrics"):
-            model.output["training_metrics"] = model.model_metrics(train)
+        with TimeLine.span("train", "final_metrics", source="carried_F"):
+            model.output["training_metrics"] = model.metrics_from_raw(
+                model._raw_from_F(F_train), train)
             if valid is not None:
                 model.output["validation_metrics"] = \
                     model.model_metrics(valid)

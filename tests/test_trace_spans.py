@@ -116,13 +116,14 @@ def test_two_job_threads_do_not_share_a_stack():
 
 
 def test_span_lies_in_a_profile_as_trace_annotation(monkeypatch):
-    """The block runs under ``TraceAnnotation("h2o:<kind>.<what>")``."""
+    """The block runs under ``TraceAnnotation("h2o:<kind>.<what>")``,
+    which takes the span's ``info`` as the event's stats."""
     from h2o_tpu.core import diag
     seen = []
 
     class Spy:
-        def __init__(self, name):
-            seen.append(name)
+        def __init__(self, name, **stats):
+            seen.append((name, stats))
 
         def __enter__(self):
             return self
@@ -133,7 +134,10 @@ def test_span_lies_in_a_profile_as_trace_annotation(monkeypatch):
     monkeypatch.setattr(diag, "TraceAnnotation", Spy)
     with TimeLine.span("train", "block.pull"):
         pass
-    assert seen == ["h2o:train.block.pull"]
+    with TimeLine.span("train", "final_metrics", source="carried_F"):
+        pass
+    assert seen == [("h2o:train.block.pull", {}),
+                    ("h2o:train.final_metrics", {"source": "carried_F"})]
 
 
 # ------------------------------------------------------- a training's tree
