@@ -574,7 +574,8 @@ def _route_gather_impl(bins, lf, col, bitset, na_left, do_split, thr,
     """The engine's per-level GATHER router (build_tree_* adaptive
     path) mirrored 1:1 — the reference the matmul router must match
     bitwise."""
-    b = jnp.take_along_axis(bins, col[lf][:, None], axis=1)[:, 0]
+    from h2o_tpu.ops.binpack import pick_bin
+    b = pick_bin(bins, col[lf])
     gset = bitset[lf, jnp.minimum(b, Bd)] > 0.5
     gthr = jnp.where(b == Bd, na_left[lf] > 0.5, b < thr[lf])
     go = jnp.where(cat_choice[lf], gset, gthr)

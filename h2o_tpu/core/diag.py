@@ -285,7 +285,9 @@ class TimeLine:
         under ``TraceAnnotation("h2o:<kind>.<what>", **info)``: while a
         profile is being taken the span lies in its host plane on the
         device events' clock, ``info`` among its stats; otherwise that
-        is a flag test."""
+        is a flag test.  ``with ... as fields`` hands the block the
+        event's own dict: what it counts while open (host work on data
+        it already holds) lands in the ring event, not in the profile."""
         stack = getattr(cls._open, "stack", None)
         if stack is None:
             stack = cls._open.stack = []
@@ -297,7 +299,7 @@ class TimeLine:
         ns, t0 = time.time_ns(), time.perf_counter_ns()
         try:
             with TraceAnnotation(f"h2o:{kind}.{what}", **info):
-                yield
+                yield info
         finally:
             dur = time.perf_counter_ns() - t0
             stack.pop()

@@ -25,6 +25,7 @@ import numpy as np
 from h2o_tpu.core.frame import Frame, Vec
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
 from h2o_tpu.models.tree import shared_tree as st
+from h2o_tpu.ops.binpack import pick_bin
 
 
 @functools.partial(jax.jit, static_argnames=("depth",))
@@ -39,8 +40,7 @@ def _terminal_nodes(bins, split_col, bitset, depth: int):
         for _ in range(depth):
             c = sc[node]
             term = c < 0
-            b = jnp.take_along_axis(bins, jnp.maximum(c, 0)[:, None],
-                                    axis=1)[:, 0]
+            b = pick_bin(bins, jnp.maximum(c, 0))
             go_left = bs[node, b]
             nxt = 2 * node + jnp.where(go_left, 1, 2)
             node = jnp.where(term, node, nxt)
@@ -93,8 +93,7 @@ class RuleFitModel(Model):
         """Rule + linear feature frame for the inner GLM."""
         out = self.output
         m = frame.as_matrix(out["x"])
-        bins = st.bin_matrix(m, jnp.asarray(out["split_points"]),
-                             out["is_cat"], int(out["nbins"]))
+        bins = st.bin_matrix_out(m, out)
         cols: List[Vec] = []
         names: List[str] = []
         for fi, f in enumerate(out["forests"]):
@@ -207,6 +206,7 @@ class RuleFit(ModelBuilder):
             if model_type in ("rules_and_linear", "linear") else []
         out_proto = dict(x=list(di.x), split_points=binned.split_points,
                          is_cat=binned.is_cat, nbins=binned.nbins,
+                         col_nbins=binned.col_nbins,
                          forests=forests, linear_names=linear_names,
                          response_domain=di.response_domain
                          if nclass >= 2 else None)

@@ -37,9 +37,7 @@ from h2o_tpu.models.tree import shared_tree as st
 def _binned(model, frame: Frame) -> np.ndarray:
     out = model.output
     m = frame.as_matrix(out["x"])
-    return np.asarray(st.bin_matrix(
-        m, jnp.asarray(out["split_points"]), out["is_cat"],
-        st.model_fine_na(out)))
+    return np.asarray(st.bin_matrix_out(m, out))
 
 
 def _forest_arrays(model, need_cover: bool = True):

@@ -48,9 +48,7 @@ class DRFModel(Model):
         output['x'] order, no Frame/DKV."""
         out = self.output
         m = jnp.asarray(X, jnp.float32)
-        bins = st.bin_matrix(m, jnp.asarray(out["split_points"]),
-                             out["is_cat"], st.model_fine_na(out))
-        F = st.forest_score_out(bins, out)
+        F = st.forest_score_out(st.bin_matrix_out(m, out), out)
         return raw_from_votes(F, int(out["ntrees_actual"]),
                               out.get("response_domain"),
                               threshold=float(out.get(
@@ -103,10 +101,10 @@ class DRF(ModelBuilder):
             sp_dev = jnp.asarray(co["split_points"])
             binned = st.BinnedData(
                 st.bin_matrix(train.as_matrix(di.x), sp_dev,
-                              co["is_cat"], ck_fine),
+                              co["is_cat"], ck_fine, co.get("col_nbins")),
                 np.asarray(co["split_points"]), sp_dev,
                 np.asarray(co["is_cat"]), int(co["nbins"]), ck_fine,
-                hist_type)
+                hist_type, co.get("col_nbins"))
         else:
             binned = st.prepare_bins(
                 di, int(p["nbins"]), int(p["nbins_cats"]), hist_type,
@@ -164,7 +162,7 @@ class DRF(ModelBuilder):
             out = dict(
                 x=list(di.x), split_points=sp_np, is_cat=ic_np,
                 nbins=binned.nbins, fine_nbins=binned.fine,
-                hist_type=binned.hist_type,
+                col_nbins=binned.col_nbins, hist_type=binned.hist_type,
                 split_col=sc, bitset=bs, value=vl,
                 child=ch,
                 max_depth=depth, effective_max_depth=depth,
@@ -216,7 +214,7 @@ class DRF(ModelBuilder):
             score_frame = valid if valid is not None else train
             bins_sc = bins if valid is None else st.bin_matrix(
                 valid.as_matrix(di.x), binned.split_points_dev,
-                binned.is_cat, binned.fine)
+                binned.is_cat, binned.fine, binned.col_nbins)
             F_sc = jnp.zeros((bins_sc.shape[0], K), jnp.float32)
             if prior:
                 F_sc = F_sc + st.forest_score_out(bins_sc, co, depth)

@@ -177,6 +177,18 @@ def _block_nbytes(tf) -> int:
                for a in (getattr(tf, name),) if a is not None)
 
 
+def _split_counts(sc, bs, th, na, is_cat) -> Dict[str, int]:
+    """A pulled block's split nodes, counted on the host from the arrays
+    the pull already holds: how many there are, how many sit on a
+    categorical column, how many send their NA bucket left (a bitset
+    split says so in its last bit, a threshold split in ``na_left``)."""
+    split = sc >= 0
+    na_left = np.where(th >= 0, na, bs[..., -1])
+    return {"num_splits": int(split.sum()),
+            "cat_splits": int(is_cat[sc[split]].sum()),
+            "na_left_splits": int((na_left & split).sum())}
+
+
 def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                     make_model: Callable,
                     scorer: Optional[IncrementalScorer],
@@ -276,6 +288,9 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         block = min(interval, ckpt_every) if ckpt_every else interval
     else:
         block = ckpt_every or max(1, min(ntrees, 10))
+    # host copy of a (C,) flag the builder made from a numpy array: read
+    # once, before any block is queued
+    is_cat_host = np.asarray(train_kwargs["is_cat"], bool)
     lists = {n: [] for n in _CKPT_LISTS}
     scs, bss, vls, chs = (lists[n] for n in ("scs", "bss", "vls", "chs"))
     gns, nws, ths, nas = (lists[n] for n in ("gns", "nws", "ths", "nas"))
@@ -381,7 +396,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         # already queued, so "score" also waits for t+1's build
         with TimeLine.span("train", "block.absorb",
                            t0=prior_trees + cur["off"], n=n):
-            with TimeLine.span("train", "block.pull"):
+            with TimeLine.span("train", "block.pull") as pulled:
                 chaos().maybe_slow_transfer("tree_block")
                 scs.append(np.asarray(tf.split_col))
                 bss.append(np.asarray(tf.bitset))
@@ -393,6 +408,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                 ths.append(np.asarray(tf.thr_bin))
                 nas.append(np.asarray(tf.na_left))
                 vi = np.asarray(tf.varimp)
+                pulled.update(_split_counts(scs[-1], bss[-1], ths[-1],
+                                            nas[-1], is_cat_host))
             TimeLine.record("dispatch", "tree_block_materialize",
                             t0=prior_trees + cur["off"], n=n)
             DispatchStats.note_transfer("tree_block", _block_nbytes(tf))

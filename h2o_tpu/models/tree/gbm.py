@@ -59,9 +59,7 @@ class GBMModel(Model):
         """(rows, C) raw-code matrix -> link-scale forest sum (shared by
         the Frame path and the online array fast path)."""
         out = self.output
-        bins = st.bin_matrix(m, jnp.asarray(out["split_points"]),
-                             out["is_cat"], st.model_fine_na(out))
-        return st.forest_score_out(bins, out) + \
+        return st.forest_score_out(st.bin_matrix_out(m, out), out) + \
             jnp.asarray(out["f0"])[None, :]
 
     def _raw_from_F(self, F) -> jax.Array:
@@ -175,10 +173,10 @@ class GBM(ModelBuilder):
             sp_dev = jnp.asarray(co["split_points"])
             binned = st.BinnedData(
                 st.bin_matrix(train.as_matrix(di.x), sp_dev,
-                              co["is_cat"], ck_fine),
+                              co["is_cat"], ck_fine, co.get("col_nbins")),
                 np.asarray(co["split_points"]), sp_dev,
                 np.asarray(co["is_cat"]), int(co["nbins"]), ck_fine,
-                hist_type)
+                hist_type, co.get("col_nbins"))
         else:
             binned = st.prepare_bins(
                 di, int(p["nbins"]), int(p["nbins_cats"]), hist_type,
@@ -291,7 +289,7 @@ class GBM(ModelBuilder):
             out = dict(
                 x=list(di.x), split_points=sp_np, is_cat=ic_np,
                 nbins=binned.nbins, fine_nbins=binned.fine,
-                hist_type=binned.hist_type,
+                col_nbins=binned.col_nbins, hist_type=binned.hist_type,
                 split_col=sc, bitset=bs, value=vl,
                 child=ch,
                 max_depth=depth, f0=f0_out, effective_max_depth=depth,
@@ -355,7 +353,7 @@ class GBM(ModelBuilder):
             score_frame = valid if valid is not None else train
             bins_sc = bins if valid is None else st.bin_matrix(
                 valid.as_matrix(di.x), binned.split_points_dev,
-                binned.is_cat, binned.fine)
+                binned.is_cat, binned.fine, binned.col_nbins)
             F_sc = jnp.broadcast_to(
                 f0[None, :], (bins_sc.shape[0], K)).astype(jnp.float32)
             off_col = p.get("offset_column")

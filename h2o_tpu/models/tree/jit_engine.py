@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from h2o_tpu.models.distributions import get_distribution
 from h2o_tpu.models.tree.shared_tree import find_splits
 from h2o_tpu.ops import statpack
+from h2o_tpu.ops.binpack import pick_bin
 from h2o_tpu.ops.histogram import histogram_build_traced as _shard_histogram
 
 EPS = 1e-10
@@ -473,7 +474,7 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
                     cat_choice, adaptive, thr_leaf, F)
             else:
                 c = s["col"][lf]
-                b = jnp.take_along_axis(bins, c[:, None], axis=1)[:, 0]
+                b = pick_bin(bins, c)
                 if adaptive:
                     gset = s["bitset"][lf, jnp.minimum(b, Bd)]
                     gthr = jnp.where(b == F, s["na_left"][lf],
@@ -686,7 +687,7 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
                                      )[:, 0].astype(jnp.int32)
                 else:
                     c = s["col"][sl]
-                    b = jnp.take_along_axis(bins, c[:, None], axis=1)[:, 0]
+                    b = pick_bin(bins, c)
                     if adaptive:
                         gset = s["bitset"][sl, jnp.minimum(b, Bd)]
                         gthr = jnp.where(b == F, s["na_left"][sl],
@@ -770,8 +771,7 @@ def _tree_predict(bins, split_col, bitset, value, D: int, child=None,
             from h2o_tpu.models.tree.shared_tree import _go_left
             c = split_col[node]
             term = c < 0
-            b = jnp.take_along_axis(bins, jnp.maximum(c, 0)[:, None],
-                                    axis=1)[:, 0]
+            b = pick_bin(bins, jnp.maximum(c, 0))
             go_left = _go_left(bitset, node, b, thr, na_l, fine_na, B)
             if child is None:
                 nxt = 2 * node + jnp.where(go_left, 1, 2)

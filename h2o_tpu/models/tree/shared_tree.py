@@ -44,7 +44,7 @@ from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import Frame
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
 from h2o_tpu.ops.binpack import (bins_bucket, bins_pack_enabled, cast_bins,
-                                 packed_dtype_name)
+                                 packed_dtype_name, pick_bin)
 from h2o_tpu.ops.histogram import histogram_build
 
 EPS = 1e-10
@@ -64,6 +64,11 @@ class BinnedData(NamedTuple):
     # top-level grid, reference nbins_top_level; QuantilesGlobal: F == B)
     fine_nbins: int = 0
     hist_type: str = "QuantilesGlobal"
+    # (C,) int32: how many bins each column really has inside the one
+    # table — a numeric column the stated ``nbins``, a categorical one a
+    # bin per level (capped at nbins_cats); codes at or past it are
+    # unseen levels and bin to the NA bucket
+    col_nbins: Optional[np.ndarray] = None
 
     @property
     def fine(self) -> int:
@@ -101,13 +106,12 @@ def resolve_histogram_type(p) -> str:
     return "UniformAdaptive" if ht == "AUTO" else ht
 
 
-@TimeLine.span("train", "bin")
 def prepare_bins(di: DataInfo, nbins: int, nbins_cats: int,
                  histogram_type: str = "QuantilesGlobal",
                  nbins_top_level: int = 1024) -> BinnedData:
     """Feature binning for the tree engines.
 
-    QuantilesGlobal: per-column global quantile grid of ``nbins``
+    QuantilesGlobal: per-column global quantile grid of ``nbins - 1``
     thresholds (the one-shot batched sort) — F == B.
 
     UniformAdaptive / Random (reference DHistogram.java:19-62 AUTO
@@ -117,45 +121,62 @@ def prepare_bins(di: DataInfo, nbins: int, nbins_cats: int,
     refining resolution every level exactly like the reference's
     per-node DHistogram ranges (nbins_top_level halving schedule).
 
-    Categorical columns always bin by level code; F >= B so codes and
-    the NA sentinel (F) coexist in one packed matrix (uint8/int16/int32
-    by F under the ``tree.bins_dtype`` lever — ops/binpack.py).
+    Categorical columns always bin by level code, one bin a level up to
+    ``nbins_cats`` (a code at or past that count — a level past the cap,
+    or one the training domain does not hold — bins to the NA bucket).
+    All columns share ONE table of width B = max(nbins, widest
+    categorical): a numeric column fills its first ``nbins`` bins of it
+    whatever B is (``col_nbins`` says which column has how many), and
+    F >= B so codes and the NA sentinel (F) coexist in one packed matrix
+    (uint8/int16/int32 by F under the ``tree.bins_dtype`` lever —
+    ops/binpack.py: a table wider than 255 bins rides int16).  The
+    adaptive grids place B buckets a node on every column (their bucket
+    count is one number per level, not per column).
     """
     fr, xs = di.frame, di.x
     C = len(xs)
-    max_card = max([fr.vec(c).cardinality for c in di.cat_names] or [0])
-    B = max(nbins, min(max_card, nbins_cats))
     is_cat = np.array([fr.vec(c).is_categorical for c in xs], bool)
-    if (histogram_type in ("UniformAdaptive", "Random")
-            and _stream_blocks_enabled(fr, xs)):
-        # frame bigger than the HBM budget: never materialize the full
-        # matrix — stream shard-aligned windows through binning instead
-        return _prepare_bins_streamed(fr, xs, is_cat, B,
-                                      max(int(nbins_top_level), B),
-                                      histogram_type)
-    m = fr.as_matrix(xs)
-    if histogram_type in ("UniformAdaptive", "Random"):
-        F = max(int(nbins_top_level), B)
-        mn = np.asarray(_col_min_max(m, jnp.int32(fr.nrows)))
-        sp = _uniform_split_points(mn[0], mn[1], is_cat, C, F)
-    else:
-        F = B
-        sp_raw = np.asarray(_quantile_split_points(m, jnp.int32(fr.nrows),
-                                                   B))
-        # dedupe per column (repeated quantiles collapse to one
-        # threshold); categorical columns get no thresholds
-        sp = np.full((C, B - 1), np.nan, np.float32)
-        for j in range(C):
-            if is_cat[j]:
-                continue
-            qs = np.unique(sp_raw[j][~np.isnan(sp_raw[j])])
-            sp[j, : len(qs)] = qs
-    sp_dev = jax.device_put(jnp.asarray(sp), cloud().replicated)
-    bins = bin_matrix(m, sp_dev, is_cat, F)
-    return BinnedData(bins, sp, sp_dev, is_cat, B, F, histogram_type)
+    max_card = max([fr.vec(c).cardinality for c in di.cat_names] or [0])
+    col_nbins = np.array(
+        [min(fr.vec(c).cardinality, nbins_cats) if cat else nbins
+         for c, cat in zip(xs, is_cat)], np.int32)
+    B = max(nbins, min(max_card, nbins_cats))
+    with TimeLine.span("train", "bin", cat_cols=int(is_cat.sum()),
+                       max_card=int(max_card), table_bins=int(B)):
+        if (histogram_type in ("UniformAdaptive", "Random")
+                and _stream_blocks_enabled(fr, xs)):
+            # frame bigger than the HBM budget: never materialize the
+            # full matrix — stream shard-aligned windows through binning
+            return _prepare_bins_streamed(fr, xs, is_cat, B,
+                                          max(int(nbins_top_level), B),
+                                          histogram_type, col_nbins)
+        m = fr.as_matrix(xs)
+        if histogram_type in ("UniformAdaptive", "Random"):
+            F = max(int(nbins_top_level), B)
+            mn = np.asarray(_col_min_max(m, jnp.int32(fr.nrows)))
+            sp = _uniform_split_points(mn[0], mn[1], is_cat, C, F)
+        else:
+            F = B
+            sp_raw = np.asarray(_quantile_split_points(
+                m, jnp.int32(fr.nrows), nbins))
+            # dedupe per column (repeated quantiles collapse to one
+            # threshold); categorical columns get no thresholds, and a
+            # table widened by a categorical column leaves the numeric
+            # rows' tail NaN
+            sp = np.full((C, B - 1), np.nan, np.float32)
+            for j in range(C):
+                if is_cat[j]:
+                    continue
+                qs = np.unique(sp_raw[j][~np.isnan(sp_raw[j])])
+                sp[j, : len(qs)] = qs
+        sp_dev = jax.device_put(jnp.asarray(sp), cloud().replicated)
+        bins = bin_matrix(m, sp_dev, is_cat, F, col_nbins)
+        return BinnedData(bins, sp, sp_dev, is_cat, B, F, histogram_type,
+                          col_nbins)
 
 
-def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int):
+def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int,
+               col_nbins=None):
     """Bin raw values AND pack to the narrowest dtype the fine bin
     count permits — the one binning entry every trainer and scorer
     shares.  The ``tree.bins_dtype`` lever is resolved HERE, outside
@@ -164,7 +185,9 @@ def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int):
     executable instead of silently hitting a stale one).  Scoring a
     model under a different lever state than it trained with is safe:
     packed and int32 matrices hold identical integers (ops/binpack.py
-    decode contract), so descent and histograms agree bitwise."""
+    decode contract), so descent and histograms agree bitwise.
+    ``col_nbins`` is the model's ``col_nbins`` (BinnedData): None, from
+    an artifact that predates it, keeps every categorical code."""
     # a TRACED matrix means a caller is compiling its whole predict
     # around this call (serve/engine.py): the bins are an intermediate
     # of that program, not an HBM-resident input, and a lever cannot be
@@ -173,14 +196,28 @@ def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int):
         bins_bucket(matrix.shape[0], matrix.shape[1], fine_nbins))
     return _bin_all(matrix, split_points_dev, jnp.asarray(is_cat),
                     fine_nbins,
-                    out_dtype=packed_dtype_name(fine_nbins, packed))
+                    out_dtype=packed_dtype_name(fine_nbins, packed),
+                    col_nbins=None if col_nbins is None
+                    else jnp.asarray(col_nbins, jnp.int32))
+
+
+def bin_matrix_out(matrix, out: Dict):
+    """``bin_matrix`` over a model-output dict: the one way a scorer
+    bins raw values into a trained model's bin space."""
+    return bin_matrix(matrix, jnp.asarray(out["split_points"]),
+                      out["is_cat"], model_fine_na(out),
+                      out.get("col_nbins"))
 
 
 @functools.partial(jax.jit, static_argnames=("nbins", "out_dtype"))
 @jax.named_scope("h2o.bin.assign")
 def _bin_all(matrix, split_points, is_cat, nbins: int,
-             out_dtype: str = "int32"):
+             out_dtype: str = "int32", col_nbins=None):
     """Raw values -> bin indices in [0, nbins]; nbins = NA bucket.
+
+    A categorical code at or past its column's ``col_nbins`` (a level
+    the training domain does not hold) is missing as a NaN is: it takes
+    the NA bucket, so every descent sends it the node's NA side.
 
     Wide fine grids (UniformAdaptive's 1024 thresholds) use a per-column
     searchsorted instead of the (R, C, F-1) one-hot compare — log(F)
@@ -204,7 +241,10 @@ def _bin_all(matrix, split_points, is_cat, nbins: int,
         num_bins = jnp.sum((v >= t) & ~jnp.isnan(t), axis=2)
     cat_bins = jnp.clip(matrix, 0, nbins - 1).astype(jnp.int32)
     b = jnp.where(is_cat[None, :], cat_bins, num_bins)
-    return cast_bins(jnp.where(jnp.isnan(matrix), nbins, b), out_dtype)
+    missing = jnp.isnan(matrix)
+    if col_nbins is not None:
+        missing = missing | (is_cat[None, :] & (matrix >= col_nbins[None, :]))
+    return cast_bins(jnp.where(missing, nbins, b), out_dtype)
 
 
 @jax.jit
@@ -309,7 +349,8 @@ def _scatter_window(buf, blk, w0: int):
 
 
 def _prepare_bins_streamed(fr: Frame, xs, is_cat: np.ndarray, B: int,
-                           F: int, histogram_type: str) -> BinnedData:
+                           F: int, histogram_type: str,
+                           col_nbins: np.ndarray) -> BinnedData:
     """UniformAdaptive/Random binning without ever materializing the
     full matrix: pass 1 streams windows through a blocked min/max, pass
     2 bins each window and scatters it into the packed bins buffer.
@@ -334,6 +375,7 @@ def _prepare_bins_streamed(fr: Frame, xs, is_cat: np.ndarray, B: int,
         packed = bins_pack_enabled(bins_bucket(R, C, F))
         dt = packed_dtype_name(F, packed)
         is_cat_dev = jnp.asarray(is_cat)
+        col_nbins_dev = jnp.asarray(col_nbins, jnp.int32)
         buf = landing.reshard_rows(jnp.zeros((R, C), dt),
                                    cloud().matrix_sharding())
         L = streamer.per_shard_rows
@@ -347,7 +389,8 @@ def _prepare_bins_streamed(fr: Frame, xs, is_cat: np.ndarray, B: int,
                 q = streamer.window
                 w0 = min(pos, max(0, L - q))
                 blk = streamer.device_block(w0, w0 + q)
-                bb = _bin_all(blk, sp_dev, is_cat_dev, F, out_dtype=dt)
+                bb = _bin_all(blk, sp_dev, is_cat_dev, F, out_dtype=dt,
+                              col_nbins=col_nbins_dev)
                 return w0, bb, w0 + q
 
             w0, bb, pos = oom_ladder("tier.block", attempt,
@@ -361,7 +404,8 @@ def _prepare_bins_streamed(fr: Frame, xs, is_cat: np.ndarray, B: int,
                 streamer.stage(n0, n0 + q)
     finally:
         streamer.close()
-    return BinnedData(buf, sp, sp_dev, is_cat, B, F, histogram_type)
+    return BinnedData(buf, sp, sp_dev, is_cat, B, F, histogram_type,
+                      col_nbins)
 
 
 # ---------------------------------------------------------------------------
@@ -419,75 +463,82 @@ def find_splits(hist, is_cat, col_allowed, min_rows: float = 10.0,
     w, wg, wgg, wh = (hist[..., k] for k in range(4))
 
     # order bins: numeric -> natural, categorical -> by mean gradient
-    mean = wg[..., :B] / jnp.maximum(w[..., :B], EPS)
-    empty = w[..., :B] <= 0
-    key = jnp.where(empty, jnp.inf, mean)
-    natural = jnp.broadcast_to(
-        jnp.arange(B, dtype=jnp.float32)[None, None, :], key.shape)
-    order = jnp.argsort(jnp.where(is_cat[None, :, None], key, natural),
-                        axis=2)                              # (L, C, B)
+    with jax.named_scope("h2o.tree.split.order"):
+        mean = wg[..., :B] / jnp.maximum(w[..., :B], EPS)
+        empty = w[..., :B] <= 0
+        key = jnp.where(empty, jnp.inf, mean)
+        natural = jnp.broadcast_to(
+            jnp.arange(B, dtype=jnp.float32)[None, None, :], key.shape)
+        order = jnp.argsort(jnp.where(is_cat[None, :, None], key, natural),
+                            axis=2)                          # (L, C, B)
 
-    def sort_take(x):
-        return jnp.take_along_axis(x[..., :B], order, axis=2)
+        def sort_take(x):
+            return jnp.take_along_axis(x[..., :B], order, axis=2)
 
-    sw, swg, swgg, swh = map(sort_take, (w, wg, wgg, wh))
-    cw, cwg, cwgg, cwh = (jnp.cumsum(x, axis=2)
-                          for x in (sw, swg, swgg, swh))
-    naw, nawg, nawgg, nawh = (x[..., B] for x in (w, wg, wgg, wh))
-    tot_w = cw[..., -1] + naw
-    tot_wg = cwg[..., -1] + nawg
-    tot_wgg = cwgg[..., -1] + nawgg
-    tot_wh = cwh[..., -1] + nawh
+        sw, swg, swgg, swh = map(sort_take, (w, wg, wgg, wh))
+    # the scan over ordered prefixes: sums, gains, the arg-max
+    with jax.named_scope("h2o.tree.split.scan"):
+        cw, cwg, cwgg, cwh = (jnp.cumsum(x, axis=2)
+                              for x in (sw, swg, swgg, swh))
+        naw, nawg, nawgg, nawh = (x[..., B] for x in (w, wg, wgg, wh))
+        tot_w = cw[..., -1] + naw
+        tot_wg = cwg[..., -1] + nawg
+        tot_wgg = cwgg[..., -1] + nawgg
+        tot_wh = cwh[..., -1] + nawh
 
-    def se(w_, wg_, wgg_):
-        return wgg_ - wg_ ** 2 / jnp.maximum(w_, EPS)
+        def se(w_, wg_, wgg_):
+            return wgg_ - wg_ ** 2 / jnp.maximum(w_, EPS)
 
-    se_parent = se(tot_w, tot_wg, tot_wgg)                   # (L, C)
+        se_parent = se(tot_w, tot_wg, tot_wgg)               # (L, C)
 
-    def side_gain(na_left):
-        lw = cw + (naw[..., None] if na_left else 0.0)
-        lwg = cwg + (nawg[..., None] if na_left else 0.0)
-        lwgg = cwgg + (nawgg[..., None] if na_left else 0.0)
-        lwh = cwh + (nawh[..., None] if na_left else 0.0)
-        rw = tot_w[..., None] - lw
-        rwg = tot_wg[..., None] - lwg
-        rwgg = tot_wgg[..., None] - lwgg
-        rwh = tot_wh[..., None] - lwh
-        gain = se_parent[..., None] - se(lw, lwg, lwgg) - se(rw, rwg, rwgg)
-        ok = (lw >= min_rows) & (rw >= min_rows)
-        if use_mono:
-            # reject splits whose child values violate the declared
-            # direction (increasing: right >= left)
-            if newton:
-                lv = lwg / jnp.maximum(lwh + reg_lambda, EPS)
-                rv = rwg / jnp.maximum(rwh + reg_lambda, EPS)
-            else:
-                lv = lwg / jnp.maximum(lw, EPS)
-                rv = rwg / jnp.maximum(rw, EPS)
-            m = mono[None, :, None].astype(jnp.float32)
-            ok = ok & ((m == 0) | (m * (rv - lv) >= 0))
-        return jnp.where(ok, gain, -jnp.inf)
+        def side_gain(na_left):
+            lw = cw + (naw[..., None] if na_left else 0.0)
+            lwg = cwg + (nawg[..., None] if na_left else 0.0)
+            lwgg = cwgg + (nawgg[..., None] if na_left else 0.0)
+            lwh = cwh + (nawh[..., None] if na_left else 0.0)
+            rw = tot_w[..., None] - lw
+            rwg = tot_wg[..., None] - lwg
+            rwgg = tot_wgg[..., None] - lwgg
+            rwh = tot_wh[..., None] - lwh
+            gain = (se_parent[..., None] - se(lw, lwg, lwgg)
+                    - se(rw, rwg, rwgg))
+            ok = (lw >= min_rows) & (rw >= min_rows)
+            if use_mono:
+                # reject splits whose child values violate the declared
+                # direction (increasing: right >= left)
+                if newton:
+                    lv = lwg / jnp.maximum(lwh + reg_lambda, EPS)
+                    rv = rwg / jnp.maximum(rwh + reg_lambda, EPS)
+                else:
+                    lv = lwg / jnp.maximum(lw, EPS)
+                    rv = rwg / jnp.maximum(rw, EPS)
+                m = mono[None, :, None].astype(jnp.float32)
+                ok = ok & ((m == 0) | (m * (rv - lv) >= 0))
+            return jnp.where(ok, gain, -jnp.inf)
 
-    gains = jnp.stack([side_gain(False), side_gain(True)], axis=-1)
-    # candidate axis: (L, C, B, 2) — last split index B-1 sends everything
-    # left, which is never valid (rw=0 or < min_rows) so it self-eliminates
-    gains = jnp.where(col_allowed[..., None, None], gains, -jnp.inf)
-    flat = gains.reshape(L, -1)
-    best = jnp.argmax(flat, axis=1)
-    best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
-    col = (best // (B * 2)).astype(jnp.int32)
-    rem = best % (B * 2)
-    split_b = (rem // 2).astype(jnp.int32)
-    na_left = (rem % 2).astype(jnp.bool_)
+        gains = jnp.stack([side_gain(False), side_gain(True)], axis=-1)
+        # candidate axis: (L, C, B, 2) — the last split index B-1 sends
+        # every bin left: valid only with the NA bucket on the right and
+        # min_rows rows in it ("missing or not"), else it self-eliminates
+        gains = jnp.where(col_allowed[..., None, None], gains, -jnp.inf)
+        flat = gains.reshape(L, -1)
+        best = jnp.argmax(flat, axis=1)
+        best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
+        col = (best // (B * 2)).astype(jnp.int32)
+        rem = best % (B * 2)
+        split_b = (rem // 2).astype(jnp.int32)
+        na_left = (rem % 2).astype(jnp.bool_)
 
-    thresh = jnp.maximum(min_split_improvement *
-                         jnp.max(jnp.maximum(se_parent, 0.0), axis=1), EPS)
-    do_split = best_gain > thresh
+        thresh = jnp.maximum(
+            min_split_improvement *
+            jnp.max(jnp.maximum(se_parent, 0.0), axis=1), EPS)
+        do_split = best_gain > thresh
 
     # gather chosen column's per-leaf arrays
     li = jnp.arange(L)
-    order_c = order[li, col]                                  # (L, B)
-    rank = jnp.argsort(order_c, axis=1)                       # inverse perm
+    with jax.named_scope("h2o.tree.split.order"):
+        order_c = order[li, col]                              # (L, B)
+        rank = jnp.argsort(order_c, axis=1)                   # inverse perm
     bitset_bins = rank <= split_b[:, None]                    # (L, B)
     bitset = jnp.concatenate([bitset_bins, na_left[:, None]], axis=1)
 
@@ -514,7 +565,7 @@ def _advance_leaves(bins, leaf, do_split, col, bitset):
     active = leaf >= 0
     lf = jnp.maximum(leaf, 0)
     c = col[lf]
-    b = jnp.take_along_axis(bins, c[:, None], axis=1)[:, 0]
+    b = pick_bin(bins, c)
     go_left = bitset[lf, b]
     # level-LOCAL child index (heap index = level_offset + local)
     child = 2 * lf + jnp.where(go_left, 0, 1)
@@ -586,8 +637,7 @@ def forest_tree_values(bins, split_col, bitset, value, depth: int,
         for _ in range(depth):
             c = sc[node]
             term = c < 0
-            b = jnp.take_along_axis(bins, jnp.maximum(c, 0)[:, None],
-                                    axis=1)[:, 0]
+            b = pick_bin(bins, jnp.maximum(c, 0))
             go_left = _go_left(bs, node, b, th, na, fine_na, B)
             if ch is None:
                 nxt = 2 * node + jnp.where(go_left, 1, 2)

@@ -30,6 +30,7 @@ from h2o_tpu.models import metrics as mm
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
 from h2o_tpu.core.autotune import hist_bucket
 from h2o_tpu.models.tree import shared_tree as st
+from h2o_tpu.ops.binpack import pick_bin
 from h2o_tpu.ops.histogram import histogram_build_traced, pallas_env_enabled
 
 EPS = 1e-6
@@ -226,7 +227,7 @@ def _train_uplift_forest(bins, treat, yv, w, active, key, *, ntrees: int,
                 act = slot >= 0
                 sl = jnp.maximum(slot, 0)
                 c = s["col"][sl]
-                b = jnp.take_along_axis(bins, c[:, None], axis=1)[:, 0]
+                b = pick_bin(bins, c)
                 go_left = s["bitset"][sl, b]
                 cand = 2 * sl + jnp.where(go_left, 0, 1)
                 new_slot = jnp.where(act & do[sl], inv[cand], -1)
@@ -247,8 +248,7 @@ class UpliftDRFModel(Model):
     def predict_raw(self, frame: Frame):
         out = self.output
         m = frame.as_matrix(out["x"])
-        bins = st.bin_matrix(m, jnp.asarray(out["split_points"]),
-                             out["is_cat"], int(out["nbins"]))
+        bins = st.bin_matrix_out(m, out)
         D = int(out["max_depth"])
         T = max(int(out["ntrees_actual"]), 1)
         sc = jnp.asarray(out["split_col"])[:, None]
@@ -363,6 +363,7 @@ class UpliftDRF(ModelBuilder):
                 binned.nbins, min(1 << depth, max_live_leaves()))))
         out = dict(x=list(di.x), split_points=binned.split_points,
                    is_cat=binned.is_cat, nbins=binned.nbins,
+                   col_nbins=binned.col_nbins,
                    split_col=np.asarray(sc), bitset=np.asarray(bs),
                    val_t=np.asarray(vt), val_c=np.asarray(vc),
                    child=np.asarray(ch),

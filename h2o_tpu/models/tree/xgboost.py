@@ -232,11 +232,8 @@ class XGBoost(GBM):
                 na = m.output.get("na_left")
                 if base_out is None:
                     base_out = m.output
-                    bins = st.bin_matrix(
-                        train.as_matrix(m.output["x"]),
-                        jnp.asarray(m.output["split_points"]),
-                        m.output["is_cat"],
-                        st.model_fine_na(m.output))
+                    bins = st.bin_matrix_out(
+                        train.as_matrix(m.output["x"]), m.output)
                 Fnew = np.asarray(st.forest_score(
                     bins, jnp.asarray(sc), jnp.asarray(bs),
                     jnp.asarray(vl),

@@ -37,14 +37,20 @@ def _link_inv(dist: str, f):
 
 # -- trees ------------------------------------------------------------------
 
-def _bin_matrix(X, split_points, is_cat, nbins: int) -> np.ndarray:
-    """Raw values -> bin ids (shared_tree._bin_all in numpy)."""
+def _bin_matrix(X, split_points, is_cat, nbins: int,
+                col_nbins=None) -> np.ndarray:
+    """Raw values -> bin ids (shared_tree._bin_all in numpy): a
+    categorical code at or past its column's ``col_nbins`` (a level the
+    model was not trained on) is missing, as a NaN is."""
     valid_t = ~np.isnan(split_points)                       # (C, B-1)
     num_bins = ((X[:, :, None] >= split_points[None, :, :]) &
                 valid_t[None, :, :]).sum(axis=2)
     cat_bins = np.clip(np.nan_to_num(X), 0, nbins - 1).astype(np.int64)
     b = np.where(is_cat[None, :], cat_bins, num_bins).astype(np.int64)
-    return np.where(np.isnan(X), nbins, b)
+    missing = np.isnan(X)
+    if col_nbins is not None:
+        missing |= is_cat[None, :] & (np.nan_to_num(X) >= col_nbins[None, :])
+    return np.where(missing, nbins, b)
 
 
 def _forest_score(bins, split_col, bitset, value, depth: int,
@@ -88,7 +94,8 @@ def _forest_score(bins, split_col, bitset, value, depth: int,
 def _tree_F(arrays: Dict, meta: Dict, X) -> np.ndarray:
     fine = int(meta.get("fine_nbins") or meta["nbins"])
     bins = _bin_matrix(X, arrays["split_points"],
-                       arrays["is_cat"].astype(bool), fine)
+                       arrays["is_cat"].astype(bool), fine,
+                       arrays.get("col_nbins"))
     return _forest_score(bins, arrays["split_col"], arrays["bitset"],
                          arrays["value"], int(meta["max_depth"]),
                          child=arrays.get("child"),
@@ -402,7 +409,8 @@ def score_rulefit(arrays, meta, X):
     R = X.shape[0]
     bins = _bin_matrix(X[:, [cols.index(c) for c in meta["x"]]],
                        arrays["split_points"],
-                       arrays["is_cat"].astype(bool), int(meta["nbins"]))
+                       arrays["is_cat"].astype(bool), int(meta["nbins"]),
+                       arrays.get("col_nbins"))
     n_forests = int(meta["forests__len"])
     feats = {}
     rows = np.arange(R)

@@ -82,6 +82,22 @@ def widen_bins(b) -> jax.Array:
     return lax.convert_element_type(b, jnp.int32)
 
 
+def pick_bin(bins, c) -> jax.Array:
+    """``bins[r, c[r]]`` for every row r: each row's bin in the column
+    its node splits on, in the matrix's own dtype.  A compare-select
+    over the C columns and a sum, one pass over the matrix that fuses
+    into a single loop, NOT a per-row gather: on the chip the gather
+    (``take_along_axis``) issues one access per row, took 0.26-0.32 s a
+    level at 11.5M rows, and ran at one of three speeds from process to
+    process by where the allocator had placed ``bins`` (PERF.md section
+    6, PR 30), which no window of fixed work can be timed over.  Exact:
+    one column matches a valid ``c``, none a negative one (the result is
+    then 0, and every caller masks such rows)."""
+    cols = jnp.arange(bins.shape[1], dtype=c.dtype)
+    return jnp.sum(jnp.where(cols[None, :] == c[:, None], bins, 0),
+                   axis=1, dtype=bins.dtype)
+
+
 def bins_pack_enabled(bucket=None) -> bool:
     """Tri-state ``H2O_TPU_BINS_PACK``: ``1`` forces packing, ``0``
     forces the int32 reference, ``auto``/unset defers to the measured
