@@ -212,12 +212,6 @@ class DRF(ModelBuilder):
             float(p.get("max_runtime_secs") or 0) > 0
         if want_scoring:
             score_frame = valid if valid is not None else train
-            bins_sc = bins if valid is None else st.bin_matrix(
-                valid.as_matrix(di.x), binned.split_points_dev,
-                binned.is_cat, binned.fine, binned.col_nbins)
-            F_sc = jnp.zeros((bins_sc.shape[0], K), jnp.float32)
-            if prior:
-                F_sc = F_sc + st.forest_score_out(bins_sc, co, depth)
             H = pool_size(depth, kleaves)
             proto = make_model(
                 np.zeros((0, K, H), np.int32),
@@ -231,9 +225,19 @@ class DRF(ModelBuilder):
                 return proto.metrics_from_raw(
                     raw_from_votes(Fv, ntot, dom_sc), score_frame)
 
-            scorer = IncrementalScorer(bins_sc, F_sc, depth, to_metrics,
-                                       valid is not None,
-                                       fine_na=binned.fine)
+            if valid is None:
+                # the trainer's carried F holds the raw votes on every
+                # row of this frame: the driver scores each block on it
+                scorer = IncrementalScorer(to_metrics)
+            else:
+                bins_sc = st.bin_matrix(
+                    valid.as_matrix(di.x), binned.split_points_dev,
+                    binned.is_cat, binned.fine, binned.col_nbins)
+                F_sc = jnp.zeros((bins_sc.shape[0], K), jnp.float32)
+                if prior:
+                    F_sc = F_sc + st.forest_score_out(bins_sc, co, depth)
+                scorer = IncrementalScorer(to_metrics, bins_sc, F_sc, depth,
+                                           fine_na=binned.fine)
         job.update(0.05, f"training {int(p['ntrees']) - prior} trees")
         model = run_tree_driver(job, p, train_kwargs, F0, self.rng_key(),
                                 make_model, scorer, kind,

@@ -6,11 +6,15 @@ early stopping, and ``resumeFromCheckpoint`` (:465-478).
 
 TPU-native: trees are trained in BLOCKS of ``score_tree_interval`` trees,
 each block one fused XLA dispatch (jit_engine.train_forest with the F vector
-carried across blocks).  Scoring is INCREMENTAL: the scoring frame's
-link-scale predictions are a running F to which only the new block's trees
-are added (one forest_score over the block), so total scoring work is O(T) —
-the reference's per-scoring-round full-model rescore (BigScore over all
-trees) is avoided entirely.
+carried across blocks).  Scoring is INCREMENTAL, and on the training
+frame it descends nothing: the block's own carried F (``tf.f_final``: f0 +
+offset + checkpoint forest + every kept tree, on every row) IS the training
+frame's link-scale prediction, so the metric kernels read it where it lies.
+Only a validation frame, whose rows the trainer never sees, keeps a running
+F of its own to which the new block's trees are added (one forest_score
+over the block).  Either way total scoring work is O(T) — the reference's
+per-scoring-round full-model rescore (BigScore over all trees) is avoided
+entirely.
 
 OOM DEGRADATION LADDER (core/oom.py): every block launch runs under
 ``oom_ladder("tree.block", ...)`` — a RESOURCE_EXHAUSTED dispatch first
@@ -32,10 +36,10 @@ which never leaves the device — and block *t*'s arrays are pulled with
 the ScoreKeeper decision point synchronizes (its metrics need host
 values); an early stop discards the one speculatively-launched block,
 which is why speculative launches never donate their F0 (the stop path
-still needs the previous block's f_final).  Tree outputs are bitwise
-identical to the synchronous path: the RNG stream is split in the same
-order, and discarded speculative keys are exactly the keys the
-synchronous path never consumes.
+and the training-frame scorer still read the previous block's f_final).
+Tree outputs are bitwise identical to the synchronous path: the RNG
+stream is split in the same order, and discarded speculative keys are
+exactly the keys the synchronous path never consumes.
 """
 
 from __future__ import annotations
@@ -88,21 +92,34 @@ def _set_node_array(model, name: str, new: np.ndarray) -> None:
 
 
 class IncrementalScorer:
-    """Running link-scale predictions of the growing forest on one frame.
+    """Link-scale predictions of the growing forest on one frame.
 
-    to_metrics(F, ntrees_total) -> ModelMetrics converts the accumulated F
-    (model-specific link/vote semantics) and runs the metric kernels.
+    to_metrics(F, ntrees_total) -> ModelMetrics converts F (model-specific
+    link/vote semantics) and runs the metric kernels.
+
+    ``bins`` None: the frame is the TRAINING frame.  Its F is the one the
+    trainer carries — ``score`` reads the block's ``f_final``, descends
+    nothing and keeps no F of its own.  With ``bins`` (a validation
+    frame's) the scorer keeps the running ``F``, from ``F_init``, and adds
+    each block's trees to it by one descent.
     """
 
-    def __init__(self, bins, F_init, depth: int,
-                 to_metrics: Callable, is_validation: bool,
-                 fine_na: int = -1):
+    def __init__(self, to_metrics: Callable, bins=None, F_init=None,
+                 depth: int = 0, fine_na: int = -1):
+        self.to_metrics = to_metrics
         self.bins = bins
         self.F = F_init
         self.depth = depth
-        self.to_metrics = to_metrics
-        self.is_validation = is_validation
         self.fine_na = fine_na
+
+    @property
+    def is_validation(self) -> bool:
+        return self.bins is not None
+
+    @property
+    def source(self) -> str:
+        """Where ``score`` finds its F: field of span train.block.score."""
+        return "descent" if self.is_validation else "carried_F"
 
     def add(self, sc, bs, vl, ch=None, th=None, na=None) -> None:
         from h2o_tpu.core.cloud import donation_enabled
@@ -120,8 +137,14 @@ class IncrementalScorer:
         acc = _accum_donate if donation_enabled() else _accum
         self.F = acc(self.F, delta)
 
-    def metrics(self, ntrees_total: int):
-        return self.to_metrics(self.F, ntrees_total)
+    def score(self, tf, ntrees_total: int):
+        """Metrics of the forest up to and including block ``tf``."""
+        F = tf.f_final
+        if self.is_validation:
+            self.add(tf.split_col, tf.bitset, tf.value, tf.child,
+                     tf.thr_bin, tf.na_left)
+            F = self.F
+        return self.to_metrics(F, ntrees_total)
 
 
 @jax.jit
@@ -315,7 +338,10 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
             vi_total = st.get("vi_total")
             if st.get("sk") is not None:
                 sk = st["sk"]
-            if scorer is not None and st.get("scorer_F") is not None:
+            # a training-frame scorer keeps no F (checkpoints written when
+            # it did still carry one: not restored)
+            if scorer is not None and scorer.is_validation and \
+                    st.get("scorer_F") is not None:
                 scorer.F = jnp.asarray(_fit_rows(
                     st["scorer_F"], int(scorer.F.shape[0])))
             job.update(0.05 + 0.85 * done / ntrees,
@@ -324,13 +350,15 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     may_stop = (rounds > 0 and scorer is not None) or max_rt > 0
     # speculative launches must not donate their F0: on an early stop /
     # runtime-budget break the discarded block's INPUT (the last kept
-    # block's f_final) is still read by make_model, and recovery
-    # checkpoints np.asarray the post-block F after the next block has
-    # already been dispatched.  Sync mode (and async without any stop
-    # path) uses the default donation policy — the carry is then written
-    # in place across blocks.
-    donate_launch = False if (use_async and
-                              (may_stop or recovery is not None)) else None
+    # block's f_final) is still read by make_model, recovery checkpoints
+    # np.asarray the post-block F after the next block has already been
+    # dispatched, and a training-frame scorer reads block t's f_final as
+    # its F when block t is absorbed, after t+1's launch.  Sync mode (and
+    # async with none of those readers) uses the default donation policy
+    # — the carry is then written in place across blocks.
+    reads_carry = scorer is not None and not scorer.is_validation
+    donate_launch = False if (use_async and (
+        may_stop or recovery is not None or reads_carry)) else None
     launched = done
     no_donate = False       # latched by the OOM ladder: retries re-read F
 
@@ -417,10 +445,9 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
             done += n
             stop = False
             if scorer is not None:
-                with TimeLine.span("train", "block.score"):
-                    scorer.add(tf.split_col, tf.bitset, tf.value, tf.child,
-                               tf.thr_bin, tf.na_left)
-                    mm = scorer.metrics(prior_trees + done)
+                with TimeLine.span("train", "block.score",
+                                   source=scorer.source):
+                    mm = scorer.score(tf, prior_trees + done)
                     row = {"number_of_trees": prior_trees + done,
                            "timestamp": time.time()}
                     for k in ("mse", "logloss", "AUC",
@@ -446,8 +473,10 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                          "done": done, "F": np.asarray(tf.f_final),
                          "key": rng_key_to_np(cur["key_after"]),
                          "lists": lists, "vi_total": vi_total, "sk": sk,
+                         # a training-frame scorer's F is "F" above
                          "scorer_F": np.asarray(scorer.F)
-                         if scorer is not None else None},
+                         if scorer is not None and scorer.is_validation
+                         else None},
                         meta={"kind": "tree",
                               "trees_done": prior_trees + done,
                               "ntrees": int(p["ntrees"])})

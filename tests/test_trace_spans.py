@@ -3,7 +3,8 @@
 - ``TimeLine.span``: one ring event per span with ``dur_ns``, ``id``,
   ``parent`` and ``job``; a thread-local stack; closed by an exception.
 - a GBM ``train()`` leaves ``job.run > train.bin, train.block.launch,
-  train.block.absorb (> pull, score), train.final_metrics`` under one job.
+  train.block.absorb (> pull, score), train.final_metrics`` under one job;
+  ``train.block.score`` says where its F came from (``source``).
 - the lowered tree program names every ``h2o.tree.*`` scope (both
   engines, both binnings); scoring names ``h2o.score.descent``; binning
   names ``h2o.bin.*``.
@@ -136,8 +137,11 @@ def test_span_lies_in_a_profile_as_trace_annotation(monkeypatch):
         pass
     with TimeLine.span("train", "final_metrics", source="carried_F"):
         pass
+    with TimeLine.span("train", "block.score", source="descent"):
+        pass
     assert seen == [("h2o:train.block.pull", {}),
-                    ("h2o:train.final_metrics", {"source": "carried_F"})]
+                    ("h2o:train.final_metrics", {"source": "carried_F"}),
+                    ("h2o:train.block.score", {"source": "descent"})]
 
 
 # ------------------------------------------------------- a training's tree
@@ -151,12 +155,15 @@ def _toy_frame(rng, n=600, c=4):
     return Frame(names, vecs)
 
 
-def test_gbm_train_leaves_one_tree_of_spans(cl, rng):
+@pytest.mark.parametrize("validation, source", [(False, "carried_F"),
+                                                (True, "descent")])
+def test_gbm_train_leaves_one_tree_of_spans(cl, rng, validation, source):
     from h2o_tpu.models.tree.gbm import GBM
     fr = _toy_frame(rng)
     TimeLine.clear()
     GBM(ntrees=3, max_depth=2, seed=3, score_tree_interval=1).train(
-        y="y", training_frame=fr)
+        y="y", training_frame=fr,
+        validation_frame=_toy_frame(rng, n=200) if validation else None)
     spans = _spans()
     roots = [e for e in spans if (e["kind"], e["what"]) == ("job", "run")]
     assert len(roots) == 1
@@ -177,6 +184,9 @@ def test_gbm_train_leaves_one_tree_of_spans(cl, rng):
     absorbs = {e["id"] for e in of("block.absorb")}
     assert {e["parent"] for e in of("block.pull")} == absorbs
     assert {e["parent"] for e in of("block.score")} == absorbs
+    # the training frame's scorer reads the block's carried F; only a
+    # validation frame's descends the block's trees
+    assert {e["source"] for e in of("block.score")} == {source}
     assert not of("block.checkpoint")       # no recovery attached
     # children lie inside the root on the wall clock
     for e in mine:
