@@ -7,7 +7,7 @@ visible chip, the REST server, CSV ingest, ``POST /3/ModelBuilders/gbm``
 twice, bulk ``/3/Predictions``, ``POST /3/Serving`` and ``/score`` — at
 the full width of the one configuration with any chip history: GBM
 binomial on the HIGGS shape (1,000,000 x 28 float32 + a binary response
-from ``bench._make_data(seed=0)``; 20 trees, depth 5, 64 bins,
+from ``make_data(seed=0)`` below; 20 trees, depth 5, 64 bins,
 QuantilesGlobal).  No ``H2O_TPU_*`` variable is set: the default
 switches decide what runs, and the assertions at the end prove that no
 fallback fired on the way.
@@ -39,6 +39,31 @@ NBINS, LEAVES = 64, (1, 16, 32)
 #   JAX_PLATFORMS=cpu python -c "import chip_smoke; chip_smoke.cpu_reference_auc()"
 CPU_F32_TRAIN_AUC = 0.783302
 AUC_BAND = 0.003
+
+
+# ------------------------------------------------------------------- data
+
+
+def make_data(rows, cols, seed=0):
+    """The HIGGS-shaped rows every assertion here was read on (rows
+    first, response drawn last; ``benchmark.data.higgs_like`` draws
+    other rows)."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(rows, cols)).astype(np.float32)
+    # HIGGS-like signal: nonlinear combination of a few features
+    logits = (1.2 * X[:, 0] - 0.8 * X[:, 1] + X[:, 2] * X[:, 3]
+              + 0.5 * np.sin(3 * X[:, 4]))
+    y = (rng.uniform(size=rows) < 1 / (1 + np.exp(-logits))).astype(np.int32)
+    return X, y
+
+
+def make_frame(X, y):
+    from h2o_tpu.core.frame import Frame, Vec, T_CAT
+    cols = X.shape[1]
+    names = [f"x{j}" for j in range(cols)] + ["y"]
+    vecs = [Vec(X[:, j]) for j in range(cols)] + \
+        [Vec(y, T_CAT, domain=["b", "s"])]
+    return Frame(names, vecs)
 
 
 # ---------------------------------------------------------------- harness
@@ -176,7 +201,6 @@ def assert_sharded(fr, n, platform, what):
 def ingest(cl, device, X, y, csv_rows):
     """A CSV of the same data through parse_file (native tokenizer ->
     landing), and the full frame through Frame/Vec."""
-    import bench
     from h2o_tpu import native, parse_file
     check(native.available(), "native CSV tokenizer built and loaded")
     n = device["count"]
@@ -194,7 +218,7 @@ def ingest(cl, device, X, y, csv_rows):
     check(np.array_equal(got.astype(np.float32), X[:csv_rows, 0]),
           "parsed column x0 equals the generated values")
     assert_sharded(pf, n, device["platform"], "parsed frame")
-    fr = bench._frame(X, y)
+    fr = make_frame(X, y)
     fr.key = "higgs"
     cl.dkv.put(fr.key, fr)
     assert_sharded(fr, n, device["platform"], "training frame")
@@ -404,8 +428,7 @@ def run(rows=ROWS, csv_rows=CSV_ROWS, require="tpu", interpret=False,
         print(f"compile cache: jax_compilation_cache_dir={d} "
               f"entries_before={n0}", flush=True)
         phase("kernels", kernels, rows, device["count"], interpret)
-        import bench
-        X, y = bench._make_data(rows, COLS, seed=0)
+        X, y = make_data(rows, COLS, seed=0)
         phase("ingest", ingest, cl, device, X, y, csv_rows)
 
         c0, h0 = DispatchStats.xla_compiles(), counters.hits
@@ -444,12 +467,11 @@ def run(rows=ROWS, csv_rows=CSV_ROWS, require="tpu", interpret=False,
 
 def cpu_reference_auc():
     """The number pinned in CPU_F32_TRAIN_AUC (run with JAX_PLATFORMS=cpu)."""
-    import bench
     from h2o_tpu.core.cloud import Cloud
     from h2o_tpu.models.tree.gbm import GBM as Builder
     Cloud.boot()
-    X, y = bench._make_data(ROWS, COLS, seed=0)
-    m = Builder(**GBM).train(y="y", training_frame=bench._frame(X, y))
+    X, y = make_data(ROWS, COLS, seed=0)
+    m = Builder(**GBM).train(y="y", training_frame=make_frame(X, y))
     print(f"CPU_F32_TRAIN_AUC = {m.output['training_metrics']['AUC']:.6f}")
 
 

@@ -1293,14 +1293,13 @@ def dispatch_route(params):
     ``plan`` reports the lazy Rapids planner (rapids/plan.py): regions
     considered/fused, verbs folded into fused programs, repacks and
     host count-syncs elided versus the eager per-verb path, OOM
-    degradations to the unfused chain, and the fuse-lever split —
-    the numbers the rapids_pipeline bench gate reads.
+    degradations to the unfused chain, and the fuse-lever split.
 
     ``dispatch.collectives`` is the per-phase collective byte ledger
     from the two-level mesh helpers (core/cloud.py hpsum/hall_gather/
     hall_to_all): per collective kind:tag, the trace-time inner-ICI
     vs outer-DCN byte estimates per compiled program — the numbers
-    the dryrun_multichip bench rung asserts are O(table) across DCN."""
+    tests/test_two_level_mesh.py asserts are O(table) across DCN."""
     from h2o_tpu.core.diag import DispatchStats
     from h2o_tpu.core.exec_store import exec_store
     from h2o_tpu.rapids.plan import PlanStats

@@ -4,15 +4,15 @@ Every knob is an environment variable read at CALL time (never cached at
 import), so tests can monkeypatch ``os.environ`` and long-lived sessions
 can retune between jobs.  The accessors below are the single source of
 truth for defaults; the modules that consume them (``core/memory.py``,
-``core/landing.py``, ``models/tree/shared_tree.py``) import from here.
+``models/tree/shared_tree.py``) import from here.
 
 Knobs
 -----
 
-``H2O_TPU_HBM_BUDGET`` (alias ``H2O_TPU_MEM_BUDGET``) — bytes of device
-    HBM the tier manager may hold resident before LRU-spilling cold
-    column blocks to host.  ``0`` (default) means unbounded: nothing
-    spills and streaming's ``auto`` gate stays closed.
+``H2O_TPU_HBM_BUDGET`` — bytes of device HBM the tier manager may hold
+    resident before LRU-spilling cold column blocks to host.  ``0``
+    (default) means unbounded: nothing spills and streaming's ``auto``
+    gate stays closed.
     ``MemoryManager.set_budget()`` overrides the env at runtime.
 
 ``H2O_TPU_HOST_BUDGET`` — bytes of host RAM the middle tier may hold
@@ -33,13 +33,6 @@ Knobs
     buffering).  Raising it hides more page-in latency at the cost of
     ``depth * window_bytes`` extra transient HBM.
 
-``H2O_TPU_SHARD_LANDING`` — ``1`` (default) lands ingest chunks
-    shard-direct: each host chunk is split along the row axis and
-    ``device_put`` per-shard, so the largest single transfer is one
-    shard of one chunk and no host ever materializes the whole frame.
-    ``0`` restores the legacy whole-array put (the parity oracle used
-    by tests and the bench gate-off run).
-
 ``H2O_TPU_TIER_STREAM`` — streamed GBM bin-preparation mode: ``auto``
     (default) streams only when an HBM budget is set and the binned
     matrix would not fit; ``1``/``on`` forces streaming; ``0``/``off``
@@ -53,8 +46,8 @@ Lazy Rapids planner knobs (``rapids/plan.py`` / ``core/fuse.py``)
     single-program path; ``0`` forces the eager per-verb chain (the
     bitwise parity oracle); unset defers to the ``rapids.fuse``
     autotuner lever (measured fused-vs-per-verb per chain kind x row
-    bucket on TPU; the per-verb reference elsewhere).  Tests, the
-    bench ladder and the audit gate set ``1`` explicitly — the same
+    bucket on TPU; the per-verb reference elsewhere).  Tests and the
+    audit gate set ``1`` explicitly — the same
     convention as ``H2O_TPU_BINS_PACK``.
 
 ``H2O_TPU_RAPIDS_FUSE_MAX_VERBS`` — cap on the number of verbs the
@@ -140,7 +133,7 @@ import os
 
 __all__ = [
     "hbm_budget", "host_budget", "tier_block_rows", "prefetch_depth",
-    "shard_landing_enabled", "tier_stream_mode",
+    "tier_stream_mode",
     "rapids_fuse_mode", "rapids_fuse_max_verbs",
     "serve_replicas", "breaker_soft", "breaker_hard",
     "breaker_open_secs", "breaker_probes", "breaker_interval_ms",
@@ -153,9 +146,7 @@ __all__ = [
 
 def hbm_budget() -> int:
     """Device-HBM residency budget in bytes; 0 = unbounded."""
-    return int(os.environ.get("H2O_TPU_HBM_BUDGET")
-               or os.environ.get("H2O_TPU_MEM_BUDGET")
-               or 0)
+    return int(os.environ.get("H2O_TPU_HBM_BUDGET") or 0)
 
 
 def host_budget() -> int:
@@ -171,12 +162,6 @@ def tier_block_rows() -> int:
 def prefetch_depth() -> int:
     """Windows staged ahead by the streamer (1 = double buffering)."""
     return int(os.environ.get("H2O_TPU_PREFETCH_DEPTH", "1") or 1)
-
-
-def shard_landing_enabled() -> bool:
-    """False restores the legacy whole-array ``device_put`` landing."""
-    return os.environ.get("H2O_TPU_SHARD_LANDING", "1").lower() not in (
-        "0", "off", "false", "no")
 
 
 def tier_stream_mode() -> str:

@@ -34,7 +34,7 @@ next backend.  This module makes the selection automatic:
 
 Escape hatches (all resolved ONLY here — lint-enforced):
   H2O_TPU_AUTOTUNE=0        reference variants everywhere, zero probes
-  H2O_TPU_AUTOTUNE=force    probe on any backend (bench/tests; default
+  H2O_TPU_AUTOTUNE=force    probe on any backend (tests; default
                             ``auto`` probes on TPU only, so CPU tiers
                             stay bitwise-identical to the references)
   H2O_TPU_HIST_PALLAS / H2O_TPU_MATMUL_ROUTE / H2O_TPU_SIBLING_SUBTRACT
@@ -307,7 +307,7 @@ def _store_decision(rec: dict) -> None:
 
 
 def _complete(out):
-    """Host-fetch barrier (bench.py's timing idiom): a device->host
+    """Host-fetch barrier: a device->host
     scalar fetch cannot complete until the whole dependency chain has
     executed, so the timed region ends when the work does."""
     leaves = jax.tree_util.tree_leaves(out)
@@ -483,7 +483,7 @@ def reset() -> None:
 
 
 def autotune_payload() -> dict:
-    """The GET /3/Autotune body (also embedded in bench lever_ab)."""
+    """The GET /3/Autotune body."""
     env = _environ_key()
     with _LOCK:
         decisions = [dict(rec) for rec in _DECISIONS.values()]
@@ -571,15 +571,13 @@ def _hist_fp() -> str:
 
 def _route_gather_impl(bins, lf, col, bitset, na_left, do_split, thr,
                        cat_choice, *, L, Bd):
-    """The engine's per-level GATHER router (build_tree_* adaptive
-    path) mirrored 1:1 — the reference the matmul router must match
-    bitwise."""
-    from h2o_tpu.ops.binpack import pick_bin
-    b = pick_bin(bins, col[lf])
-    gset = bitset[lf, jnp.minimum(b, Bd)] > 0.5
-    gthr = jnp.where(b == Bd, na_left[lf] > 0.5, b < thr[lf])
-    go = jnp.where(cat_choice[lf], gset, gthr)
-    return jnp.stack([go, do_split[lf]], axis=1).astype(jnp.float32)
+    """The engine's per-level GATHER router on the adaptive path — the
+    reference the matmul router must match bitwise."""
+    from h2o_tpu.models.tree.jit_engine import _gather_route_level
+    s = {"col": col, "bitset": bitset > 0.5, "na_left": na_left > 0.5}
+    go, do = _gather_route_level(bins, lf, s, do_split, Bd, cat_choice,
+                                 True, thr, Bd)
+    return jnp.stack([go, do], axis=1).astype(jnp.float32)
 
 
 def _route_mm_impl(bins, lf, col, bitset, na_left, do_split, thr,
@@ -627,7 +625,7 @@ def _mm_run(v: str, w: dict):
 def _mm_fp() -> str:
     from h2o_tpu.models.tree import jit_engine as je
     return ",".join(code_fingerprint(f) for f in (
-        je._mm_route_level, je._mm_pick, _route_gather_impl))
+        je._mm_route_level, je._mm_pick, je._gather_route_level))
 
 
 def _sib_on_impl(bins, slot, stats_, parent, *, L, B):
@@ -827,8 +825,7 @@ register_lever(Lever(
     # NOT bitwise: stochastic rounding perturbs each table entry by
     # < max|f|/qmax per row.  The band is ops/statpack.py TABLE_TOL;
     # whole-forest metric drift is additionally pinned to
-    # statpack.METRIC_TOL by tests/test_stats_pack.py and the
-    # stats_pack bench rung.  A candidate outside the band — or not
+    # statpack.METRIC_TOL by tests/test_stats_pack.py.  A candidate outside the band — or not
     # beating f32 by probe_margin() — is disqualified.
     tol=(0.02, 0.05),
 ))

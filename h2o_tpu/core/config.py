@@ -31,8 +31,7 @@ owning modules, like the chaos flags, so they work before a cloud boots):
 - unified executable store (core/exec_store.py — the one compiled-
   program cache under the MRTask verbs, the serve predict path, the
   munge kernels and the tree-engine executable pair):
-  ``H2O_TPU_EXEC_STORE`` (LRU capacity in entries, default 256; the
-  legacy ``H2O_TPU_DISPATCH_CACHE`` spelling is honored),
+  ``H2O_TPU_EXEC_STORE`` (LRU capacity in entries, default 256),
   ``H2O_TPU_EXEC_STORE_DIR`` (directory for persistent AOT-serialized
   executables; unset = disk layer off.  A fresh process warms its
   kernel set from here — disk entries are schema-versioned and
@@ -72,7 +71,7 @@ owning modules, like the chaos flags, so they work before a cloud boots):
   ``H2O_TPU_AUTOTUNE`` (``auto`` default: probe on TPU backends only,
   off-TPU the reference variants win with zero probe runs; ``0``/off =
   always reference variants, never probe; ``force`` = probe on any
-  backend — what the bench ladder's lever_ab block uses),
+  backend — what tests/test_autotune.py uses),
   ``H2O_TPU_AUTOTUNE_REPS`` (timed reps per candidate after the
   untimed compile run, default 5 — winner is the median),
   ``H2O_TPU_AUTOTUNE_ROWS`` (probe workload row cap, default 65536,
@@ -100,7 +99,7 @@ owning modules, like the chaos flags, so they work before a cloud boots):
   carrier names ``int16``/``int8``/``f32`` directly; ``1`` means
   int16.  Unlike bins packing the gate is NOT bitwise — each table
   entry moves by < max|f|/qmax per row — so the lever's tolerance band
-  is (0.02, 0.05) at the table and tests/bench pin whole-forest
+  is (0.02, 0.05) at the table and tests pin whole-forest
   metrics to statpack.METRIC_TOL.  Unset on CPU resolves to the f32
   reference with zero probes and stays bitwise-identical to the
   pre-quantization engine) each accept ``1``
@@ -143,8 +142,7 @@ owning modules, like the chaos flags, so they work before a cloud boots):
   for GL801 cycle detection and flags device dispatch under any
   witnessed lock as GL802.  Decided at lock CREATION time — set it
   before the first h2o_tpu import; off means plain ``threading``
-  primitives and zero overhead, a contract the bench ladder's
-  ``audit_overhead`` rung gates at < 2% dispatch delta).
+  primitives and zero overhead).
 """
 
 from __future__ import annotations

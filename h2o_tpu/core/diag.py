@@ -52,8 +52,8 @@ class DispatchStats:
     "quantile"...).  ``xla_compiles`` counts BACKEND compiles globally
     via jax's monitoring events (install_xla_listener), so even jit
     sites that do not route through the dispatch cache are visible —
-    the number the compile-count regression tests and the bench's
-    compiles-per-tree report are built on.
+    the number the compile-count regression tests and the benchmark's
+    ``window_compiles`` are built on.
     """
 
     _lock = threading.Lock()

@@ -11,8 +11,7 @@ reference funneling every distributed verb through ONE ``MRTask`` /
 per-algorithm plumbing, so this module is that substrate: a single
 ``ExecStore`` that owns
 
-- the **LRU bound** (``H2O_TPU_EXEC_STORE`` entries, default 256 —
-  ``H2O_TPU_DISPATCH_CACHE`` still honored as the legacy spelling);
+- the **LRU bound** (``H2O_TPU_EXEC_STORE`` entries, default 256);
 - **shape-bucketing** helpers (``bucket_pow2`` — the serve layer's
   power-of-two batch discipline, reused by the munge row buckets);
 - the **buffer-donation policy**: callers declare ``donate_argnums`` /
@@ -95,9 +94,7 @@ _DEFAULT_ENTRIES = 256
 
 
 def _env_capacity() -> int:
-    raw = os.environ.get("H2O_TPU_EXEC_STORE") or \
-        os.environ.get("H2O_TPU_DISPATCH_CACHE")
-    return int(raw or _DEFAULT_ENTRIES)
+    return int(os.environ.get("H2O_TPU_EXEC_STORE") or _DEFAULT_ENTRIES)
 
 
 def store_dir() -> Optional[str]:

@@ -555,7 +555,7 @@ def run_fused_groupby(fr: Frame, stages, gcols: Sequence[int],
 # ---------------------------------------------------------------------------
 # the rapids.fuse autotuner lever: fused vs per-verb, per (row bucket,
 # chain kind), bitwise parity gate against the per-verb reference.
-# H2O_TPU_RAPIDS_FUSE forces it outright (the test/bench/audit
+# H2O_TPU_RAPIDS_FUSE forces it outright (the test/audit
 # convention, like H2O_TPU_BINS_PACK); in auto mode CPU backends keep
 # the per-verb reference and TPU backends measure.
 # ---------------------------------------------------------------------------

@@ -23,9 +23,6 @@ everywhere else):
   interconnect; no host staging), also accepting host arrays from the
   host-fallback munge paths (those route through the shard-direct
   placement above).
-
-``H2O_TPU_SHARD_LANDING=0`` restores the legacy single-put path (the
-parity oracle for the landing tests).
 """
 
 from __future__ import annotations
@@ -37,8 +34,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# knob defaults + docs live in h2o_tpu/config.py
-from h2o_tpu.config import shard_landing_enabled  # noqa: F401
 from h2o_tpu.core.log import get_logger
 
 log = get_logger("landing")
@@ -48,12 +43,9 @@ _counters = {
     "chunks_landed": 0,      # land_rows calls
     "bytes_landed": 0,       # logical bytes placed (sum over shards)
     "shard_transfers": 0,    # individual per-shard host->device puts
-    "whole_puts": 0,         # legacy single-put landings (gated path)
     "reshards": 0,           # device->device reshard_rows calls
     "max_transfer_bytes": 0, # largest SINGLE host->device transfer
 }
-
-
 
 
 def _note_transfer(nbytes: int, shards: int = 1) -> None:
@@ -121,13 +113,6 @@ def land_rows(host_array, sharding: Optional[NamedSharding] = None
     sh = sharding if sharding is not None else _row_sharding_for(arr.ndim)
     with _lock:
         _counters["chunks_landed"] += 1
-    if not shard_landing_enabled():
-        with _lock:
-            _counters["whole_puts"] += 1
-            _counters["bytes_landed"] += int(arr.nbytes)
-        _note_transfer(int(arr.nbytes))
-        # graftlint: disable=GL304  legacy single-put parity oracle
-        return jax.device_put(arr, sh)
     return _place(arr, sh)
 
 

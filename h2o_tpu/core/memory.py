@@ -8,8 +8,8 @@ TPU-native, the managed heap spans THREE tiers:
 
 - **HBM** — a Vec's live device payload.  Every frame column registers
   its device bytes here; when an allocation would exceed the budget
-  (``H2O_TPU_HBM_BUDGET`` / ``H2O_TPU_MEM_BUDGET`` bytes, or
-  ``OptArgs.hbm_budget``; 0 = unlimited), the least-recently-used
+  (``H2O_TPU_HBM_BUDGET`` bytes, or ``OptArgs.hbm_budget``; 0 =
+  unlimited), the least-recently-used
   resident columns are spilled: the device array is dropped (XLA frees
   the HBM) after a host copy is parked on the Vec.
 - **Host** — the parked copy, held as :class:`HostBlocks`: the column

@@ -82,7 +82,7 @@ class OOMError(RuntimeError):
 
 # message markers of a Mosaic/Pallas custom-kernel compile failure — a
 # shape Mosaic refuses must degrade to the portable XLA path, not kill
-# the training job (chip_smoke.py proves the bench shapes do compile)
+# the training job (chip_smoke.py proves the HIGGS shapes do compile)
 _KERNEL_MARKERS = ("Mosaic", "mosaic", "Pallas", "pallas", "VMEM",
                    "custom_call_target", "tpu_custom_call")
 

@@ -25,26 +25,15 @@ import numpy as np
 from h2o_tpu.core.frame import Frame, Vec
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
 from h2o_tpu.models.tree import shared_tree as st
-from h2o_tpu.ops.binpack import pick_bin
+from h2o_tpu.ops.descend import descend
 
 
 @functools.partial(jax.jit, static_argnames=("depth",))
 def _terminal_nodes(bins, split_col, bitset, depth: int):
     """(R, T) heap index of each row's terminal node in every tree."""
-    T, H = split_col.shape
-    R = bins.shape[0]
-
     def one_tree(carry, tree):
         sc, bs = tree
-        node = jnp.zeros((R,), jnp.int32)
-        for _ in range(depth):
-            c = sc[node]
-            term = c < 0
-            b = pick_bin(bins, jnp.maximum(c, 0))
-            go_left = bs[node, b]
-            nxt = 2 * node + jnp.where(go_left, 1, 2)
-            node = jnp.where(term, node, nxt)
-        return carry, node
+        return carry, descend(bins, sc, bs, depth)
 
     _, nodes = jax.lax.scan(one_tree, 0, (split_col, bitset))
     return nodes.T                               # (R, T)

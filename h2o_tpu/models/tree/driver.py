@@ -26,10 +26,7 @@ the forest into blocks reproduces the same trees.  A terminal OOM (or
 any crash) inside a speculative launch first persists the completed-
 but-uncheckpointed previous block, so Recovery resumes after it.
 
-ASYNC DOUBLE-BUFFERING (H2O_TPU_ASYNC_DRIVER, default on): the original
-loop blocked on ``np.asarray`` per block, serializing host
-materialization of block *t*'s tree arrays against the device build of
-block *t+1*.  Now block *t+1* is DISPATCHED before block *t* is
+DOUBLE-BUFFERING: block *t+1* is DISPATCHED before block *t* is
 materialized — the only device->host data t+1 needs is the carried F,
 which never leaves the device — and block *t*'s arrays are pulled with
 ``copy_to_host_async`` so the transfer rides under t+1's compute.  Only
@@ -37,15 +34,14 @@ the ScoreKeeper decision point synchronizes (its metrics need host
 values); an early stop discards the one speculatively-launched block,
 which is why speculative launches never donate their F0 (the stop path
 and the training-frame scorer still read the previous block's f_final).
-Tree outputs are bitwise identical to the synchronous path: the RNG
-stream is split in the same order, and discarded speculative keys are
-exactly the keys the synchronous path never consumes.
+The forest is bitwise the one a single block of ``ntrees`` builds: every
+tree's key folds its absolute index into the master key, and a discarded
+block's trees are exactly the ones the model never holds.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 import time
 from typing import Callable, Dict, Optional
 
@@ -57,12 +53,6 @@ from h2o_tpu.core.chaos import chaos
 from h2o_tpu.core.diag import DispatchStats, TimeLine
 from h2o_tpu.core.oom import oom_ladder
 from h2o_tpu.models.score_keeper import ScoreKeeper
-
-
-def async_driver_enabled() -> bool:
-    """H2O_TPU_ASYNC_DRIVER=0 restores the fully synchronous block loop
-    (the bitwise-equality reference the overlap tests compare against)."""
-    return os.environ.get("H2O_TPU_ASYNC_DRIVER", "1") != "0"
 
 
 def _set_node_array(model, name: str, new: np.ndarray) -> None:
@@ -346,19 +336,18 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                     st["scorer_F"], int(scorer.F.shape[0])))
             job.update(0.05 + 0.85 * done / ntrees,
                        f"resumed mid-forest at {prior_trees + done} trees")
-    use_async = async_driver_enabled()
     may_stop = (rounds > 0 and scorer is not None) or max_rt > 0
     # speculative launches must not donate their F0: on an early stop /
     # runtime-budget break the discarded block's INPUT (the last kept
     # block's f_final) is still read by make_model, recovery checkpoints
     # np.asarray the post-block F after the next block has already been
     # dispatched, and a training-frame scorer reads block t's f_final as
-    # its F when block t is absorbed, after t+1's launch.  Sync mode (and
-    # async with none of those readers) uses the default donation policy
-    # — the carry is then written in place across blocks.
+    # its F when block t is absorbed, after t+1's launch.  With none of
+    # those readers the default donation policy applies — the carry is
+    # then written in place across blocks.
     reads_carry = scorer is not None and not scorer.is_validation
-    donate_launch = False if (use_async and (
-        may_stop or recovery is not None or reads_carry)) else None
+    donate_launch = False if (
+        may_stop or recovery is not None or reads_carry) else None
     launched = done
     no_donate = False       # latched by the OOM ladder: retries re-read F
 
@@ -420,8 +409,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         already-completed previous block)."""
         nonlocal vi_total, done
         tf, n = cur["tf"], cur["n"]
-        # spans are HOST time: under the async driver block t+1 is
-        # already queued, so "score" also waits for t+1's build
+        # spans are HOST time: block t+1 is already queued, so "score"
+        # also waits for t+1's build
         with TimeLine.span("train", "block.absorb",
                            t0=prior_trees + cur["off"], n=n):
             with TimeLine.span("train", "block.pull") as pulled:
@@ -483,38 +472,33 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         return stop
 
     pend = None
-    if use_async and done < ntrees:
+    if done < ntrees:
         pend = _launch(launched, min(block, ntrees - launched))
         launched += pend["n"]
     while done < ntrees:
-        if use_async:
-            cur = pend
-            pend = None
-            if launched < ntrees:
-                # dispatch block t+1 BEFORE materializing block t — the
-                # host pulls below overlap its device build; only the
-                # ScoreKeeper decision point below synchronizes
-                try:
-                    pend = _launch(launched,
-                                   min(block, ntrees - launched))
-                    launched += pend["n"]
-                except BaseException:
-                    # the speculative launch died (crash, terminal OOM)
-                    # with block t complete on device but NOT yet
-                    # checkpointed — persist it best-effort before
-                    # propagating, so Recovery resumes AFTER it instead
-                    # of losing it (durability beats overlap on the
-                    # death path)
-                    if recovery is not None and cur is not None:
-                        try:
-                            _absorb(cur)
-                            cur = None
-                        except BaseException:  # noqa: BLE001
-                            pass               # dying anyway
-                    raise
-        else:
-            cur = _launch(launched, min(block, ntrees - launched))
-            launched += cur["n"]
+        cur = pend
+        pend = None
+        if launched < ntrees:
+            # dispatch block t+1 BEFORE materializing block t — the
+            # host pulls below overlap its device build; only the
+            # ScoreKeeper decision point below synchronizes
+            try:
+                pend = _launch(launched, min(block, ntrees - launched))
+                launched += pend["n"]
+            except BaseException:
+                # the speculative launch died (crash, terminal OOM)
+                # with block t complete on device but NOT yet
+                # checkpointed — persist it best-effort before
+                # propagating, so Recovery resumes AFTER it instead
+                # of losing it (durability beats overlap on the
+                # death path)
+                if recovery is not None and cur is not None:
+                    try:
+                        _absorb(cur)
+                        cur = None
+                    except BaseException:  # noqa: BLE001
+                        pass               # dying anyway
+                raise
         tf = cur["tf"]
         stop = _absorb(cur)
         if not stop and max_rt > 0 and time.time() - t_start > max_rt:

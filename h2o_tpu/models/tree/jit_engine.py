@@ -50,6 +50,7 @@ from h2o_tpu.models.distributions import get_distribution
 from h2o_tpu.models.tree.shared_tree import find_splits
 from h2o_tpu.ops import statpack
 from h2o_tpu.ops.binpack import pick_bin
+from h2o_tpu.ops.descend import descend
 from h2o_tpu.ops.histogram import histogram_build_traced as _shard_histogram
 
 EPS = 1e-10
@@ -254,6 +255,27 @@ def _mm_route_level(bins, lf, s, do_split, L: int, Bd: int, cat_choice,
         do_lf = _mm_pick(leafhot, do_split.astype(jnp.float32)[:, None]
                          )[:, 0] > 0.5
     return go_left, do_lf
+
+
+def _gather_route_level(bins, lf, s, do_split, Bd: int, cat_choice=None,
+                        adaptive: bool = False, thr_leaf=None, F: int = -1):
+    """A level's routing from the level's split record, gather form —
+    the ONE statement of the rule during growth (both engines, uplift
+    and the tuner's probe call it; ``_mm_route_level`` is its bitwise
+    twin): returns (go_left, do_split[lf]).  A row takes its leaf's
+    split column's bin; a bitset split looks the bin up in the leaf's
+    left set (slot ``Bd`` = NA), an adaptive numeric split compares it
+    with the leaf's fine-bin threshold and sends bin ``F`` (NA) by the
+    leaf's ``na_left``."""
+    c = s["col"][lf]
+    b = pick_bin(bins, c)
+    if adaptive:
+        gset = s["bitset"][lf, jnp.minimum(b, Bd)]
+        gthr = jnp.where(b == F, s["na_left"][lf], b < thr_leaf[lf])
+        go_left = jnp.where(cat_choice[lf], gset, gthr)
+    else:
+        go_left = s["bitset"][lf, b]
+    return go_left, do_split[lf]
 
 
 def _node_val(wg, wh, w, newton: bool, reg_lambda: float = 0.0):
@@ -473,16 +495,9 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
                     bins, lf, s, do_split, L, Bd if adaptive else B,
                     cat_choice, adaptive, thr_leaf, F)
             else:
-                c = s["col"][lf]
-                b = pick_bin(bins, c)
-                if adaptive:
-                    gset = s["bitset"][lf, jnp.minimum(b, Bd)]
-                    gthr = jnp.where(b == F, s["na_left"][lf],
-                                     b < thr_leaf[lf])
-                    go_left = jnp.where(cat_choice[lf], gset, gthr)
-                else:
-                    go_left = s["bitset"][lf, b]
-                do_lf = do_split[lf]
+                go_left, do_lf = _gather_route_level(
+                    bins, lf, s, do_split, Bd, cat_choice, adaptive,
+                    thr_leaf, F)
             child = 2 * lf + jnp.where(go_left, 0, 1)
             leaf = jnp.where(active & do_lf, child,
                              jnp.where(active, -1, leaf))
@@ -686,16 +701,9 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
                     inv_c = _mm_pick(candhot, inv.astype(jnp.float32)[:, None]
                                      )[:, 0].astype(jnp.int32)
                 else:
-                    c = s["col"][sl]
-                    b = pick_bin(bins, c)
-                    if adaptive:
-                        gset = s["bitset"][sl, jnp.minimum(b, Bd)]
-                        gthr = jnp.where(b == F, s["na_left"][sl],
-                                         b < thr_leaf[sl])
-                        go_left = jnp.where(cat_choice[sl], gset, gthr)
-                    else:
-                        go_left = s["bitset"][sl, b]
-                    do_sl = do_split[sl]
+                    go_left, do_sl = _gather_route_level(
+                        bins, sl, s, do_split, Bd, cat_choice, adaptive,
+                        thr_leaf, F)
                     cand = 2 * sl + jnp.where(go_left, 0, 1)
                     inv_c = inv[cand]
                 new_slot = jnp.where(active & do_sl, inv_c, -1)
@@ -727,63 +735,50 @@ def _tree_predict(bins, split_col, bitset, value, D: int, child=None,
     ``thr``/``na_l`` carry adaptive numeric thresholds.  ``mm`` routes the
     per-level lookups through one-hot matmuls (gather-free; identical
     results) when the node table is small enough."""
+    if not (mm and split_col.shape[0] <= _MM_ROUTE_MAX_TABLE):
+        return value[descend(bins, split_col, bitset, D, child=child,
+                             thr=thr, na_l=na_l, fine_na=fine_na)]
     R, C = bins.shape
     B = bitset.shape[-1] - 1
     H = split_col.shape[0]
     node = jnp.zeros((R,), jnp.int32)
-    use_mm = mm and H <= _MM_ROUTE_MAX_TABLE
     for _ in range(D):
-        if use_mm:
-            nodehot = node[:, None] == jnp.arange(H)[None, :]  # (R, H)
-            tbl = [split_col.astype(jnp.float32),
-                   (thr if thr is not None else
-                    jnp.full((H,), -1, jnp.int32)).astype(jnp.float32),
-                   (na_l if na_l is not None else
-                    jnp.zeros((H,), bool)).astype(jnp.float32),
-                   (child if child is not None else
-                    jnp.full((H,), -1, jnp.int32)).astype(jnp.float32)]
-            V = _mm_pick(nodehot, jnp.stack(tbl, axis=1))      # (R, 4)
-            c = V[:, 0].astype(jnp.int32)
-            term = c < 0
-            colhot = jnp.maximum(c, 0)[:, None] == \
-                jnp.arange(C)[None, :]
-            b = jnp.sum(bins.astype(jnp.float32) * colhot,
-                        axis=1).astype(jnp.int32)
-            T = _mm_pick(nodehot, bitset)                      # (R, B+1)
-            nb = jnp.minimum(b, B)
-            gl = jnp.sum(
-                T * (nb[:, None] == jnp.arange(B + 1)[None, :]),
-                axis=1) > 0.5
-            if thr is None:
-                go_left = gl
-            else:
-                tn = V[:, 1].astype(jnp.int32)
-                go_left = jnp.where(
-                    tn >= 0,
-                    jnp.where(b == fine_na, V[:, 2] > 0.5, b < tn), gl)
-            if child is None:
-                nxt = 2 * node + jnp.where(go_left, 1, 2)
-            else:
-                left = V[:, 3].astype(jnp.int32)
-                term = term | (left < 0)
-                nxt = left + jnp.where(go_left, 0, 1)
+        nodehot = node[:, None] == jnp.arange(H)[None, :]      # (R, H)
+        tbl = [split_col.astype(jnp.float32),
+               (thr if thr is not None else
+                jnp.full((H,), -1, jnp.int32)).astype(jnp.float32),
+               (na_l if na_l is not None else
+                jnp.zeros((H,), bool)).astype(jnp.float32),
+               (child if child is not None else
+                jnp.full((H,), -1, jnp.int32)).astype(jnp.float32)]
+        V = _mm_pick(nodehot, jnp.stack(tbl, axis=1))          # (R, 4)
+        c = V[:, 0].astype(jnp.int32)
+        term = c < 0
+        colhot = jnp.maximum(c, 0)[:, None] == \
+            jnp.arange(C)[None, :]
+        b = jnp.sum(bins.astype(jnp.float32) * colhot,
+                    axis=1).astype(jnp.int32)
+        T = _mm_pick(nodehot, bitset)                          # (R, B+1)
+        nb = jnp.minimum(b, B)
+        gl = jnp.sum(
+            T * (nb[:, None] == jnp.arange(B + 1)[None, :]),
+            axis=1) > 0.5
+        if thr is None:
+            go_left = gl
         else:
-            from h2o_tpu.models.tree.shared_tree import _go_left
-            c = split_col[node]
-            term = c < 0
-            b = pick_bin(bins, jnp.maximum(c, 0))
-            go_left = _go_left(bitset, node, b, thr, na_l, fine_na, B)
-            if child is None:
-                nxt = 2 * node + jnp.where(go_left, 1, 2)
-            else:
-                left = child[node]
-                term = term | (left < 0)
-                nxt = left + jnp.where(go_left, 0, 1)
+            tn = V[:, 1].astype(jnp.int32)
+            go_left = jnp.where(
+                tn >= 0,
+                jnp.where(b == fine_na, V[:, 2] > 0.5, b < tn), gl)
+        if child is None:
+            nxt = 2 * node + jnp.where(go_left, 1, 2)
+        else:
+            left = V[:, 3].astype(jnp.int32)
+            term = term | (left < 0)
+            nxt = left + jnp.where(go_left, 0, 1)
         node = jnp.where(term, node, nxt)
-    if use_mm:
-        nodehot = node[:, None] == jnp.arange(H)[None, :]
-        return _mm_pick(nodehot, value[:, None])[:, 0]
-    return value[node]
+    nodehot = node[:, None] == jnp.arange(H)[None, :]
+    return _mm_pick(nodehot, value[:, None])[:, 0]
 
 
 def _hist_bucket(args, kwargs):

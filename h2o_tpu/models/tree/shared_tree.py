@@ -44,7 +44,8 @@ from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import Frame
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
 from h2o_tpu.ops.binpack import (bins_bucket, bins_pack_enabled, cast_bins,
-                                 packed_dtype_name, pick_bin)
+                                 packed_dtype_name)
+from h2o_tpu.ops.descend import descend
 from h2o_tpu.ops.histogram import histogram_build
 
 EPS = 1e-10
@@ -559,20 +560,6 @@ def find_splits(hist, is_cat, col_allowed, min_rows: float = 10.0,
                 leaf=leaf_stats, left=left_stats, right=right_stats)
 
 
-@jax.jit
-def _advance_leaves(bins, leaf, do_split, col, bitset):
-    """Route active rows to children; deactivate rows in terminal leaves."""
-    active = leaf >= 0
-    lf = jnp.maximum(leaf, 0)
-    c = col[lf]
-    b = pick_bin(bins, c)
-    go_left = bitset[lf, b]
-    # level-LOCAL child index (heap index = level_offset + local)
-    child = 2 * lf + jnp.where(go_left, 0, 1)
-    splits = do_split[lf]
-    return jnp.where(active & splits, child, jnp.where(active, -1, leaf))
-
-
 # ---------------------------------------------------------------------------
 # tree storage + scoring
 # ---------------------------------------------------------------------------
@@ -587,19 +574,6 @@ class Forest(NamedTuple):
     depth: int
     nbins: int
     child: object = None   # int32 (T, K, N) or None
-
-
-def _go_left(bs, node, b, th, na, fine_na: int, B: int):
-    """Mixed split semantics: thr >= 0 -> adaptive numeric threshold in
-    fine-bin units (NA routed by na); thr < 0 -> bitset membership
-    (categorical splits, and every split of pre-adaptive models)."""
-    nb = jnp.minimum(b, B)                       # NA (fine_na) -> slot B
-    gl = bs[node, nb]
-    if th is None:
-        return gl
-    tn = th[node]
-    return jnp.where(tn >= 0,
-                     jnp.where(b == fine_na, na[node], b < tn), gl)
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "fine_na"))
@@ -625,7 +599,6 @@ def forest_tree_values(bins, split_col, bitset, value, depth: int,
     staged predictions (GBMModel.StagedPredictionsTask)."""
     T, K, H = split_col.shape
     R = bins.shape[0]
-    B = bitset.shape[-1] - 1
 
     def one_tree(carry, tk):
         sc, bs, vl = tk[0], tk[1], tk[2]
@@ -633,20 +606,8 @@ def forest_tree_values(bins, split_col, bitset, value, depth: int,
         ch = rest.pop(0) if child is not None else None
         th = rest.pop(0) if thr is not None else None
         na = rest.pop(0) if thr is not None else None
-        node = jnp.zeros((R,), jnp.int32)
-        for _ in range(depth):
-            c = sc[node]
-            term = c < 0
-            b = pick_bin(bins, jnp.maximum(c, 0))
-            go_left = _go_left(bs, node, b, th, na, fine_na, B)
-            if ch is None:
-                nxt = 2 * node + jnp.where(go_left, 1, 2)
-            else:
-                left = ch[node]
-                term = term | (left < 0)
-                nxt = left + jnp.where(go_left, 0, 1)
-            node = jnp.where(term, node, nxt)
-        return carry, vl[node]
+        return carry, vl[descend(bins, sc, bs, depth, child=ch, thr=th,
+                                 na_l=na, fine_na=fine_na)]
 
     xs = (split_col.reshape(T * K, H),
           bitset.reshape(T * K, H, -1),

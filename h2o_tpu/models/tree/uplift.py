@@ -30,7 +30,8 @@ from h2o_tpu.models import metrics as mm
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
 from h2o_tpu.core.autotune import hist_bucket
 from h2o_tpu.models.tree import shared_tree as st
-from h2o_tpu.ops.binpack import pick_bin
+from h2o_tpu.models.tree.jit_engine import (_gather_route_level,
+                                            frontier_plan)
 from h2o_tpu.ops.histogram import histogram_build_traced, pallas_env_enabled
 
 EPS = 1e-6
@@ -145,7 +146,6 @@ def _train_uplift_forest(bins, treat, yv, w, active, key, *, ntrees: int,
     size, nodes in a grows-with-splits pool with explicit child
     pointers.  Child rates come from the split's own cumsums, so no
     extra final-level histogram pass is needed."""
-    from h2o_tpu.models.tree.jit_engine import frontier_plan
     from h2o_tpu.ops import statpack
     R, C = bins.shape
     D, B = max_depth, nbins
@@ -226,11 +226,9 @@ def _train_uplift_forest(bins, treat, yv, w, active, key, *, ntrees: int,
                               jnp.arange(L_next, dtype=jnp.int32), -1))
                 act = slot >= 0
                 sl = jnp.maximum(slot, 0)
-                c = s["col"][sl]
-                b = pick_bin(bins, c)
-                go_left = s["bitset"][sl, b]
+                go_left, do_sl = _gather_route_level(bins, sl, s, do, B)
                 cand = 2 * sl + jnp.where(go_left, 0, 1)
-                new_slot = jnp.where(act & do[sl], inv[cand], -1)
+                new_slot = jnp.where(act & do_sl, inv[cand], -1)
                 slot = jnp.where(act, new_slot, slot)
             base += 2 * L
         return carry, (split_col[:N], bitset[:N], val_t[:N], val_c[:N],

@@ -49,7 +49,7 @@ quantized carrier (``1``/``int16``, or ``int8``), force the f32
 reference (``0``/``f32``), or unset/``auto`` = measured decision (TPU
 only; CPU tiers keep the bitwise pre-lever f32 path with zero probes).
 The parity gate tolerance is the published table-level bound below —
-NOT bitwise, which is why the bench rung and tests additionally pin
+NOT bitwise, which is why the tests additionally pin
 whole-forest metrics (deviance/AUC) inside ``METRIC_TOL``.
 """
 
@@ -67,8 +67,8 @@ _CARRIER = {"int16": (jnp.int16, 32767), "int8": (jnp.int8, 127)}
 
 #: published whole-forest metric tolerance for the quantized carrier:
 #: deviance / AUC of an int16-stats forest must sit within this
-#: relative band of the f32 reference (tests/test_stats_pack.py and the
-#: ``stats_pack`` bench rung both assert it; the autotuner additionally
+#: relative band of the f32 reference (tests/test_stats_pack.py asserts
+#: it; the autotuner additionally
 #: disqualifies a candidate whose probe tables drift past TABLE_TOL).
 METRIC_TOL = 0.02
 

@@ -12,9 +12,10 @@ tests pin the dispatch layer's invariants:
 - donation: trained-model outputs are bitwise-identical with
   H2O_TPU_DONATE=0/1 (on XLA:CPU donation is a no-op alias-wise, but it
   must select the donating executable without changing results).
-- async driver: H2O_TPU_ASYNC_DRIVER=0/1 produce bitwise-identical
-  forests, and the TimeLine event order proves block *t+1* is DISPATCHED
-  before block *t* is materialized (the overlap).
+- block loop: any partition of a forest into blocks (an early stop's
+  discarded block included) builds the forest one block of ``ntrees``
+  builds, bitwise, and the TimeLine event order proves block *t+1* is
+  DISPATCHED before block *t* is materialized (the overlap).
 """
 
 import numpy as np
@@ -185,47 +186,46 @@ def test_donation_bitwise_identical(cl, rng, monkeypatch):
         m_on.output["training_metrics"]["logloss"]
 
 
-# ---------------------------------------------------------- async driver
+# ------------------------------------------------------------ block loop
 
 
-def test_async_driver_bitwise_equals_sync(cl, rng, monkeypatch):
+def test_blocked_forest_bitwise_equals_one_block(cl, rng):
+    # the driver's contract: any partition of the forest into blocks
+    # reproduces the identical forest bitwise.  The reference is the
+    # same forest trained in ONE block (block = ntrees).
     fr = _toy_binomial(rng)
-    monkeypatch.setenv("H2O_TPU_ASYNC_DRIVER", "0")
-    m_sync = _gbm(rng, fr, score_tree_interval=2)
-    monkeypatch.setenv("H2O_TPU_ASYNC_DRIVER", "1")
-    m_async = _gbm(rng, fr, score_tree_interval=2)
-    a, b = _forest_arrays(m_sync), _forest_arrays(m_async)
-    for k in a:
+    m_one = _gbm(rng, fr, score_tree_interval=6)
+    m_blocked = _gbm(rng, fr, score_tree_interval=2)
+    a, b = _forest_arrays(m_one), _forest_arrays(m_blocked)
+    for k in ("split_col", "value"):
         np.testing.assert_array_equal(a[k], b[k], err_msg=k)
-    assert len(m_sync.output["scoring_history"]) == \
-        len(m_async.output["scoring_history"])
+    # the blocks' importances are added on the host: another order
+    np.testing.assert_allclose(a["varimp"], b["varimp"], rtol=1e-6)
+    assert len(m_one.output["scoring_history"]) == 1
+    assert len(m_blocked.output["scoring_history"]) == 3
 
 
-def test_async_driver_bitwise_under_early_stop(cl, rng, monkeypatch):
+def test_early_stop_keeps_prefix_of_one_block_forest(cl, rng):
     # the speculative-discard path: an early stop throws away the
-    # already-launched block t+1 — the kept forest must equal sync's
+    # already-launched block t+1 — the kept forest must be the prefix
+    # of the same forest trained in one block with no stopping
     fr = _toy_binomial(rng, n=1500)
-
-    def mk():
-        return _gbm(rng, fr, ntrees=40, learn_rate=0.5,
-                    stopping_rounds=2, stopping_tolerance=1e-2,
-                    score_tree_interval=2)
-    monkeypatch.setenv("H2O_TPU_ASYNC_DRIVER", "0")
-    m_sync = mk()
-    monkeypatch.setenv("H2O_TPU_ASYNC_DRIVER", "1")
-    m_async = mk()
-    assert m_sync.output["ntrees_actual"] == \
-        m_async.output["ntrees_actual"]
-    a, b = _forest_arrays(m_sync), _forest_arrays(m_async)
-    for k in a:
-        np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+    m_stop = _gbm(rng, fr, ntrees=40, learn_rate=0.5,
+                  stopping_rounds=2, stopping_tolerance=5e-2,
+                  score_tree_interval=2)
+    kept = int(m_stop.output["ntrees_actual"])
+    assert 0 < kept < 40        # it did stop, and discarded a block
+    m_one = _gbm(rng, fr, ntrees=40, learn_rate=0.5,
+                 score_tree_interval=40)
+    a, b = _forest_arrays(m_one), _forest_arrays(m_stop)
+    for k in ("split_col", "value"):
+        np.testing.assert_array_equal(a[k][:kept], b[k], err_msg=k)
 
 
-def test_async_driver_overlaps_blocks(cl, rng, monkeypatch):
-    """The overlap proof: in async mode block t+1's device launch is
-    recorded BEFORE block t's host materialization — host transfer of
-    one block rides under the next block's compute."""
-    monkeypatch.setenv("H2O_TPU_ASYNC_DRIVER", "1")
+def test_driver_overlaps_blocks(cl, rng):
+    """The overlap proof: block t+1's device launch is recorded BEFORE
+    block t's host materialization — host transfer of one block rides
+    under the next block's compute."""
     fr = _toy_binomial(rng)
     TimeLine.clear()
     _gbm(rng, fr, ntrees=6, score_tree_interval=2)
@@ -241,12 +241,11 @@ def test_async_driver_overlaps_blocks(cl, rng, monkeypatch):
         assert launches[t0 + 2] < mats[t0], (launches, mats)
 
 
-def test_async_driver_overlap_under_slow_transfer(cl, rng, monkeypatch):
-    """Chaos slow-transfer widens the host window; the async pipeline
-    must still produce the bitwise-identical forest."""
+def test_driver_overlap_under_slow_transfer(cl, rng):
+    """Chaos slow-transfer widens the host window; the pipeline must
+    still produce the bitwise-identical forest."""
     from h2o_tpu.core import chaos as chaos_mod
     fr = _toy_binomial(rng, n=800)
-    monkeypatch.setenv("H2O_TPU_ASYNC_DRIVER", "1")
     m_ref = _gbm(rng, fr, score_tree_interval=2)
     chaos_mod.configure(transfer_slow_p=1.0, transfer_slow_ms=5, seed=0)
     try:

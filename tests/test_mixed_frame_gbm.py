@@ -1,5 +1,5 @@
 """GBM on a mixed-type frame, against the plain reference
-(``tests/reference_gbm_mixed.py``: numpy float64, no program import).
+(``benchmark/reference/gbm_mixed.py``: numpy float64, no program import).
 
 - the program's trees on seeded frames of 4,000 rows (numeric columns
   with missing values beside enum columns of 3 / 29 / 352 levels, depth
@@ -21,17 +21,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _frames import CARD, CARDS, frame_of, mixed_columns
+from benchmark.reference.gbm_mixed import (GbmMixedReference, Spec,
+                                           trees_from_artifact)
 from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import Frame, Vec, T_CAT
 from h2o_tpu.models.tree import shared_tree as st
 from h2o_tpu.models.tree.gbm import GBM
-from reference_gbm_mixed import (GbmMixedReference, Spec,
-                                 trees_from_artifact)
 
 ROWS, DEPTH, NBINS, MIN_ROWS = 4000, 4, 255, 10
-CARDS = (3, 29, 352)
-NAMES = ["n0", "c3", "n1", "c29", "c352", "n2"]
-CARD = [0, 3, 0, 29, 352, 0]
 
 # Tolerances.  The program sums float32 statistics of 4,000 rows on the
 # CPU mesh (exact float32 products, eight shards added in float32); the
@@ -45,32 +43,6 @@ CARD = [0, 3, 0, 29, 352, 0]
 TOL = {"rank_gap": 0.0, "split_gap": 1e-6, "median_leaf_gap": 1e-5,
        "leaf_value_gap": 1e-5, "update_gap": 1e-5, "logloss_gap": 1e-6,
        "f0_gap": 1e-6}
-
-
-def mixed_columns(seed: int, na_share: float, rows: int = ROWS):
-    """Three numeric columns (the first with missing values) and three
-    enum columns of 3 / 29 / 352 levels; a logistic response on level
-    effects, the numeric columns and missingness."""
-    rng = np.random.default_rng(seed)
-    num = [rng.normal(size=rows).astype(np.float32) for _ in range(3)]
-    miss = rng.random(rows) < na_share
-    num[0][miss] = np.nan
-    cat = [rng.integers(0, k, rows).astype(np.int32) for k in CARDS]
-    if na_share:
-        cat[1][rng.random(rows) < na_share / 2] = -1     # a missing enum
-    eff = [rng.normal(0.0, s, k) for k, s in zip(CARDS, (0.7, 0.6, 0.8))]
-    z = (0.6 * np.nan_to_num(num[0]) + 0.8 * miss - 0.5 * num[1]
-         + sum(e[np.maximum(c, 0)] for e, c in zip(eff, cat)))
-    y = (rng.random(rows) < 1.0 / (1.0 + np.exp(-z))).astype(np.int32)
-    cols = [num[0], cat[0], num[1], cat[1], cat[2], num[2]]
-    return cols, y
-
-
-def frame_of(cols, y, names=NAMES, card=CARD):
-    vecs = [Vec(c, T_CAT, domain=[f"L{i}" for i in range(k)]) if k
-            else Vec(c) for c, k in zip(cols, card)]
-    return Frame(list(names) + ["y"],
-                 vecs + [Vec(y, T_CAT, domain=["no", "yes"])])
 
 
 def gbm(**kw):

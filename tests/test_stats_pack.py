@@ -341,7 +341,7 @@ def test_corrupted_quantized_candidate_disqualified(monkeypatch):
 def test_memory_stats_account_true_packed_stat_nbytes():
     """MemoryManager byte accounting is exact for a quantized stats
     holder: an int16 (R, S) carrier registers R*S*2 bytes — half of
-    f32 — and the bench's bytes model matches the real array."""
+    f32 — and the bytes model matches the real array."""
     import jax
     import jax.numpy as jnp
     from h2o_tpu.core.memory import MemoryManager

@@ -4,7 +4,7 @@ core/memory.py tiers, mrtask.FrameBlockStreamer).
 The acceptance drills for training on frames bigger than HBM:
 
 - shard-direct landing: no single host->device transfer ever exceeds
-  ONE shard (landing.stats() pull accounting, whole_puts == 0);
+  ONE shard (landing.stats() pull accounting);
 - streamed prepare_bins is BITWISE equal to the full-matrix path, and
   a bounded-HBM GBM produces a forest BITWISE equal to the unbounded
   run with ZERO steady-state recompiles;
@@ -98,7 +98,6 @@ def test_landing_shard_direct_pull_accounting(cl, rng):
     padded = arr.shape[0]
     assert padded % cl.row_multiple() == 0
     st = landing.stats()
-    assert st["whole_puts"] == 0
     assert st["chunks_landed"] >= 1
     assert st["shard_transfers"] >= cl.n_nodes
     shard_bytes = (padded // cl.n_nodes) * host.dtype.itemsize
@@ -108,17 +107,24 @@ def test_landing_shard_direct_pull_accounting(cl, rng):
     assert np.isnan(back[n:]).all()
 
 
-def test_landing_gate_off_single_put(cl, rng, monkeypatch):
-    """H2O_TPU_SHARD_LANDING=0 restores the legacy whole-array put —
-    the parity oracle — and the accounting records it as such."""
+def test_landing_rows_equal_host_padding_nan(cl, rng):
+    """The shard path's own check on a matrix whose rows are already
+    aligned and one that needs padding: the landed rows equal the host
+    rows, the padding is NaN, one transfer a shard and nothing wider."""
     from h2o_tpu.core import landing
-    monkeypatch.setenv("H2O_TPU_SHARD_LANDING", "0")
-    landing.reset_stats()
-    host = rng.normal(size=cl.row_multiple() * 2).astype(np.float32)
-    arr = cl.device_put_rows(host)
-    st = landing.stats()
-    assert st["whole_puts"] == 1
-    np.testing.assert_array_equal(np.asarray(arr)[: host.size], host)
+    for n in (cl.row_multiple() * 2, cl.row_multiple() * 2 + 5):
+        landing.reset_stats()
+        host = rng.normal(size=(n, 3)).astype(np.float32)
+        arr = cl.device_put_rows(host)
+        st = landing.stats()
+        assert st["chunks_landed"] == 1
+        assert st["shard_transfers"] == cl.n_nodes
+        assert st["bytes_landed"] == arr.nbytes
+        assert st["max_transfer_bytes"] == arr.nbytes // cl.n_nodes
+        back = np.asarray(arr)
+        np.testing.assert_array_equal(back[:n], host)
+        assert back.shape[0] % cl.row_multiple() == 0
+        assert np.isnan(back[n:]).all()
 
 
 # ---------------------------------------------------------------------------
@@ -189,7 +195,6 @@ def test_streamed_gbm_bitwise_prefetch_and_zero_recompiles(
     windows1 = ms1["prefetch_hits"] + ms1["prefetch_misses"]
     assert windows1 > windows0
     st = landing.stats()
-    assert st["whole_puts"] == 0
     full_matrix_bytes = data.padded_rows * 3 * 4
     assert st["max_transfer_bytes"] < full_matrix_bytes
 

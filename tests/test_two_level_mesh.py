@@ -11,8 +11,8 @@ The tentpole contract for core/cloud.py's two-level topology and the
   the same order as the flat axis) and to the host oracles;
 - the per-axis byte ledger (DispatchStats.note_collective) records DCN
   bytes only on two-level meshes, and only for the combine collectives
-  — O(table) cross-slice traffic, never O(rows) (the full row-count
-  independence claim is the ``dryrun_multichip`` bench rung);
+  — O(table) cross-slice traffic, never O(rows): the same bytes at two
+  row counts;
 - the membership survivor policy drops a whole SLICE per attempt on a
   two-level mesh (an ICI island is the DCN failure unit), and a slice
   loss mid-train reforms to the surviving slice and resumes bitwise;
@@ -300,6 +300,48 @@ def test_collective_byte_ledger(cl, reboot):
     coll = dispatch_route({})["dispatch"]["collectives"]
     assert any("sort.splitters" in t for ph in coll.values()
                for t in ph), coll
+
+
+# the combine collectives of each step: the tags whose DCN bytes must not
+# grow with the rows.  The sort's route all_to_all (sort.route) moves
+# O(rows) by design and is no combine.
+_COMBINE_TAGS = {"sort": ("sort.splitters", "sort.counts"),
+                 "groupby": ("groupby.count", "groupby.partials"),
+                 "hist": ("hist.table",)}
+
+
+@pytest.mark.parametrize("step", sorted(_COMBINE_TAGS))
+def test_combine_dcn_bytes_do_not_grow_with_rows(cl, reboot, step):
+    """Two-slice mesh, one step at two row counts (each a fresh bucket,
+    so each compiles and writes the trace-time ledger): the cross-slice
+    bytes of its combine collectives are positive and equal."""
+    import jax.numpy as jnp
+    from h2o_tpu.core import munge
+    from h2o_tpu.ops.histogram import histogram_build
+    reboot(*TWO)
+
+    def run(n):
+        fr = _torture_frame(n=n, seed=47)
+        c0 = _coll()
+        if step == "sort":
+            munge.sort_frame(fr, [1], [True])
+        elif step == "groupby":
+            munge.groupby_frame(fr, [2], [("sum", 3, "all"),
+                                          ("nrow", 3, "all")])
+        else:
+            rng = np.random.default_rng(9)
+            histogram_build(
+                jnp.asarray(rng.integers(0, 32, size=(n, 4)), jnp.int32),
+                jnp.asarray(rng.integers(0, 8, size=(n,)), jnp.int32),
+                jnp.asarray(rng.normal(size=(n, 4)), jnp.float32),
+                n_leaves=8, nbins=32).block_until_ready()
+        c1 = _coll()
+        return {t: v[1] - c0.get(t, [0, 0])[1] for t, v in c1.items()
+                if t.split(":", 1)[-1] in _COMBINE_TAGS[step]}
+
+    small, large = run(3000), run(12000)
+    assert any(v > 0 for v in small.values()), small
+    assert small == large, (small, large)
 
 
 # ---------------------------------------------------------------------------
