@@ -50,7 +50,6 @@ from h2o_tpu.models.distributions import get_distribution
 from h2o_tpu.models.tree.shared_tree import find_splits
 from h2o_tpu.ops import statpack
 from h2o_tpu.ops.binpack import pick_bin
-from h2o_tpu.ops.descend import descend
 from h2o_tpu.ops.histogram import histogram_build_traced as _shard_histogram
 
 EPS = 1e-10
@@ -330,10 +329,18 @@ def _hist_level_with_sibling(bins, slot, stats, L: int, B: int, cfg,
 def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
                       tree_col_mask=None, mono=None, inv_scale=None):
     """Traceable single-tree build.  Returns (split_col, bitset, value,
-    varimp), shapes (H,), (H, B+1), (H,), (C,) with H = 2^(D+1)-1.
+    varimp, node_gain, node_w, thr, na_left, pos), shapes (H,), (H, B+1),
+    (H,), (C,), (H,) x4, (R,) with H = 2^(D+1)-1.
     varimp accumulates each split's SE-reduction gain into its column —
     the reference's relative-importance convention (SharedTreeModel
     varimp from squared-error improvements).
+
+    ``pos`` is EVERY row's final node in this tree (heap index), the
+    node ``ops/descend.descend`` would reach over the returned arrays:
+    growth routes all R rows level by level, and ``leaf0`` (0 = the tree
+    is grown on this row, -1 = sampled out or inactive) only decides
+    which rows the histograms see.  The caller's F update reads
+    ``value[pos]`` and descends nothing.
 
     ``inv_scale`` non-None means ``stats`` is the quantized integer
     carrier (ops/statpack.py): tables come back exact int32 and are
@@ -356,7 +363,8 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
     node_w = jnp.zeros((H,), jnp.float32)      # per-node cover (TreeSHAP)
     thr_arr = jnp.full((H,), -1, jnp.int32)    # adaptive numeric splits
     na_arr = jnp.zeros((H,), bool)
-    leaf = leaf0
+    grown = leaf0 >= 0
+    pos = jnp.zeros(leaf0.shape, jnp.int32)
     use_mono = bool(cfg.get("use_mono")) and mono is not None
     # monotone value bounds per live leaf (XGBoost-style two-part scheme:
     # find_splits rejects violating splits, these clamp child values)
@@ -381,19 +389,25 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
         # root, halving per level down to nbins — per-level histogram
         # cost L * Bd stays ~constant
         Bd = max(B, F >> d) if adaptive else B
+        with jax.named_scope("h2o.tree.route"):
+            # this level's slot of every row whose node is on it (-1: the
+            # row's node stopped splitting higher up); the histograms see
+            # the rows the tree is grown on
+            leaf = jnp.where(pos >= off, pos - off, -1)
+            slot = jnp.where(grown, leaf, -1)
         if adaptive:
             with jax.named_scope("h2o.tree.split"):
                 key, sub = jax.random.split(key)
                 roff = _rand_offsets(sub, L, C, rlo, rhi, random_mode)
             hist = _shard_histogram(
-                bins, leaf, stats, L, Bd, cfg["block_rows"], cfg["bf16"],
+                bins, slot, stats, L, Bd, cfg["block_rows"], cfg["bf16"],
                 fine_map=(rlo, rhi, roff, is_cat, F),
                 pallas=cfg.get("pallas"))
         elif sib and d >= 1:
-            hist = _hist_level_with_sibling(bins, leaf, stats, L, B, cfg,
+            hist = _hist_level_with_sibling(bins, slot, stats, L, B, cfg,
                                             prev_hist, prev_do)
         else:
-            hist = _shard_histogram(bins, leaf, stats, L, B,
+            hist = _shard_histogram(bins, slot, stats, L, B,
                                     cfg["block_rows"], cfg["bf16"],
                                     pallas=cfg.get("pallas"))
         # the ONE integer->f32 crossing per level: split finding and
@@ -486,7 +500,9 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
                 node_w, jnp.where(child_mask, child_ws, cur_w), (coff,))
 
         with jax.named_scope("h2o.tree.route"):
-            # route rows
+            # route EVERY row standing on this level, grown-on or not, the
+            # last level included: where its node splits it moves to the
+            # child's heap slot, else it stays where it is for good
             active = leaf >= 0
             lf = jnp.maximum(leaf, 0)
             if cfg.get("mm_route") and L <= _MM_ROUTE_MAX_TABLE and \
@@ -499,8 +515,7 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
                     bins, lf, s, do_split, Bd, cat_choice, adaptive,
                     thr_leaf, F)
             child = 2 * lf + jnp.where(go_left, 0, 1)
-            leaf = jnp.where(active & do_lf, child,
-                             jnp.where(active, -1, leaf))
+            pos = jnp.where(active & do_lf, 2 * L - 1 + child, pos)
         with jax.named_scope("h2o.tree.split"):
             if adaptive and d + 1 < D:
                 new_lo, new_hi = _refine_ranges(hist_f, rlo, rhi, roff, Bd)
@@ -508,7 +523,7 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
                                          is_cat, do_split)
         prev_hist, prev_do = hist, do_split
     return (split_col, bitset, value, varimp, node_gain, node_w,
-            thr_arr, na_arr)
+            thr_arr, na_arr, pos)
 
 
 def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
@@ -526,7 +541,14 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
     left-``child`` pointer (right = left+1) — the sparse-CompressedTree
     analog (reference hex/tree/DTree.java:891-935).  Returns
     (split_col (N,), bitset (N, B+1), value (N,), child (N,),
-    varimp (C,), node_gain (N,)).
+    varimp (C,), node_gain (N,), node_w (N,), thr (N,), na_left (N,),
+    pos (R,)).
+
+    ``pos`` is every row's final node as a pool id, as in
+    ``build_tree_traced``: all R rows are routed (``slot0`` -1 only
+    keeps a row out of the histograms), the last level included; a row
+    whose child fell off the frontier ends AT that child, whose value is
+    pre-written.
     """
     D = cfg["max_depth"]
     B = cfg["nbins"]
@@ -551,7 +573,9 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
     varimp = jnp.zeros((C,), jnp.float32)
 
     frontier = jnp.zeros((1,), jnp.int32)          # pool ids of live leaves
-    slot = slot0                                   # per-row frontier slot
+    grown = slot0 >= 0                             # rows the histograms see
+    slot = jnp.zeros(slot0.shape, jnp.int32)       # per-row frontier slot
+    pos = jnp.zeros(slot0.shape, jnp.int32)        # per-row pool id
     use_mono = bool(cfg.get("use_mono")) and mono is not None
     lo_b = jnp.full((1,), -jnp.inf, jnp.float32)
     hi_b = jnp.full((1,), jnp.inf, jnp.float32)
@@ -568,12 +592,14 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
     for d in range(D):                             # static unroll
         L = widths[d]
         Bd = max(B, F >> d) if adaptive else B
+        with jax.named_scope("h2o.tree.route"):
+            hslot = jnp.where(grown, slot, -1)
         if adaptive:
             with jax.named_scope("h2o.tree.split"):
                 key, sub = jax.random.split(key)
                 roff = _rand_offsets(sub, L, C, rlo, rhi, random_mode)
             hist = _shard_histogram(
-                bins, slot, stats, L, Bd, cfg["block_rows"], cfg["bf16"],
+                bins, hslot, stats, L, Bd, cfg["block_rows"], cfg["bf16"],
                 fine_map=(rlo, rhi, roff, is_cat, F),
                 pallas=cfg.get("pallas"))
         elif sib and d >= 1 and L == 2 * widths[d - 1]:
@@ -581,10 +607,10 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
             # parent order (identity selection), so the dense sibling
             # subtraction applies verbatim; capped levels (top_k
             # reshuffles slots) fall back to the full histogram
-            hist = _hist_level_with_sibling(bins, slot, stats, L, B, cfg,
+            hist = _hist_level_with_sibling(bins, hslot, stats, L, B, cfg,
                                             prev_hist, prev_do)
         else:
-            hist = _shard_histogram(bins, slot, stats, L, B,
+            hist = _shard_histogram(bins, hslot, stats, L, B,
                                     cfg["block_rows"], cfg["bf16"],
                                     pallas=cfg.get("pallas"))
         # dequantize once per level at the table (see build_tree_traced)
@@ -686,28 +712,36 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
                 inv = jnp.full((2 * L,), -1, jnp.int32).at[sel].set(
                     jnp.where(sel_valid,
                               jnp.arange(L_next, dtype=jnp.int32), -1))
-            with jax.named_scope("h2o.tree.route"):
-                # route rows: split-parent rows follow the split to a child;
-                # rows whose child fell off the frontier finalize (-1)
-                active = slot >= 0
-                sl = jnp.maximum(slot, 0)
-                if cfg.get("mm_route") and 2 * L <= _MM_ROUTE_MAX_TABLE and \
-                        (Bd if adaptive else B) < _MM_ROUTE_MAX_TABLE:
-                    go_left, do_sl = _mm_route_level(
-                        bins, sl, s, do_split, L, Bd if adaptive else B,
-                        cat_choice, adaptive, thr_leaf, F)
-                    cand = 2 * sl + jnp.where(go_left, 0, 1)
+        with jax.named_scope("h2o.tree.route"):
+            # route EVERY row on the frontier, grown-on or not, the last
+            # level included: a split parent's rows follow the split to a
+            # child's pool slot; a row whose child fell off the frontier
+            # ends there (slot -1)
+            active = slot >= 0
+            sl = jnp.maximum(slot, 0)
+            mm = bool(cfg.get("mm_route")) and \
+                2 * L <= _MM_ROUTE_MAX_TABLE and \
+                (Bd if adaptive else B) < _MM_ROUTE_MAX_TABLE
+            if mm:
+                go_left, do_sl = _mm_route_level(
+                    bins, sl, s, do_split, L, Bd if adaptive else B,
+                    cat_choice, adaptive, thr_leaf, F)
+            else:
+                go_left, do_sl = _gather_route_level(
+                    bins, sl, s, do_split, Bd, cat_choice, adaptive,
+                    thr_leaf, F)
+            cand = 2 * sl + jnp.where(go_left, 0, 1)
+            moved = active & do_sl
+            pos = jnp.where(moved, base + cand, pos)
+            if d + 1 < D:
+                if mm:
                     candhot = cand[:, None] == jnp.arange(2 * L)[None, :]
                     inv_c = _mm_pick(candhot, inv.astype(jnp.float32)[:, None]
                                      )[:, 0].astype(jnp.int32)
                 else:
-                    go_left, do_sl = _gather_route_level(
-                        bins, sl, s, do_split, Bd, cat_choice, adaptive,
-                        thr_leaf, F)
-                    cand = 2 * sl + jnp.where(go_left, 0, 1)
                     inv_c = inv[cand]
-                new_slot = jnp.where(active & do_sl, inv_c, -1)
-                slot = jnp.where(active, new_slot, slot)
+                slot = jnp.where(moved, inv_c, -1)
+        if d + 1 < D:
             with jax.named_scope("h2o.tree.split"):
                 if use_mono:
                     lo_b = jnp.take(lo_c, sel)
@@ -723,62 +757,7 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
         base += 2 * L
 
     return (split_col[:N], bitset[:N], value[:N], child[:N], varimp,
-            node_gain[:N], node_w[:N], thr_pool[:N], na_pool[:N])
-
-
-@jax.named_scope("h2o.tree.predict")
-def _tree_predict(bins, split_col, bitset, value, D: int, child=None,
-                  thr=None, na_l=None, fine_na: int = -1,
-                  mm: bool = False):
-    """Descend one tree for all rows (traceable).  ``child`` None = dense
-    heap (children at 2n+1/2n+2), else explicit left-child pointers;
-    ``thr``/``na_l`` carry adaptive numeric thresholds.  ``mm`` routes the
-    per-level lookups through one-hot matmuls (gather-free; identical
-    results) when the node table is small enough."""
-    if not (mm and split_col.shape[0] <= _MM_ROUTE_MAX_TABLE):
-        return value[descend(bins, split_col, bitset, D, child=child,
-                             thr=thr, na_l=na_l, fine_na=fine_na)]
-    R, C = bins.shape
-    B = bitset.shape[-1] - 1
-    H = split_col.shape[0]
-    node = jnp.zeros((R,), jnp.int32)
-    for _ in range(D):
-        nodehot = node[:, None] == jnp.arange(H)[None, :]      # (R, H)
-        tbl = [split_col.astype(jnp.float32),
-               (thr if thr is not None else
-                jnp.full((H,), -1, jnp.int32)).astype(jnp.float32),
-               (na_l if na_l is not None else
-                jnp.zeros((H,), bool)).astype(jnp.float32),
-               (child if child is not None else
-                jnp.full((H,), -1, jnp.int32)).astype(jnp.float32)]
-        V = _mm_pick(nodehot, jnp.stack(tbl, axis=1))          # (R, 4)
-        c = V[:, 0].astype(jnp.int32)
-        term = c < 0
-        colhot = jnp.maximum(c, 0)[:, None] == \
-            jnp.arange(C)[None, :]
-        b = jnp.sum(bins.astype(jnp.float32) * colhot,
-                    axis=1).astype(jnp.int32)
-        T = _mm_pick(nodehot, bitset)                          # (R, B+1)
-        nb = jnp.minimum(b, B)
-        gl = jnp.sum(
-            T * (nb[:, None] == jnp.arange(B + 1)[None, :]),
-            axis=1) > 0.5
-        if thr is None:
-            go_left = gl
-        else:
-            tn = V[:, 1].astype(jnp.int32)
-            go_left = jnp.where(
-                tn >= 0,
-                jnp.where(b == fine_na, V[:, 2] > 0.5, b < tn), gl)
-        if child is None:
-            nxt = 2 * node + jnp.where(go_left, 1, 2)
-        else:
-            left = V[:, 3].astype(jnp.int32)
-            term = term | (left < 0)
-            nxt = left + jnp.where(go_left, 0, 1)
-        node = jnp.where(term, node, nxt)
-    nodehot = node[:, None] == jnp.arange(H)[None, :]
-    return _mm_pick(nodehot, value[:, None])[:, 0]
+            node_gain[:N], node_w[:N], thr_pool[:N], na_pool[:N], pos)
 
 
 def _hist_bucket(args, kwargs):
@@ -1045,11 +1024,11 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
                 else:
                     inv_sc = None
             if kleaves > 0:
-                sc, bs, vl, ch, vi, gn, nw, th, na = build_tree_frontier(
+                sc, bs, vl, ch, vi, gn, nw, th, na, pos = build_tree_frontier(
                     bins, stats, leaf0, kk, is_cat, cfg, tree_cols,
                     mono=mono, inv_scale=inv_sc)
             else:
-                sc, bs, vl, vi, gn, nw, th, na = build_tree_traced(
+                sc, bs, vl, vi, gn, nw, th, na, pos = build_tree_traced(
                     bins, stats, leaf0, kk, is_cat, cfg, tree_cols,
                     mono=mono, inv_scale=inv_sc)
                 ch = None
@@ -1064,10 +1043,10 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
             nws.append(nw)
             ths.append(th)
             nas.append(na)
-            preds.append(_tree_predict(
-                bins, sc, bs, vl, max_depth, child=ch, thr=th, na_l=na,
-                fine_na=int(cfg.get("fine_nbins") or nbins),
-                mm=bool(cfg.get("mm_route"))))
+            with jax.named_scope("h2o.tree.predict"):
+                # growth left every row on its final node: the tree's
+                # update is a lookup, not a descent of the tree just grown
+                preds.append(vl[pos])
         with jax.named_scope("h2o.tree.predict"):
             F = F + jnp.stack(preds, axis=1)
         with jax.named_scope("h2o.tree.split"):

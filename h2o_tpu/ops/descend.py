@@ -1,11 +1,13 @@
 """The walk down a built tree's node arrays — the ONE place that says
 which way a row goes at a stored split.
 
-Every reader of a finished tree (the trainer's F update, forest scoring,
-staged predictions, RuleFit's rule matrix) calls :func:`descend`; the
-numpy walk in ``mojo/scorers.py`` is the reference the tests hold it to.
-Opens no ``jax.named_scope``: the callers name the device time
-(``h2o.tree.predict``, ``h2o.score.descent``).
+Every reader of a finished tree (forest scoring, staged predictions,
+RuleFit's rule matrix) calls :func:`descend`; the numpy walk in
+``mojo/scorers.py`` is the reference the tests hold it to.  Training
+does not: growth leaves every row on its final node
+(``jit_engine.build_tree_traced``'s ``pos``, held to this walk by
+``tests/test_grown_positions.py``).  Opens no ``jax.named_scope``: the
+callers name the device time (``h2o.score.descent``).
 """
 
 from __future__ import annotations

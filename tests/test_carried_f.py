@@ -31,7 +31,7 @@ N, C = 480, 3
 XS = [f"x{j}" for j in range(C)]
 
 
-def _frame(rng, response: str):
+def _frame(rng, response: str, N: int = N):
     X = rng.normal(size=(N, C)).astype(np.float32)
     X[rng.uniform(size=(N, C)) < 0.03] = np.nan        # NA bucket in play
     z = np.nan_to_num(X[:, 0]) - 0.7 * np.nan_to_num(X[:, 1])
@@ -156,6 +156,65 @@ def test_train_bins_once_and_rescores_no_forest(cl, rng, monkeypatch):
     calls["forest_score"].clear()
     _gbm(ntrees=3).train(y="y", x=XS, training_frame=fr)
     assert len(calls["bin_matrix"]) == 1 and not calls["forest_score"]
+
+
+class _Descended(Exception):
+    """The training program traced a walk down a built tree."""
+
+
+@pytest.mark.parametrize("algo,valid_rows", [("gbm", 400), ("drf", 336)])
+def test_training_traces_no_descent(cl, rng, monkeypatch, algo, valid_rows):
+    """Growth hands every row's final node to the F update, so the
+    training program holds no ``ops/descend.descend``: patched to raise
+    while ``train_forest`` is on the stack, a ``train()`` with no
+    validation frame still traces and runs.  With a validation frame the
+    descending scorer calls it, outside the training program."""
+    from h2o_tpu.models.tree import jit_engine, shared_tree
+    from h2o_tpu.ops import descend as descend_mod
+    inner, training, calls, grown = descend_mod.descend, [], [], []
+
+    def watched(*a, **kw):
+        calls.append(bool(training))
+        if training:
+            raise _Descended()
+        return inner(*a, **kw)
+    monkeypatch.setattr(descend_mod, "descend", watched)
+    monkeypatch.setattr(shared_tree, "descend", watched)
+    monkeypatch.setattr(jit_engine, "descend", watched, raising=False)
+
+    train_forest = jit_engine.train_forest
+
+    def guarded(*a, **kw):
+        training.append(1)
+        try:
+            return train_forest(*a, **kw)
+        finally:
+            training.pop()
+    monkeypatch.setattr(jit_engine, "train_forest", guarded)
+    grow = jit_engine.build_tree_traced
+
+    def growing(*a, **kw):
+        grown.append(bool(training))
+        return grow(*a, **kw)
+    monkeypatch.setattr(jit_engine, "build_tree_traced", growing)
+
+    # a static no other test passes: the program is traced here, under
+    # the patch, and not taken from an earlier test's trace
+    kw = dict(ntrees=3, score_tree_interval=1,
+              min_split_improvement=1.25e-7)
+    build = (lambda: _gbm(**kw)) if algo == "gbm" else \
+        (lambda: _drf(sample_rate=0.632, **kw))
+    fr = _frame(rng, "binomial")
+    model = build().train(y="y", x=XS, training_frame=fr)
+    assert model.output["ntrees_actual"] == 3
+    assert grown and all(grown)
+    assert True not in calls
+
+    # a row count no other test scores, so the scorer is traced here too
+    del calls[:]
+    build().train(y="y", x=XS, training_frame=fr,
+                  validation_frame=_frame(rng, "binomial", valid_rows))
+    assert calls and True not in calls
 
 
 def test_drf_final_span_says_it_rescored(cl, rng):
