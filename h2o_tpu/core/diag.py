@@ -297,15 +297,18 @@ class TimeLine:
         sid = next(cls._ids)
         stack.append((sid, job))
         ns, t0 = time.time_ns(), time.perf_counter_ns()
+        # the ring event IS the dict the block is handed: a field whose
+        # value is still on the device when the span closes (a count
+        # fetched with a later sync) may be written into it afterwards
+        ev = {"ns": ns, "kind": kind, "what": what,
+              "thread": threading.get_ident(), "dur_ns": None,
+              "id": sid, "parent": parent, "job": job, **info}
         try:
             with TraceAnnotation(f"h2o:{kind}.{what}", **info):
-                yield info
+                yield ev
         finally:
-            dur = time.perf_counter_ns() - t0
+            ev["dur_ns"] = time.perf_counter_ns() - t0
             stack.pop()
-            ev = {"ns": ns, "kind": kind, "what": what,
-                  "thread": threading.get_ident(), "dur_ns": dur,
-                  "id": sid, "parent": parent, "job": job, **info}
             with cls._lock:
                 cls._events.append(ev)
 

@@ -182,6 +182,45 @@ class RollupStats:
         return self._vec.histogram()
 
 
+# -- a frame's enum codes in another frame's domain --------------------------
+
+_LOOKUP_QUANTUM = 64    # a lookup table's length is padded to it: one program a size
+
+
+def domain_table(frame_domain, train_domain) -> Optional[np.ndarray]:
+    """H2O-3's ``adaptTestForTrain`` for one enum column, as a lookup
+    table: entry i is the TRAINING code of the frame's level i, matched
+    by the level's string; NaN for a level training never saw.  The tail
+    (at least one entry) is NaN padding.  None when the two domains are
+    equal: there is nothing to do."""
+    fd, td = list(frame_domain or ()), list(train_domain or ())
+    if fd == td:
+        return None
+    code = {s: i for i, s in enumerate(td)}
+    table = np.full(-(-(len(fd) + 1) // _LOOKUP_QUANTUM) * _LOOKUP_QUANTUM,
+                    np.nan, np.float32)
+    table[: len(fd)] = [code.get(s, np.nan) for s in fd]
+    return table
+
+
+def table_unseen_levels(table: np.ndarray, frame_domain) -> int:
+    """Levels of the frame's domain that the table maps to nothing."""
+    return int(np.isnan(table[: len(frame_domain or ())]).sum())
+
+
+@jax.jit
+@jax.named_scope("h2o.score.adapt")
+def _codes_in_domain(codes, table, nrows):
+    """Row-sharded int codes (NA below 0) -> float32 codes of the
+    table's domain (NA and unseen levels NaN), and how many of the first
+    ``nrows`` rows hold a level the table lacks."""
+    last = table.shape[0] - 1                   # always NaN
+    held = (codes >= 0) & (codes < last)
+    out = table[jnp.where(held, codes, last)]
+    miss = held & jnp.isnan(out) & (jnp.arange(codes.shape[0]) < nrows)
+    return out, jnp.sum(miss, dtype=jnp.int32)
+
+
 class Vec:
     """One column.  Numeric/categorical/time payloads live on-device."""
 
@@ -1016,19 +1055,53 @@ class Frame:
         ck = (names, jnp.dtype(dtype).name)
         m = self._matrix_cache.get(ck)
         if m is None:
-            R = self.padded_rows
-            cols = [self.vec(n).as_float() for n in names]
-            # appendable columns carry pow2 capacity; a column added
-            # AFTER appends (or a lazy sparse one) may be shorter — pad
-            # it to the frame's capacity so the stack stays rectangular
-            cols = [c if c.shape[0] == R else
-                    jnp.pad(c, (0, R - c.shape[0]),
-                            constant_values=jnp.nan) for c in cols]
-            m = jnp.stack(cols, axis=1).astype(dtype)
-            from h2o_tpu.core import landing
-            m = landing.reshard_rows(m, cloud().matrix_sharding())
+            m = self._stack_columns(
+                [self.vec(n).as_float() for n in names], dtype)
             self._matrix_cache[ck] = m
         return m
+
+    def _stack_columns(self, cols, dtype=jnp.float32) -> jax.Array:
+        """Float columns -> the (padded_rows, ncols) row-sharded matrix."""
+        R = self.padded_rows
+        # appendable columns carry pow2 capacity; a column added
+        # AFTER appends (or a lazy sparse one) may be shorter — pad
+        # it to the frame's capacity so the stack stays rectangular
+        cols = [c if c.shape[0] == R else
+                jnp.pad(c, (0, R - c.shape[0]),
+                        constant_values=jnp.nan) for c in cols]
+        m = jnp.stack(cols, axis=1).astype(dtype)
+        from h2o_tpu.core import landing
+        return landing.reshard_rows(m, cloud().matrix_sharding())
+
+    def as_matrix_in_domains(self, names: Sequence[str], tables: Dict):
+        """``as_matrix(names)`` with the enum columns in ``tables``
+        (column -> ``domain_table``) carried into another frame's domain
+        on the device, and a column this frame lacks all NaN.  Returns
+        ``(matrix, unseen_rows)``: the second a device int32 scalar, the
+        rows (a column at a time) whose level the other domain lacks.
+        Cached like ``as_matrix``."""
+        if self.is_ragged:
+            self.repack()
+        ck = ("in_domains", tuple(names),
+              tuple((c, t.tobytes()) for c, t in sorted(tables.items())))
+        hit = self._matrix_cache.get(ck)
+        if hit is None:
+            cols, unseen = [], jnp.int32(0)
+            for n in names:
+                if n not in self:
+                    cols.append(jnp.full((self.padded_rows,), jnp.nan,
+                                         jnp.float32))
+                elif n in tables:
+                    col, miss = _codes_in_domain(
+                        self.vec(n).data, jnp.asarray(tables[n]),
+                        jnp.int32(self.nrows))
+                    cols.append(col)
+                    unseen = unseen + miss
+                else:
+                    cols.append(self.vec(n).as_float())
+            hit = self._matrix_cache[ck] = (self._stack_columns(cols),
+                                            unseen)
+        return hit
 
     def row_mask(self) -> jax.Array:
         """Validity predicate over padded rows (ragged-aware: all vecs of

@@ -16,8 +16,8 @@ from typing import Dict, Optional
 import jax.numpy as jnp
 import numpy as np
 
-from h2o_tpu.core.frame import Frame
-from h2o_tpu.models.model import Model, ModelBuilder
+from h2o_tpu.core.frame import Frame, T_TIME
+from h2o_tpu.models.model import Model, ModelBuilder, adapt_frame
 
 
 class GenericModel(Model):
@@ -56,32 +56,19 @@ class GenericModel(Model):
     def predict_raw(self, frame: Frame):
         mojo = self._mojo()
         cols = mojo.columns
-        X = np.full((frame.nrows, len(cols)), np.nan, np.float64)
+        # adaptTestForTrain (models/model.py adapt_frame, on the device):
+        # the frame's enum codes in the artifact's training domains, an
+        # unseen level or an absent column NaN, score_matrix's NA
+        ad = adapt_frame(frame, cols, {
+            c: mojo.domain_of(c) for c in cols
+            if mojo.domain_of(c) is not None})
+        X = np.asarray(ad.matrix, np.float64)[: frame.nrows]
+        # the device matrix is float32; a time column keeps its exact
+        # float64 epoch-ms on the host (float32 is two minutes coarse
+        # there), and the artifact's thresholds are read against that
         for j, c in enumerate(cols):
-            if c in frame:
-                v = frame.vec(c)
-                col = np.asarray(v.to_numpy(), np.float64)
-                if v.is_categorical:
-                    # adaptTestForTrain: remap the frame's domain codes to
-                    # the artifact's training domain; unseen levels -> NA
-                    # (NaN is score_matrix's NA convention; frame NA = -1)
-                    col = np.where(col < 0, np.nan, col)
-                    mdom = mojo.domain_of(c)
-                    fdom = v.domain or []
-                    if mdom is not None and list(mdom) != list(fdom):
-                        lut = {s: i for i, s in enumerate(mdom)}
-                        remap = np.array(
-                            [lut.get(s, np.nan) for s in fdom], np.float64)
-                        if len(fdom):
-                            # NaN-safe: index with NA rows pinned to 0,
-                            # then restore NaN (NaN.astype(int64) is UB)
-                            idx = np.clip(np.nan_to_num(col), 0,
-                                          len(fdom) - 1).astype(np.int64)
-                            col = np.where(np.isnan(col), np.nan,
-                                           remap[idx])
-                        else:
-                            col = np.full_like(col, np.nan)
-                X[:, j] = col
+            if c in frame and frame.vec(c).type == T_TIME:
+                X[:, j] = np.asarray(frame.vec(c).to_numpy(), np.float64)
         raw = mojo.score_matrix(X)
         # pad back to the frame's padded shape for the metric kernels
         pad = frame.padded_rows - frame.nrows

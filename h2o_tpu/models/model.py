@@ -15,14 +15,16 @@ from __future__ import annotations
 
 import pickle
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from h2o_tpu.core.cloud import cloud
-from h2o_tpu.core.frame import Frame, T_CAT, Vec
+from h2o_tpu.core.frame import (Frame, T_CAT, Vec, _codes_in_domain,
+                                domain_table, table_unseen_levels)
 from h2o_tpu.core.job import Job
 from h2o_tpu.core.log import get_logger
 from h2o_tpu.core.store import Key
@@ -153,6 +155,51 @@ class DataInfo:
         return self._names_expanded
 
 
+class Adapted(NamedTuple):
+    """A frame as a model's scorer reads it (``adapt_frame``)."""
+    matrix: jax.Array          # (padded_rows, len(x)) float32
+    remapped: Tuple[str, ...]  # enum columns whose codes went through a table
+    absent: Tuple[str, ...]    # columns the frame lacks: all NA
+    unseen_levels: int         # levels of the frame's domains training lacks
+    # device int32 scalar: rows, a column at a time, that hold such a
+    # level; None when no column was remapped (no program ran)
+    unseen_rows: Optional[jax.Array]
+
+
+def adapt_frame(frame: Frame, x: Sequence[str],
+                domains: Optional[Dict[str, List[str]]],
+                warn=None) -> Adapted:
+    """H2O-3's ``adaptTestForTrain`` contract, on the device: ``frame``'s
+    columns ``x`` as the matrix a model trained on ``domains`` scores.
+    An enum column is matched to training's by level STRING (the
+    frame's own codes mean nothing to the model: a file parsed on its
+    own holds the sorted levels present in THAT file); a level training
+    never saw is NA, which binning sends to the NA bucket and every node
+    to its NA side; a column the frame lacks is all NA, with one
+    warning (``warn``: a job's, else the log).  Equal domains cost
+    nothing: the frame's cached ``as_matrix``."""
+    tables = {}
+    for c in x:
+        if c in frame and c in (domains or {}) and \
+                frame.vec(c).is_categorical:
+            t = domain_table(frame.vec(c).domain, domains[c])
+            if t is not None:
+                tables[c] = t
+    absent = tuple(c for c in x if c not in frame)
+    if absent:
+        (warn or log.warning)(
+            f"frame {frame.key} lacks column(s) {', '.join(absent)} the "
+            "model was trained on: scored as missing values")
+    if not tables and not absent:
+        return Adapted(frame.as_matrix(x), (), (), 0, None)
+    matrix, unseen_rows = frame.as_matrix_in_domains(x, tables)
+    return Adapted(
+        matrix, tuple(tables), absent,
+        sum(table_unseen_levels(t, frame.vec(c).domain)
+            for c, t in tables.items()),
+        unseen_rows if tables else None)
+
+
 def _raw_to_frame(raw, nrows: int, dom: Optional[List[str]]) -> Frame:
     """raw predictions -> prediction Frame ([predict, p0..pK-1] layout)."""
     raw = jnp.asarray(raw)
@@ -184,6 +231,12 @@ class Model:
         """Device predictions over padded rows: (rows,) regression values or
         (rows, 1+K) [label, p0..pK-1] for classification."""
         raise NotImplementedError
+
+    def scoring_matrix(self, frame: Frame) -> jax.Array:
+        """``frame`` as this model's scorer reads it: columns
+        ``output['x']`` in the training domains (``adapt_frame``)."""
+        return adapt_frame(frame, self.output["x"],
+                           self.output.get("domains")).matrix
 
     def predict(self, frame: Frame) -> Frame:
         """Public scoring: returns a Frame (the /3/Predictions surface)."""
@@ -275,8 +328,13 @@ class Model:
         yv = frame.vec(y_name)
         dom = self.output.get("response_domain")
         valid = frame.row_mask()
-        y = yv.as_float() if not yv.is_categorical else jnp.where(
-            yv.data < 0, jnp.nan, yv.data.astype(jnp.float32))
+        # the response is matched by level string like any enum column
+        y = yv.as_float()
+        table = domain_table(yv.domain, dom) \
+            if dom is not None and yv.is_categorical else None
+        if table is not None:
+            y = _codes_in_domain(yv.data, jnp.asarray(table),
+                                 jnp.int32(yv.nrows))[0]
         if w is None:
             wc = self.params.get("weights_column")
             w = frame.vec(wc).data if wc and wc in frame else None

@@ -81,8 +81,7 @@ class RuleFitModel(Model):
     def _rule_frame(self, frame: Frame) -> Frame:
         """Rule + linear feature frame for the inner GLM."""
         out = self.output
-        m = frame.as_matrix(out["x"])
-        bins = st.bin_matrix_out(m, out)
+        bins = st.bin_matrix_out(self.scoring_matrix(frame), out)
         cols: List[Vec] = []
         names: List[str] = []
         for fi, f in enumerate(out["forests"]):
@@ -197,6 +196,8 @@ class RuleFit(ModelBuilder):
                          is_cat=binned.is_cat, nbins=binned.nbins,
                          col_nbins=binned.col_nbins,
                          forests=forests, linear_names=linear_names,
+                         domains={c: list(train.vec(c).domain)
+                                  for c in di.cat_names},
                          response_domain=di.response_domain
                          if nclass >= 2 else None)
         proto = self.model_cls(self.model_id, dict(p), out_proto)

@@ -36,8 +36,7 @@ from h2o_tpu.models.tree import shared_tree as st
 
 def _binned(model, frame: Frame) -> np.ndarray:
     out = model.output
-    m = frame.as_matrix(out["x"])
-    return np.asarray(st.bin_matrix_out(m, out))
+    return np.asarray(st.bin_matrix_out(model.scoring_matrix(frame), out))
 
 
 def _forest_arrays(model, need_cover: bool = True):

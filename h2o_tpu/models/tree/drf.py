@@ -56,7 +56,7 @@ class DRFModel(Model):
 
     def predict_raw(self, frame: Frame):
         # delegates to the array fast path — one scoring implementation
-        return self.predict_raw_array(frame.as_matrix(self.output["x"]))
+        return self.predict_raw_array(self.scoring_matrix(frame))
 
 
 class DRF(ModelBuilder):
@@ -211,7 +211,6 @@ class DRF(ModelBuilder):
             p.get("score_each_iteration") or \
             float(p.get("max_runtime_secs") or 0) > 0
         if want_scoring:
-            score_frame = valid if valid is not None else train
             H = pool_size(depth, kleaves)
             proto = make_model(
                 np.zeros((0, K, H), np.int32),
@@ -221,23 +220,24 @@ class DRF(ModelBuilder):
                 0, None)
             dom_sc = di.response_domain if nclass >= 2 else None
 
-            def to_metrics(Fv, ntot):
-                return proto.metrics_from_raw(
-                    raw_from_votes(Fv, ntot, dom_sc), score_frame)
+            def metrics_on(frame):
+                return lambda Fv, ntot: proto.metrics_from_raw(
+                    raw_from_votes(Fv, ntot, dom_sc), frame)
 
             if valid is None:
                 # the trainer's carried F holds the raw votes on every
                 # row of this frame: the driver scores each block on it
-                scorer = IncrementalScorer(to_metrics)
+                scorer = IncrementalScorer(metrics_on(train))
             else:
-                bins_sc = st.bin_matrix(
-                    valid.as_matrix(di.x), binned.split_points_dev,
-                    binned.is_cat, binned.fine, binned.col_nbins)
+                bins_sc, prepared = st.bin_validation_frame(
+                    job, valid, di.x, proto.output["domains"], binned)
                 F_sc = jnp.zeros((bins_sc.shape[0], K), jnp.float32)
                 if prior:
                     F_sc = F_sc + st.forest_score_out(bins_sc, co, depth)
-                scorer = IncrementalScorer(to_metrics, bins_sc, F_sc, depth,
-                                           fine_na=binned.fine)
+                scorer = IncrementalScorer(
+                    metrics_on(train), bins_sc, F_sc, depth,
+                    fine_na=binned.fine, valid_metrics=metrics_on(valid),
+                    prepared=prepared, ntrees=prior)
         job.update(0.05, f"training {int(p['ntrees']) - prior} trees")
         model = run_tree_driver(job, p, train_kwargs, F0, self.rng_key(),
                                 make_model, scorer, kind,
@@ -248,5 +248,5 @@ class DRF(ModelBuilder):
             model.output["training_metrics"] = model.model_metrics(train)
             if valid is not None:
                 model.output["validation_metrics"] = \
-                    model.model_metrics(valid)
+                    st.final_validation_metrics(model, valid, scorer)
         return model

@@ -12,7 +12,9 @@ offset + checkpoint forest + every kept tree, on every row) IS the training
 frame's link-scale prediction, so the metric kernels read it where it lies.
 Only a validation frame, whose rows the trainer never sees, keeps a running
 F of its own to which the new block's trees are added (one forest_score
-over the block).  Either way total scoring work is O(T) — the reference's
+over the block); a scoring point then carries both frames' metrics, and
+the validation metrics that end ``train()`` are read from that running F.
+Either way total scoring work is O(T) — the reference's
 per-scoring-round full-model rescore (BigScore over all trees) is avoided
 entirely.
 
@@ -82,25 +84,34 @@ def _set_node_array(model, name: str, new: np.ndarray) -> None:
 
 
 class IncrementalScorer:
-    """Link-scale predictions of the growing forest on one frame.
+    """Link-scale predictions of the growing forest, scored per block.
 
     to_metrics(F, ntrees_total) -> ModelMetrics converts F (model-specific
-    link/vote semantics) and runs the metric kernels.
+    link/vote semantics) and runs the metric kernels on the TRAINING
+    frame.  Its F is the one the trainer carries: ``score`` reads the
+    block's ``f_final``, descends nothing and keeps no F of its own.
 
-    ``bins`` None: the frame is the TRAINING frame.  Its F is the one the
-    trainer carries — ``score`` reads the block's ``f_final``, descends
-    nothing and keeps no F of its own.  With ``bins`` (a validation
-    frame's) the scorer keeps the running ``F``, from ``F_init``, and adds
-    each block's trees to it by one descent.
+    With ``bins`` (a validation frame's, ``bin_validation_frame``) the
+    scorer also keeps that frame's running ``F``, from ``F_init`` (which
+    holds ``ntrees`` trees already: a checkpoint's), adds each block's
+    trees to it by one descent, and ``valid_metrics(F, ntrees_total)``
+    scores it: a scoring point then carries both frames' metrics, the
+    validation frame's last (the one a stopping rule reads, as H2O-3's).
     """
 
     def __init__(self, to_metrics: Callable, bins=None, F_init=None,
-                 depth: int = 0, fine_na: int = -1):
+                 depth: int = 0, fine_na: int = -1,
+                 valid_metrics: Optional[Callable] = None,
+                 prepared=None, ntrees: int = 0):
         self.to_metrics = to_metrics
+        self.valid_metrics = valid_metrics
         self.bins = bins
         self.F = F_init
+        self.ntrees = ntrees        # trees summed in ``F``
         self.depth = depth
         self.fine_na = fine_na
+        self._prepared = prepared
+        self.valid_rows = int(prepared[0]["rows"]) if prepared else 0
 
     @property
     def is_validation(self) -> bool:
@@ -108,7 +119,8 @@ class IncrementalScorer:
 
     @property
     def source(self) -> str:
-        """Where ``score`` finds its F: field of span train.block.score."""
+        """Where ``score`` finds the stopping frame's F: field of span
+        train.block.score."""
         return "descent" if self.is_validation else "carried_F"
 
     def add(self, sc, bs, vl, ch=None, th=None, na=None) -> None:
@@ -126,15 +138,26 @@ class IncrementalScorer:
         # safe here (unlike the forest F, which speculation may re-read)
         acc = _accum_donate if donation_enabled() else _accum
         self.F = acc(self.F, delta)
+        self.ntrees += int(sc.shape[0])
 
     def score(self, tf, ntrees_total: int):
-        """Metrics of the forest up to and including block ``tf``."""
-        F = tf.f_final
+        """``[(prefix, metrics)]`` of the forest up to and including
+        block ``tf``: the training frame's, then the validation frame's
+        where there is one."""
+        out = [("training_", self.to_metrics(tf.f_final, ntrees_total))]
         if self.is_validation:
             self.add(tf.split_col, tf.bitset, tf.value, tf.child,
                      tf.thr_bin, tf.na_left)
-            F = self.F
-        return self.to_metrics(F, ntrees_total)
+            out.append(("validation_",
+                        self.valid_metrics(self.F, ntrees_total)))
+            if self._prepared is not None:
+                # the metrics above have synced: this fetch waits for
+                # nothing
+                ev, unseen = self._prepared
+                if unseen is not None:
+                    ev["unseen_rows"] = int(unseen)
+                self._prepared = None
+        return out
 
 
 @jax.jit
@@ -200,6 +223,20 @@ def _split_counts(sc, bs, th, na, is_cat) -> Dict[str, int]:
     return {"num_splits": int(split.sum()),
             "cat_splits": int(is_cat[sc[split]].sum()),
             "na_left_splits": int((na_left & split).sum())}
+
+
+def _warn_empty_roots(job, node_w: np.ndarray) -> None:
+    """A tree whose ROOT covers no row was grown from an empty histogram
+    table: with rows to train on that is a fault of the table's build,
+    never of the data (a contraction that the chip's compiler emitted
+    wrong once zeroed every table of a job, which then returned a forest
+    of no split and no error: PERF.md, PR 34).  Read from the cover the
+    pull already holds; one warning on the job."""
+    if node_w.size and not np.all(node_w[..., 0] > 0):
+        job.warn("a tree's root covers no row: its histogram table came "
+                 "back empty, so the forest holds trees of no split "
+                 "(a fault of the program or its compiler, not of the "
+                 "data)")
 
 
 def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
@@ -293,6 +330,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         model.output["varimp"] = vi if prior_vi is None else prior_vi + vi
         _set_node_array(model, "node_gain", np.asarray(tf.node_gain))
         _set_node_array(model, "node_w", np.asarray(tf.node_w))
+        _warn_empty_roots(job, np.asarray(tf.node_w))
         _set_node_array(model, "thr_bin", np.asarray(tf.thr_bin))
         _set_node_array(model, "na_left", np.asarray(tf.na_left))
         return model
@@ -310,8 +348,6 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     vi_total = None
     F = F0
     done = 0
-    prefix = "validation_" if scorer is not None and \
-        scorer.is_validation else "training_"
     if recovery is not None:
         st = recovery.load_iteration()
         # resume only a checkpoint of THIS build shape — a stale state
@@ -334,6 +370,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                     st.get("scorer_F") is not None:
                 scorer.F = jnp.asarray(_fit_rows(
                     st["scorer_F"], int(scorer.F.shape[0])))
+                scorer.ntrees = prior_trees + done
             job.update(0.05 + 0.85 * done / ntrees,
                        f"resumed mid-forest at {prior_trees + done} trees")
     may_stop = (rounds > 0 and scorer is not None) or max_rt > 0
@@ -341,13 +378,12 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     # runtime-budget break the discarded block's INPUT (the last kept
     # block's f_final) is still read by make_model, recovery checkpoints
     # np.asarray the post-block F after the next block has already been
-    # dispatched, and a training-frame scorer reads block t's f_final as
-    # its F when block t is absorbed, after t+1's launch.  With none of
-    # those readers the default donation policy applies — the carry is
-    # then written in place across blocks.
-    reads_carry = scorer is not None and not scorer.is_validation
+    # dispatched, and a scorer reads block t's f_final as the training
+    # frame's F when block t is absorbed, after t+1's launch.  With none
+    # of those readers the default donation policy applies — the carry
+    # is then written in place across blocks.
     donate_launch = False if (
-        may_stop or recovery is not None or reads_carry) else None
+        may_stop or recovery is not None or scorer is not None) else None
     launched = done
     no_donate = False       # latched by the OOM ladder: retries re-read F
 
@@ -427,6 +463,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                 vi = np.asarray(tf.varimp)
                 pulled.update(_split_counts(scs[-1], bss[-1], ths[-1],
                                             nas[-1], is_cat_host))
+            _warn_empty_roots(job, nws[-1])
             TimeLine.record("dispatch", "tree_block_materialize",
                             t0=prior_trees + cur["off"], n=n)
             DispatchStats.note_transfer("tree_block", _block_nbytes(tf))
@@ -434,16 +471,20 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
             done += n
             stop = False
             if scorer is not None:
-                with TimeLine.span("train", "block.score",
-                                   source=scorer.source):
-                    mm = scorer.score(tf, prior_trees + done)
+                with TimeLine.span(
+                        "train", "block.score", source=scorer.source,
+                        valid_rows=scorer.valid_rows):
                     row = {"number_of_trees": prior_trees + done,
                            "timestamp": time.time()}
-                    for k in ("mse", "logloss", "AUC",
-                              "mean_residual_deviance", "err"):
-                        if mm.get(k) is not None:
-                            row[prefix + k.lower()] = mm.get(k)
-                    sk.add(mm, row)
+                    points = scorer.score(tf, prior_trees + done)
+                    for prefix, mm in points:
+                        for k in ("mse", "logloss", "AUC",
+                                  "mean_residual_deviance", "err"):
+                            if mm.get(k) is not None:
+                                row[prefix + k.lower()] = mm.get(k)
+                    # the stopping rule reads the last frame scored: the
+                    # validation frame where there is one
+                    sk.add(points[-1][1], row)
                 job.update(0.05 + 0.85 * done / ntrees,
                            f"{prior_trees + done} trees, "
                            f"{sk.metric_name}={sk.history[-1]:.5g}")

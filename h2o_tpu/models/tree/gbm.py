@@ -78,7 +78,7 @@ class GBMModel(Model):
             jnp.asarray(X, jnp.float32)))
 
     def predict_raw(self, frame: Frame):
-        F = self._forest_F(frame.as_matrix(self.output["x"]))
+        F = self._forest_F(self.scoring_matrix(frame))
         off_col = self.params.get("offset_column")
         if off_col and off_col in frame:
             F = F + frame.vec(off_col).data[:, None]
@@ -350,7 +350,6 @@ class GBM(ModelBuilder):
             p.get("score_each_iteration") or \
             float(p.get("max_runtime_secs") or 0) > 0
         if want_scoring:
-            score_frame = valid if valid is not None else train
             H = pool_size(depth, kleaves)
             proto = make_model(
                 np.zeros((0, K, H), np.int32),
@@ -360,21 +359,22 @@ class GBM(ModelBuilder):
                 0, None)
             dom_sc = di.response_domain if nclass >= 2 else None
 
-            def to_metrics(Fv, ntot):
-                raw = raw_from_F(Fv, dom_sc, dist_name,
-                                 float(p["tweedie_power"]),
-                                 custom_link=custom.link_name
-                                 if custom else None)
-                return proto.metrics_from_raw(raw, score_frame)
+            def metrics_on(frame):
+                def to_metrics(Fv, ntot):
+                    raw = raw_from_F(Fv, dom_sc, dist_name,
+                                     float(p["tweedie_power"]),
+                                     custom_link=custom.link_name
+                                     if custom else None)
+                    return proto.metrics_from_raw(raw, frame)
+                return to_metrics
 
             if valid is None:
                 # the trainer's carried F is this frame's prediction: the
                 # driver scores each block on it and descends nothing
-                scorer = IncrementalScorer(to_metrics)
+                scorer = IncrementalScorer(metrics_on(train))
             else:
-                bins_sc = st.bin_matrix(
-                    valid.as_matrix(di.x), binned.split_points_dev,
-                    binned.is_cat, binned.fine, binned.col_nbins)
+                bins_sc, prepared = st.bin_validation_frame(
+                    job, valid, di.x, proto.output["domains"], binned)
                 F_sc = jnp.broadcast_to(
                     f0[None, :], (bins_sc.shape[0], K)).astype(jnp.float32)
                 off_col = p.get("offset_column")
@@ -382,8 +382,10 @@ class GBM(ModelBuilder):
                     F_sc = F_sc + valid.vec(off_col).data[:, None]
                 if prior:
                     F_sc = F_sc + st.forest_score_out(bins_sc, co, depth)
-                scorer = IncrementalScorer(to_metrics, bins_sc, F_sc, depth,
-                                           fine_na=binned.fine)
+                scorer = IncrementalScorer(
+                    metrics_on(train), bins_sc, F_sc, depth,
+                    fine_na=binned.fine, valid_metrics=metrics_on(valid),
+                    prepared=prepared, ntrees=prior)
         job.update(0.05, f"training {int(p['ntrees']) - prior} trees")
         model = run_tree_driver(job, p, train_kwargs, F, self.rng_key(),
                                 make_model, scorer, kind,
@@ -399,5 +401,5 @@ class GBM(ModelBuilder):
                 model._raw_from_F(F_train), train)
             if valid is not None:
                 model.output["validation_metrics"] = \
-                    model.model_metrics(valid)
+                    st.final_validation_metrics(model, valid, scorer)
         return model

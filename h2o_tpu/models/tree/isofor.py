@@ -157,7 +157,7 @@ class IsolationForestModel(AnomalyModel):
 
     def _total_path(self, frame: Frame):
         out = self.output
-        X = frame.as_matrix(out["x"])
+        X = self.scoring_matrix(frame)
         return _if_path_lengths(X, jnp.asarray(out["split_col"]),
                                 jnp.asarray(out["thresh"]),
                                 int(out["max_depth"]))
@@ -318,7 +318,7 @@ class ExtendedIsolationForestModel(AnomalyModel):
 
     def predict_raw(self, frame: Frame):
         out = self.output
-        X = frame.as_matrix(out["x"])
+        X = self.scoring_matrix(frame)
         mean_len = _eif_mean_path(
             X, jnp.asarray(out["normals"]), jnp.asarray(out["points"]),
             jnp.asarray(out["value"]), jnp.asarray(out["is_split"]),

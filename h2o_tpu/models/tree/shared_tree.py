@@ -107,6 +107,20 @@ def resolve_histogram_type(p) -> str:
     return "UniformAdaptive" if ht == "AUTO" else ht
 
 
+def table_width(levels: int) -> int:
+    """The bins an enum of ``levels`` levels asks of the shared table:
+    ``levels`` rounded up to a size class, a multiple of an eighth of
+    the power of two below it (337 -> 352, 257 -> 288, 33 -> 36, 8 ->
+    8).  The table's width is a SHAPE of every program a train compiles,
+    and an enum's level count is whatever the file holds: a file that
+    lacks a few of a column's levels (a split of the same data, next
+    month's import) then runs the programs its sibling compiled instead
+    of a new set a level count, for at most an eighth more bins, which
+    no row falls in (``col_nbins`` keeps the column's own count)."""
+    step = max(1, (1 << max(levels, 1).bit_length() - 1) >> 3)
+    return -(-levels // step) * step
+
+
 def prepare_bins(di: DataInfo, nbins: int, nbins_cats: int,
                  histogram_type: str = "QuantilesGlobal",
                  nbins_top_level: int = 1024) -> BinnedData:
@@ -125,8 +139,9 @@ def prepare_bins(di: DataInfo, nbins: int, nbins_cats: int,
     Categorical columns always bin by level code, one bin a level up to
     ``nbins_cats`` (a code at or past that count — a level past the cap,
     or one the training domain does not hold — bins to the NA bucket).
-    All columns share ONE table of width B = max(nbins, widest
-    categorical): a numeric column fills its first ``nbins`` bins of it
+    All columns share ONE table of width B = max(nbins, ``table_width``
+    of the widest categorical): a numeric column fills its first
+    ``nbins`` bins of it
     whatever B is (``col_nbins`` says which column has how many), and
     F >= B so codes and the NA sentinel (F) coexist in one packed matrix
     (uint8/int16/int32 by F under the ``tree.bins_dtype`` lever —
@@ -141,7 +156,7 @@ def prepare_bins(di: DataInfo, nbins: int, nbins_cats: int,
     col_nbins = np.array(
         [min(fr.vec(c).cardinality, nbins_cats) if cat else nbins
          for c, cat in zip(xs, is_cat)], np.int32)
-    B = max(nbins, min(max_card, nbins_cats))
+    B = max(nbins, table_width(min(max_card, nbins_cats)))
     with TimeLine.span("train", "bin", cat_cols=int(is_cat.sum()),
                        max_card=int(max_card), table_bins=int(B)):
         if (histogram_type in ("UniformAdaptive", "Random")
@@ -177,7 +192,7 @@ def prepare_bins(di: DataInfo, nbins: int, nbins_cats: int,
 
 
 def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int,
-               col_nbins=None):
+               col_nbins=None, scoring: bool = False):
     """Bin raw values AND pack to the narrowest dtype the fine bin
     count permits — the one binning entry every trainer and scorer
     shares.  The ``tree.bins_dtype`` lever is resolved HERE, outside
@@ -188,7 +203,10 @@ def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int,
     packed and int32 matrices hold identical integers (ops/binpack.py
     decode contract), so descent and histograms agree bitwise.
     ``col_nbins`` is the model's ``col_nbins`` (BinnedData): None, from
-    an artifact that predates it, keeps every categorical code."""
+    an artifact that predates it, keeps every categorical code.
+    ``scoring``: the matrix is a frame being SCORED (a validation frame,
+    ``predict``), binned under scope ``h2o.score.bin`` so that a profile
+    tells it from the training frame's ``h2o.bin.assign``."""
     # a TRACED matrix means a caller is compiling its whole predict
     # around this call (serve/engine.py): the bins are an intermediate
     # of that program, not an HBM-resident input, and a lever cannot be
@@ -199,7 +217,8 @@ def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int,
                     fine_nbins,
                     out_dtype=packed_dtype_name(fine_nbins, packed),
                     col_nbins=None if col_nbins is None
-                    else jnp.asarray(col_nbins, jnp.int32))
+                    else jnp.asarray(col_nbins, jnp.int32),
+                    scope="h2o.score.bin" if scoring else "h2o.bin.assign")
 
 
 def bin_matrix_out(matrix, out: Dict):
@@ -207,13 +226,60 @@ def bin_matrix_out(matrix, out: Dict):
     bins raw values into a trained model's bin space."""
     return bin_matrix(matrix, jnp.asarray(out["split_points"]),
                       out["is_cat"], model_fine_na(out),
-                      out.get("col_nbins"))
+                      out.get("col_nbins"), scoring=True)
 
 
-@functools.partial(jax.jit, static_argnames=("nbins", "out_dtype"))
-@jax.named_scope("h2o.bin.assign")
+def bin_validation_frame(job, valid: Frame, x, domains, binned: BinnedData):
+    """A validation frame's bins in the training frame's bin space:
+    its columns ``x`` in the training ``domains`` (``adapt_frame``: an
+    enum matched by level string, an unseen level NA), then ``bin_matrix``
+    on the training split points.  Returns ``(bins, prepared)``;
+    ``prepared`` is the span ``train.valid.prepare``'s ring event and the
+    device count of rows with an unseen level, which the scorer writes
+    into the event with its first scoring point's sync (no sync here,
+    before the first launch)."""
+    from h2o_tpu.models.model import adapt_frame
+    with TimeLine.span("train", "valid.prepare",
+                       rows=int(valid.nrows)) as ev:
+        ad = adapt_frame(valid, x, domains, warn=job.warn)
+        ev.update(cat_cols=int(np.sum(binned.is_cat)),
+                  remapped_cols=len(ad.remapped),
+                  unseen_levels=ad.unseen_levels,
+                  unseen_rows=0 if ad.unseen_rows is None else None)
+        bins = bin_matrix(ad.matrix, binned.split_points_dev,
+                          binned.is_cat, binned.fine, binned.col_nbins,
+                          scoring=True)
+    return bins, (ev, ad.unseen_rows)
+
+
+def final_validation_metrics(model, valid: Frame, scorer):
+    """The validation metrics that end ``train()``, under span
+    ``train.final_metrics.valid``: from the F the per-block scorer
+    carried to the last kept tree (``source`` = ``carried_F``: the metric
+    kernels, nothing binned or descended again), else by scoring the
+    finished forest (``rescore``: no scorer ran, as with no scoring
+    interval, stopping rule or runtime budget)."""
+    ntrees = int(model.output["ntrees_actual"])
+    carried = scorer is not None and scorer.is_validation and \
+        scorer.ntrees == ntrees
+    with TimeLine.span("train", "final_metrics.valid",
+                       source="carried_F" if carried else "rescore"):
+        if carried:
+            return scorer.valid_metrics(scorer.F, ntrees)
+        return model.model_metrics(valid)
+
+
+@functools.partial(jax.jit, static_argnames=("nbins", "out_dtype", "scope"))
 def _bin_all(matrix, split_points, is_cat, nbins: int,
-             out_dtype: str = "int32", col_nbins=None):
+             out_dtype: str = "int32", col_nbins=None,
+             scope: str = "h2o.bin.assign"):
+    with jax.named_scope(scope):
+        return _bin_all_traced(matrix, split_points, is_cat, nbins,
+                               out_dtype, col_nbins)
+
+
+def _bin_all_traced(matrix, split_points, is_cat, nbins: int,
+                    out_dtype: str, col_nbins):
     """Raw values -> bin indices in [0, nbins]; nbins = NA bucket.
 
     A categorical code at or past its column's ``col_nbins`` (a level

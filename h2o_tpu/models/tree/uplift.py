@@ -245,8 +245,7 @@ class UpliftDRFModel(Model):
 
     def predict_raw(self, frame: Frame):
         out = self.output
-        m = frame.as_matrix(out["x"])
-        bins = st.bin_matrix_out(m, out)
+        bins = st.bin_matrix_out(self.scoring_matrix(frame), out)
         D = int(out["max_depth"])
         T = max(int(out["ntrees_actual"]), 1)
         sc = jnp.asarray(out["split_col"])[:, None]

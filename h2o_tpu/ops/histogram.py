@@ -280,9 +280,22 @@ def histogram_build_traced(bins, leaf, stats, n_leaves: int, nbins: int,
         acc, _ = jax.lax.scan(body, init, (b3, l3, s3))
         rem = R - nblk * blk
         if rem:
-            acc = acc + _block_hist(
-                bucketize(b_sh[nblk * blk:], l_sh[nblk * blk:]),
-                l_sh[nblk * blk:], s_sh[nblk * blk:], n_leaves, nbins, mmd)
+            # the rows left over are PADDED to a whole block (inactive
+            # rows, leaf -1), so that every contraction of the program has
+            # the one shape.  Cut at its own, shorter shape the last block
+            # was a contraction that one block in 1,404 ran, and at 6,656
+            # rows x 13 columns x 338 bins the chip's compiler emitted one
+            # that handed back a table of zeros: both trees of a job grew
+            # no split (PERF.md, PR 34).  Cutting every block inside the
+            # scan instead (the last one starting early and masked) costs
+            # a seventh of a window: XLA then reads `bins` block by block
+            # where it keeps one relayout of the whole matrix for the scan
+            pad = blk - rem
+            bb = jnp.pad(b_sh[nblk * blk:], ((0, pad), (0, 0)))
+            lb = jnp.pad(l_sh[nblk * blk:], (0, pad), constant_values=-1)
+            sb = jnp.pad(s_sh[nblk * blk:], ((0, pad), (0, 0)))
+            acc = acc + _block_hist(bucketize(bb, lb), lb, sb, n_leaves,
+                                    nbins, mmd)
         return hpsum(acc, "hist.table")
 
     # the block scan, its accumulator, the cross-node combine and the

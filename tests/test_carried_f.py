@@ -11,7 +11,8 @@ throws a speculative block away, and the single-dispatch path.
 
 The scoring history is held the same way.  With no validation frame the
 block loop scores block t on block t's ``f_final`` and descends nothing;
-a validation frame still gets a scorer that descends each new block.
+a validation frame still gets a scorer that descends each new block, and
+its history holds both frames' numbers at every point.
 Handing the training frame in as the validation frame therefore gives the
 history of a descending scorer on the same rows: bit-equal with blocks of
 one tree (the same additions in the same order), equal to float32
@@ -306,9 +307,13 @@ def _inside(calls, spans):
 
 
 def _history(model, prefix):
-    """The scoring history's numbers, without the prefix and the clock."""
+    """The scoring history's numbers for the frame ``prefix`` names,
+    without the prefix and the clock (a job with a validation frame
+    reports both frames at every point)."""
+    other = {"training_": "validation_", "validation_": "training_"}[prefix]
     return [{k[len(prefix):] if k.startswith(prefix) else k: v
-             for k, v in row.items() if k != "timestamp"}
+             for k, v in row.items()
+             if k != "timestamp" and not k.startswith(other)}
             for row in model.output["scoring_history"]]
 
 
@@ -339,6 +344,14 @@ def test_scoring_history_is_that_of_a_descending_scorer(
     got = _history(carried, "training_")
     want = _history(descended, "validation_")
     assert len(got) == len(want) == len(spans) and len(got) >= 2
+    # beside the validation frame's numbers the job reports the training
+    # frame's at every point, from the same carried F as the job without
+    for g, both in zip(got, descended.output["scoring_history"]):
+        for k in g:
+            if "training_" + k in both:
+                assert both["training_" + k] == g[k], (case, k)
+        assert "training_" + ("logloss" if response != "regression"
+                              else "mse") in both
     for g, w in zip(got, want):
         assert set(g) == set(w) and len(g) >= 2
         assert g["number_of_trees"] == w["number_of_trees"]
