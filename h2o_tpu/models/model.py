@@ -13,6 +13,8 @@ Models are host objects in the DKV holding device parameter pytrees.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import pickle
 import time
 from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
@@ -23,9 +25,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from h2o_tpu.core.cloud import cloud
+from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import (Frame, T_CAT, Vec, _codes_in_domain,
                                 domain_table, table_unseen_levels)
 from h2o_tpu.core.job import Job
+from h2o_tpu.core.landing import reshard_rows
 from h2o_tpu.core.log import get_logger
 from h2o_tpu.core.store import Key
 from h2o_tpu.models import metrics as mm
@@ -211,6 +215,54 @@ def _raw_to_frame(raw, nrows: int, dom: Optional[List[str]]) -> Frame:
     for k in range(len(dom)):
         vecs.append(Vec(raw[:, 1 + k], nrows=nrows))
     return Frame(names, vecs)
+
+
+class Folds(NamedTuple):
+    """A cross-validated job's fold assignment."""
+    ids: jax.Array      # (padded_rows,) int32 on the device; a pad row -1
+    n: int
+    scheme: str         # modulo | random | stratified | fold_column
+    counts: np.ndarray  # (n,) rows of each fold, counted on the host
+
+
+@dataclasses.dataclass
+class CVFold:
+    """What a model of a cross-validated job is handed in place of frames
+    of its own, by a builder whose ``_cv_shared`` prepares something: the
+    job's shared preparation, and for a FOLD model the two weight vectors
+    over the job's frame.  The main model gets ``shared`` alone."""
+    shared: Any
+    weights: Optional[jax.Array] = None   # the user's; 0 on the fold's rows
+    holdout: Optional[jax.Array] = None   # the user's on the fold's rows
+    holdout_rows: int = 0
+    # set by ``_fit``: the model's raw predictions on EVERY row of the
+    # job's frame, from the F it carried (the fold's rows among them)
+    raw: Optional[jax.Array] = None
+
+
+@functools.partial(jax.jit, static_argnames=("padded", "n"))
+@jax.named_scope("h2o.cv.folds")
+def _modulo_folds(nrows, padded: int, n: int):
+    r = jnp.arange(padded, dtype=jnp.int32)
+    return jnp.where(r < nrows, r % n, -1)
+
+
+@jax.jit
+@jax.named_scope("h2o.cv.weights")
+def _fold_weights(fold, user_w, i):
+    """Fold ``i``'s (training weights, holdout weights): H2O-3's
+    ``cv_makeWeights``."""
+    hold = fold == i
+    return jnp.where(hold, 0.0, user_w), jnp.where(hold, user_w, 0.0)
+
+
+@jax.jit
+@jax.named_scope("h2o.cv.select")
+def _select_holdout(fold, i, raw, combined):
+    """Fold ``i``'s rows of ``raw`` into the combined holdout predictions."""
+    hold = fold == i
+    return jnp.where(hold[:, None] if raw.ndim == 2 else hold, raw,
+                     combined)
 
 
 class Model:
@@ -594,16 +646,38 @@ class ModelBuilder:
         raise NotImplementedError
 
     # -- n-fold cross-validation orchestration -----------------------------
-    # Reference: hex/ModelBuilder.java:535-690 — N fold models trained with
-    # zero-weight holdout rows, combined holdout predictions scored once
-    # (cv_mainModelMetrics), optimal stopping params transferred to the main
-    # model (cv_computeAndSetOptimalParameters), then the main model trained
-    # on all rows.
+    # Reference: hex/ModelBuilder.java:535-690 (computeCrossValidation).
+    # One orchestrator for every builder; a builder whose K+1 models can
+    # share what it prepares from the frame says so through ``_cv_shared``.
 
-    def _fold_assignment(self, train: Frame, y: Optional[str]) -> np.ndarray:
+    def _cv_shared(self, job: Job, x: List[str], y: Optional[str],
+                   train: Frame):
+        """The hook of ``_fit_cv``: what the fold models and the main
+        model of one cross-validated job share, prepared ONCE from the
+        job's frame (the tree builders: its ``BinnedData``).  A builder
+        that returns something takes ``_fit(..., cv=CVFold)`` and trains
+        a fold model on the job's own frame under the fold's weights,
+        leaving its predictions on every row in ``cv.raw``.  None (the
+        default): each fold model gets a frame and a holdout frame of
+        its own and the holdout rows are scored by ``predict_raw``."""
+        return None
+
+    def _fold_assignment(self, train: Frame, y: Optional[str]) -> "Folds":
+        """Each row's fold (H2O-3 ``cv_AssignFold``).  Modulo is an iota
+        on the device; Random, Stratified and a fold column are drawn on
+        the host and landed once; only Stratified reads the response."""
         p = self.params
-        nrows = train.nrows
+        nrows, padded = train.nrows, train.padded_rows
+        scheme = (p.get("fold_assignment") or "AUTO").lower()
+        n = int(p.get("nfolds") or 0)
+        if scheme == "modulo" and not p.get("fold_column"):
+            ids = reshard_rows(_modulo_folds(jnp.int32(nrows), padded, n))
+            counts = np.array([len(range(i, nrows, n)) for i in range(n)])
+            return Folds(ids, n, scheme, counts)
+        seed = int(p.get("seed") or -1)
+        rng = np.random.default_rng(seed if seed >= 0 else None)
         if p.get("fold_column"):
+            scheme = "fold_column"
             fv = train.vec(p["fold_column"])
             vals = np.asarray(fv.to_numpy(), np.float64)
             if np.isnan(vals).any() or (fv.is_categorical and
@@ -611,63 +685,102 @@ class ModelBuilder:
                 raise ValueError("fold_column contains missing values")
             # remap to contiguous 0..n-1 (non-contiguous user fold ids
             # would otherwise create empty phantom folds)
-            _, codes = np.unique(vals, return_inverse=True)
-            return codes
-        n = int(p["nfolds"])
-        scheme = (p.get("fold_assignment") or "AUTO").lower()
-        seed = int(p.get("seed") or -1)
-        rng = np.random.default_rng(seed if seed >= 0 else None)
-        if scheme == "modulo":
-            return np.arange(nrows) % n
-        if scheme == "stratified" and y and train.vec(y).is_categorical:
+            _, host = np.unique(vals, return_inverse=True)
+        elif scheme == "stratified" and y and train.vec(y).is_categorical:
             yv = np.asarray(train.vec(y).to_numpy())
-            fold = np.zeros(nrows, np.int64)
+            host = np.zeros(nrows, np.int64)
             for k in np.unique(yv):
                 idx = np.flatnonzero(yv == k)
                 rng.shuffle(idx)
-                fold[idx] = np.arange(len(idx)) % n
-            return fold
-        return rng.integers(0, n, nrows)
+                host[idx] = np.arange(len(idx)) % n
+        else:
+            scheme = "random"
+            host = rng.integers(0, n, nrows)
+        n = int(host.max()) + 1
+        full = np.full(padded, -1, np.int32)    # a pad row is in no fold
+        full[:nrows] = host
+        return Folds(cloud().device_put_rows(full), n, scheme,
+                     np.bincount(host, minlength=n))
 
     def _fit_cv(self, job: Job, x: List[str], y: Optional[str],
                 train: Frame, valid: Optional[Frame]) -> Model:
+        """K fold models and the main model as one job, step by step as
+        H2O-3's ``ModelBuilder.computeCrossValidation``:
+
+        * ``cv_AssignFold`` / ``cv_makeWeights``: the fold ids once, and
+          per fold the training weights (the user's, 0 on the fold's
+          rows) and the holdout weights (the user's on the fold's rows,
+          0 elsewhere), all on the device;
+        * ``cv_buildModels``: fold model i sees its fold at weight 0 in
+          every statistic and scores it as its stopping frame
+          (``cv_makeFoldValid``).  Where the builder shares what it
+          prepares (``_cv_shared``) the fold model trains on the job's
+          own frame and both of its metrics, and its holdout
+          predictions, come from the F it carried; otherwise it gets a
+          weighted copy of the frame and a holdout slice, and the whole
+          frame is scored again (``cv_scoreCVModels``);
+        * ``cv_computeAndSetOptimalParameters``: a stopping rule's tree
+          count goes to the main model as the mean of the fold models';
+        * the main model on all rows, then ``cv_mainModelMetrics``: the
+          combined holdout predictions scored once, and fold by fold for
+          the summary.
+        """
         p = self.params
-        fold = self._fold_assignment(train, y)
-        nfolds = int(fold.max()) + 1
-        user_w = np.asarray(train.vec(p["weights_column"]).to_numpy(),
-                            np.float32) if p.get("weights_column") \
-            else np.ones(train.nrows, np.float32)
+        if train.is_ragged:
+            train.repack()      # a row's place on the device is its index
+        # the user's weights are weights, in every model of the job
+        x = [c for c in x if c != p.get("weights_column")]
+        nrows = train.nrows
+        with TimeLine.span("train", "cv.folds", rows=nrows) as ev:
+            folds = self._fold_assignment(train, y)
+            ev.update(nfolds=folds.n, scheme=folds.scheme)
+        fold, nfolds = folds.ids, folds.n
+        user_w = train.vec(p["weights_column"]).data \
+            if p.get("weights_column") \
+            else jnp.ones((train.padded_rows,), jnp.float32)
+        shared = self._cv_shared(job, x, y, train)
+        bins, source = ("own", "descent") if shared is None \
+            else ("shared", "carried_F")
+        fold_host = None if shared is not None \
+            else np.asarray(fold)[:nrows]
 
         cv_models, raw_combined = [], None
         for i in range(nfolds):
-            hold = fold == i
-            w_i = np.where(hold, 0.0, user_w).astype(np.float32)
-            wname = f"__cv_weights_{i}"
-            fr_i = Frame(train.names + [wname],
-                         train.vecs + [Vec(w_i)])
-            # holdout rows as the fold's validation frame so early stopping
-            # watches out-of-fold metrics (cv_makeFoldValid analog)
-            fr_hold = train.slice_rows(hold)
-            fr_hold.add(wname, Vec(user_w[hold]))
+            w_i, hold_w = _fold_weights(fold, user_w, jnp.int32(i))
             sub_params = dict(p)
-            sub_params.update(nfolds=0, fold_column=None,
-                              weights_column=wname, checkpoint=None,
+            sub_params.update(nfolds=0, fold_column=None, checkpoint=None,
                               model_id=None, recovery_dir=None)
-            sub = self.__class__(**{k: v for k, v in sub_params.items()
-                                    if k in self.default_params()})
-            sub.params["response_column"] = y
             job.update((i + 0.0) / (nfolds + 1.0),
                        f"CV model {i + 1}/{nfolds}")
-            m_i = sub._fit(job, x, y, fr_i, fr_hold)
+            rows_out = int(folds.counts[i])
+            with TimeLine.span("train", "cv.model", fold=i + 1,
+                               rows_in=nrows - rows_out, rows_out=rows_out,
+                               bins=bins):
+                if shared is not None:
+                    sub = self._cv_sub(sub_params, y)
+                    cvf = CVFold(shared, w_i, hold_w, rows_out)
+                    m_i = sub._fit(job, x, y, train, None, cv=cvf)
+                else:
+                    wname = f"__cv_weights_{i}"
+                    sub = self._cv_sub(dict(sub_params,
+                                            weights_column=wname), y)
+                    fr_i = Frame(train.names + [wname],
+                                 train.vecs + [Vec(w_i, nrows=nrows)])
+                    # holdout rows as the fold's validation frame, so that
+                    # a stopping rule watches out-of-fold metrics
+                    fr_hold = train.slice_rows(fold_host == i)
+                    fr_hold.add(wname, Vec(np.asarray(hold_w)[:nrows][
+                        fold_host == i]))
+                    m_i = sub._fit(job, x, y, fr_i, fr_hold)
             m_i.key = Key(f"{self.model_id or self.algo}_cv_{i + 1}")
             cv_models.append(m_i)
-            raw_i = np.asarray(m_i.predict_raw(train))
-            mask = (fold == i)
-            pm = np.pad(mask, (0, raw_i.shape[0] - len(mask)))
-            if raw_combined is None:
-                raw_combined = np.zeros_like(raw_i)
-            raw_combined = np.where(
-                pm[:, None] if raw_i.ndim == 2 else pm, raw_i, raw_combined)
+            with TimeLine.span("train", "cv.holdout", source=source):
+                raw_i = m_i.predict_raw(train) if shared is None \
+                    else cvf.raw
+                if raw_combined is None:
+                    raw_combined = jnp.zeros_like(raw_i)
+                raw_combined = _select_holdout(fold, jnp.int32(i), raw_i,
+                                               raw_combined)
 
         # optimal-params transfer: early stopping resolved by CV
         if int(p.get("stopping_rounds") or 0) > 0 and \
@@ -679,16 +792,18 @@ class ModelBuilder:
             self.params = p
 
         job.update(nfolds / (nfolds + 1.0), "main model on full data")
-        model = self._fit(job, x, y, train, valid)
+        with TimeLine.span("train", "cv.model", fold="main", rows_in=nrows,
+                           rows_out=0, bins=bins):
+            model = self._fit(job, x, y, train, valid) if shared is None \
+                else self._fit(job, x, y, train, valid,
+                               cv=CVFold(shared))
 
-        cvm = model.metrics_from_raw(jnp.asarray(raw_combined), train)
-        pad = raw_combined.shape[0] - train.nrows
-        fold_p = np.pad(fold, (0, pad), constant_values=-1)
-        user_w_p = np.pad(user_w, (0, pad))
-        fold_mms = [model.metrics_from_raw(
-            jnp.asarray(raw_combined), train,
-            w=jnp.asarray(np.where(fold_p == i, user_w_p, 0.0)))
-            for i in range(nfolds)]
+        with TimeLine.span("train", "cv.metrics", nfolds=nfolds):
+            cvm = model.metrics_from_raw(raw_combined, train)
+            fold_mms = [model.metrics_from_raw(
+                raw_combined, train,
+                w=_fold_weights(fold, user_w, jnp.int32(i))[1])
+                for i in range(nfolds)]
         summary: Dict[str, Any] = {}
         for k, v in fold_mms[0].data.items():
             if isinstance(v, (int, float)) and not isinstance(v, bool):
@@ -706,7 +821,7 @@ class ModelBuilder:
             model.output["cross_validation_models"] = \
                 [str(m.key) for m in cv_models]
         if p.get("keep_cross_validation_predictions"):
-            pf = _raw_to_frame(raw_combined, train.nrows,
+            pf = _raw_to_frame(raw_combined, nrows,
                                model.output.get("response_domain"))
             pf.key = Key(f"cv_holdout_prediction_{model.key}")
             cloud().dkv.put(pf.key, pf)
@@ -714,12 +829,24 @@ class ModelBuilder:
                 str(pf.key)
         if p.get("keep_cross_validation_fold_assignment"):
             ff = Frame(["fold_assignment"],
-                       [Vec(fold.astype(np.float32))])
+                       [Vec(fold.astype(jnp.float32), nrows=nrows)])
             ff.key = Key(f"cv_fold_assignment_{model.key}")
             cloud().dkv.put(ff.key, ff)
             model.output["cross_validation_fold_assignment_frame_id"] = \
                 str(ff.key)
         return model
+
+    def _cv_sub(self, sub_params: Dict[str, Any], y: Optional[str]):
+        """A fold model's builder: this builder's class and its
+        parameters AS RESOLVED (a constructor that translates a client's
+        names onto the engine's, XGBoost's, is handed none to translate
+        again: its defaults under one name would override the user's
+        value under the other)."""
+        sub = self.__class__()
+        sub.params.update({k: v for k, v in sub_params.items()
+                           if k in sub.params})
+        sub.params["response_column"] = y
+        return sub
 
     # -- shared helpers -----------------------------------------------------
 

@@ -19,7 +19,7 @@ import numpy as np
 
 from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import Frame
-from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
+from h2o_tpu.models.model import CVFold, DataInfo, Model, ModelBuilder
 from h2o_tpu.models.tree import shared_tree as st
 
 EPS = 1e-10
@@ -81,7 +81,14 @@ class DRF(ModelBuilder):
                  stopping_tolerance=1e-3)
         return p
 
-    def _fit(self, job, x, y, train: Frame, valid: Optional[Frame]):
+    def _cv_shared(self, job, x, y, train: Frame):
+        return st.shared_bins(self.params, x, y, train)
+
+    def _fit(self, job, x, y, train: Frame, valid: Optional[Frame],
+             cv: Optional[CVFold] = None):
+        """``cv``: as ``GBM._fit``'s: one of a cross-validated job's
+        models, on the job's ``BinnedData``; a fold model's metrics and
+        holdout predictions are read from the votes it carried."""
         p = self.params
         ckpt = self.checkpoint_model()
         di = DataInfo(train, x, y, mode="tree",
@@ -105,13 +112,16 @@ class DRF(ModelBuilder):
                 np.asarray(co["split_points"]), sp_dev,
                 np.asarray(co["is_cat"]), int(co["nbins"]), ck_fine,
                 hist_type, co.get("col_nbins"))
+        elif cv is not None:
+            binned = cv.shared
         else:
             binned = st.prepare_bins(
                 di, int(p["nbins"]), int(p["nbins_cats"]), hist_type,
                 int(p.get("nbins_top_level") or 1024))
         bins = binned.bins
         yv = di.response()
-        w = di.weights()
+        fold_model = cv is not None and cv.weights is not None
+        w = cv.weights if fold_model else di.weights()
         active = di.valid_mask()
         R = bins.shape[0]
         C = len(di.x)
@@ -148,7 +158,11 @@ class DRF(ModelBuilder):
         sp_np = np.asarray(binned.split_points)
         ic_np = np.asarray(binned.is_cat)
 
+        F_train = None      # the votes the driver carried, on every row
+
         def make_model(sc, bs, vl, ch, n_new, F_final):
+            nonlocal F_train
+            F_train = F_final
             if ckpt is not None:
                 sc = np.concatenate([co["split_col"], sc]) if n_new \
                     else np.asarray(co["split_col"])
@@ -220,11 +234,17 @@ class DRF(ModelBuilder):
                 0, None)
             dom_sc = di.response_domain if nclass >= 2 else None
 
-            def metrics_on(frame):
+            def metrics_on(frame, w_sc=None):
                 return lambda Fv, ntot: proto.metrics_from_raw(
-                    raw_from_votes(Fv, ntot, dom_sc), frame)
+                    raw_from_votes(Fv, ntot, dom_sc), frame, w=w_sc)
 
-            if valid is None:
+            if fold_model:
+                # both metrics from the one carried F (``GBM._fit``)
+                scorer = IncrementalScorer(
+                    metrics_on(train, w),
+                    holdout_metrics=metrics_on(train, cv.holdout),
+                    holdout_rows=cv.holdout_rows)
+            elif valid is None:
                 # the trainer's carried F holds the raw votes on every
                 # row of this frame: the driver scores each block on it
                 scorer = IncrementalScorer(metrics_on(train))
@@ -244,6 +264,17 @@ class DRF(ModelBuilder):
                                 prior_trees=prior,
                                 recovery=getattr(self, "_recovery", None),
                                 data_frame=train)
+        if fold_model:
+            with TimeLine.span("train", "final_metrics",
+                               source="carried_F"):
+                cv.raw = raw_from_votes(
+                    F_train, int(model.output["ntrees_actual"]),
+                    model.output.get("response_domain"))
+                model.output["training_metrics"] = model.metrics_from_raw(
+                    cv.raw, train, w=w)
+                model.output["validation_metrics"] = \
+                    model.metrics_from_raw(cv.raw, train, w=cv.holdout)
+            return model
         with TimeLine.span("train", "final_metrics", source="rescore"):
             model.output["training_metrics"] = model.model_metrics(train)
             if valid is not None:

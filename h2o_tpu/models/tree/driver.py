@@ -97,14 +97,24 @@ class IncrementalScorer:
     trees to it by one descent, and ``valid_metrics(F, ntrees_total)``
     scores it: a scoring point then carries both frames' metrics, the
     validation frame's last (the one a stopping rule reads, as H2O-3's).
+
+    With ``holdout_metrics`` (a fold model of a cross-validated job: the
+    fold's rows lie in the training frame at weight 0, and growth routes
+    them like any row) the second metrics are read from the SAME carried
+    F under the holdout weights (H2O-3's ``cv_makeFoldValid`` frame):
+    nothing is binned, nothing descended, no F kept.
     """
 
     def __init__(self, to_metrics: Callable, bins=None, F_init=None,
                  depth: int = 0, fine_na: int = -1,
                  valid_metrics: Optional[Callable] = None,
-                 prepared=None, ntrees: int = 0):
+                 prepared=None, ntrees: int = 0,
+                 holdout_metrics: Optional[Callable] = None,
+                 holdout_rows: int = 0):
         self.to_metrics = to_metrics
         self.valid_metrics = valid_metrics
+        self.holdout_metrics = holdout_metrics
+        self.holdout_rows = holdout_rows
         self.bins = bins
         self.F = F_init
         self.ntrees = ntrees        # trees summed in ``F``
@@ -145,7 +155,10 @@ class IncrementalScorer:
         block ``tf``: the training frame's, then the validation frame's
         where there is one."""
         out = [("training_", self.to_metrics(tf.f_final, ntrees_total))]
-        if self.is_validation:
+        if self.holdout_metrics is not None:
+            out.append(("validation_",
+                        self.holdout_metrics(tf.f_final, ntrees_total)))
+        elif self.is_validation:
             self.add(tf.split_col, tf.bitset, tf.value, tf.child,
                      tf.thr_bin, tf.na_left)
             out.append(("validation_",
@@ -473,7 +486,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
             if scorer is not None:
                 with TimeLine.span(
                         "train", "block.score", source=scorer.source,
-                        valid_rows=scorer.valid_rows):
+                        valid_rows=scorer.valid_rows,
+                        holdout_rows=scorer.holdout_rows):
                     row = {"number_of_trees": prior_trees + done,
                            "timestamp": time.time()}
                     points = scorer.score(tf, prior_trees + done)

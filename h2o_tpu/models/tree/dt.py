@@ -31,9 +31,10 @@ class DT(DRF):
                  sample_rate=1.0, mtries=-2)   # -2 = all columns (DRF.java)
         return p
 
-    def _fit(self, job, x, y, train: Frame, valid: Optional[Frame]):
+    def _fit(self, job, x, y, train: Frame, valid: Optional[Frame],
+             cv=None):
         self.params["ntrees"] = 1
         self.params["sample_rate"] = 1.0
         # mtries: all columns, not DRF's sqrt subsampling
         self.params["mtries"] = len([c for c in x]) or -1
-        return super()._fit(job, x, y, train, valid)
+        return super()._fit(job, x, y, train, valid, cv=cv)

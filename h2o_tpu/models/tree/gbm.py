@@ -26,7 +26,7 @@ import numpy as np
 from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import Frame
 from h2o_tpu.models.distributions import get_distribution
-from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
+from h2o_tpu.models.model import CVFold, DataInfo, Model, ModelBuilder
 from h2o_tpu.models.tree import shared_tree as st
 
 EPS = 1e-10
@@ -145,7 +145,17 @@ class GBM(ModelBuilder):
             mono[di.x.index(name)] = d
         return mono if mono.any() else None
 
-    def _fit(self, job, x, y, train: Frame, valid: Optional[Frame]):
+    def _cv_shared(self, job, x, y, train: Frame):
+        return st.shared_bins(self.params, x, y, train,
+                              offset=self.params.get("offset_column"))
+
+    def _fit(self, job, x, y, train: Frame, valid: Optional[Frame],
+             cv: Optional[CVFold] = None):
+        """``cv``: this model is one of a cross-validated job's
+        (``ModelBuilder._fit_cv``): it bins nothing (``cv.shared`` is the
+        job's ``BinnedData``) and, a fold model, trains under
+        ``cv.weights`` and reads its holdout metrics and predictions from
+        the F it carries."""
         p = self.params
         ckpt = self.checkpoint_model()
         di = DataInfo(train, x, y, mode="tree",
@@ -177,13 +187,16 @@ class GBM(ModelBuilder):
                 np.asarray(co["split_points"]), sp_dev,
                 np.asarray(co["is_cat"]), int(co["nbins"]), ck_fine,
                 hist_type, co.get("col_nbins"))
+        elif cv is not None:
+            binned = cv.shared
         else:
             binned = st.prepare_bins(
                 di, int(p["nbins"]), int(p["nbins_cats"]), hist_type,
                 int(p.get("nbins_top_level") or 1024))
         bins = binned.bins
         yv = di.response()
-        w = di.weights()
+        fold_model = cv is not None and cv.weights is not None
+        w = cv.weights if fold_model else di.weights()
         active = di.valid_mask()
         R = bins.shape[0]
 
@@ -359,16 +372,24 @@ class GBM(ModelBuilder):
                 0, None)
             dom_sc = di.response_domain if nclass >= 2 else None
 
-            def metrics_on(frame):
+            def metrics_on(frame, w_sc=None):
                 def to_metrics(Fv, ntot):
                     raw = raw_from_F(Fv, dom_sc, dist_name,
                                      float(p["tweedie_power"]),
                                      custom_link=custom.link_name
                                      if custom else None)
-                    return proto.metrics_from_raw(raw, frame)
+                    return proto.metrics_from_raw(raw, frame, w=w_sc)
                 return to_metrics
 
-            if valid is None:
+            if fold_model:
+                # both metrics of a scoring point from the one carried F:
+                # the rows trained on, and the fold's rows (weight 0 in
+                # every statistic, routed by growth like any row)
+                scorer = IncrementalScorer(
+                    metrics_on(train, w),
+                    holdout_metrics=metrics_on(train, cv.holdout),
+                    holdout_rows=cv.holdout_rows)
+            elif valid is None:
                 # the trainer's carried F is this frame's prediction: the
                 # driver scores each block on it and descends nothing
                 scorer = IncrementalScorer(metrics_on(train))
@@ -397,9 +418,14 @@ class GBM(ModelBuilder):
             # loop scores the final concatenated forest once
             return model
         with TimeLine.span("train", "final_metrics", source="carried_F"):
+            raw = model._raw_from_F(F_train)
             model.output["training_metrics"] = model.metrics_from_raw(
-                model._raw_from_F(F_train), train)
-            if valid is not None:
+                raw, train, w=w if fold_model else None)
+            if fold_model:
+                cv.raw = raw
+                model.output["validation_metrics"] = \
+                    model.metrics_from_raw(raw, train, w=cv.holdout)
+            elif valid is not None:
                 model.output["validation_metrics"] = \
                     st.final_validation_metrics(model, valid, scorer)
         return model

@@ -195,6 +195,23 @@ def prepare_bins(di: DataInfo, nbins: int, nbins_cats: int,
                           col_nbins)
 
 
+def shared_bins(p: Dict, x, y, train: Frame,
+                offset: Optional[str] = None) -> Optional[BinnedData]:
+    """The ONE ``BinnedData`` of a cross-validated job
+    (``ModelBuilder._cv_shared`` of the tree builders): ``prepare_bins``
+    reads no weights (the split points are those of all rows), so the K
+    fold models and the main model bin alike, to the bit, and one binning
+    serves all of them.  None with a checkpoint, whose grid is the main
+    model's alone: every model then bins for itself."""
+    if p.get("checkpoint"):
+        return None
+    di = DataInfo(train, x, y, mode="tree",
+                  weights=p.get("weights_column"), offset=offset)
+    return prepare_bins(di, int(p["nbins"]), int(p["nbins_cats"]),
+                        resolve_histogram_type(p),
+                        int(p.get("nbins_top_level") or 1024))
+
+
 def bin_matrix(matrix, split_points_dev, is_cat, fine_nbins: int,
                col_nbins=None, scoring: bool = False):
     """Bin raw values AND pack to the narrowest dtype the fine bin

@@ -123,7 +123,14 @@ class XGBoost(GBM):
                 "booster='gblinear' on this engine; refusing to train "
                 "with a silently-ignored setting")
 
-    def _fit(self, job, x, y, train: Frame, valid: Optional[Frame]):
+    def _cv_shared(self, job, x, y, train: Frame):
+        # gblinear and dart drive fits of their own: the generic path
+        if self.params.get("booster", "gbtree") != "gbtree":
+            return None
+        return super()._cv_shared(job, x, y, train)
+
+    def _fit(self, job, x, y, train: Frame, valid: Optional[Frame],
+             cv=None):
         booster = self.params.get("booster", "gbtree")
         if booster == "gblinear":
             return self._fit_gblinear(job, x, y, train, valid)
@@ -131,7 +138,7 @@ class XGBoost(GBM):
             return self._fit_dart(job, x, y, train, valid)
         # gbtree: reg_lambda flows into the Newton denominator via the
         # engine's reg_lambda kwarg (jit_engine._node_val)
-        return super()._fit(job, x, y, train, valid)
+        return super()._fit(job, x, y, train, valid, cv=cv)
 
     # -- booster=gblinear --------------------------------------------------
 
