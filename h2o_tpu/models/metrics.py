@@ -22,6 +22,54 @@ import numpy as np
 
 _NBINS_AUC = 1024
 EPS = 1e-15
+_HIST_BLOCK = 8192      # rows a contraction, as ops/histogram.py's block
+_LO_BITS = 5            # a bin is (hi, lo): hi = b >> 5, lo = b & 31
+
+
+def _score_histogram(b, wy, wn, nbins: int):
+    """Weighted score histograms ``pos[k] = sum(wy[b == k])`` and ``neg``
+    likewise, by one two-level one-hot contraction a row block, not two
+    scatter-adds (a scatter-add of 5.25M rows into 1,024 slots is
+    serialised work on the chip: 46 ms a table, PERF.md §6 PR 39).
+
+    ``onehot(hi)`` (R, nhi) meets ``[onehot(lo) * wy, onehot(lo) * wn]``
+    (R, 2 * nlo) over the rows: (nhi, 2 * nlo) -> two (nbins,) tables.
+    The weight side stays float32 (HIGHEST; the one-hot side is exact in
+    any dtype), so integer weights give the scatter's tables bit for bit.
+    One contraction a block of ``_HIST_BLOCK`` rows: no whole-frame
+    one-hot lands in HBM; the per-block partials are summed in float32."""
+    nlo = 1 << _LO_BITS
+    nhi = -(-nbins // nlo)
+    R = b.shape[0]
+    blk = max(min(_HIST_BLOCK, R), 1)
+    nblk = R // blk
+
+    def part(bb, yy, nn):
+        hi = (bb >> _LO_BITS)[:, None] == jnp.arange(nhi)[None, :]
+        lo = ((bb & (nlo - 1))[:, None] ==
+              jnp.arange(nlo)[None, :]).astype(jnp.float32)
+        rhs = jnp.concatenate([lo * yy[:, None], lo * nn[:, None]], axis=1)
+        return jax.lax.dot_general(
+            hi.astype(jnp.float32), rhs,
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)        # (nhi, 2 * nlo)
+
+    acc, _ = jax.lax.scan(
+        lambda acc, xs: (acc + part(*xs), None),
+        jnp.zeros((nhi, 2 * nlo), jnp.float32),
+        tuple(v[: nblk * blk].reshape(nblk, blk) for v in (b, wy, wn)))
+    rem = R - nblk * blk
+    if rem:
+        # the rows left over padded to a whole block at weight 0: one
+        # contraction shape, and no copy of the whole frame (PERF.md §6,
+        # PR 34: a contraction cut at its own last-block shape once came
+        # back all zero on the chip)
+        acc = acc + part(*(jnp.pad(v[nblk * blk:], (0, blk - rem))
+                           for v in (b, wy, wn)))
+    pos = acc[:, :nlo].reshape(-1)[:nbins]
+    neg = acc[:, nlo:].reshape(-1)[:nbins]
+    return pos, neg
 
 
 @functools.partial(jax.jit, static_argnames=("nbins",))
@@ -39,8 +87,7 @@ def _binomial_kernel(p, y, w, valid, nbins: int = _NBINS_AUC):
                                      jnp.log(jnp.maximum(1.0 - p, EPS))))
     mse = jnp.sum(w * (y - p) ** 2)
     b = jnp.clip((p * nbins).astype(jnp.int32), 0, nbins - 1)
-    pos = jnp.zeros((nbins,), jnp.float32).at[b].add(w * y)
-    neg = jnp.zeros((nbins,), jnp.float32).at[b].add(w * (1 - y))
+    pos, neg = _score_histogram(b, w * y, w * (1 - y), nbins)
     ymean = jnp.sum(w * y) / wsum
     return dict(logloss=logloss / wsum, mse=mse / wsum, pos=pos, neg=neg,
                 wsum=wsum, ymean=ymean)
