@@ -32,13 +32,18 @@ from typing import Any, Dict, List, Optional
 from jax.profiler import TraceAnnotation
 
 MAX_EVENTS = 2048
+MAX_PROGRAMS = 4096
 
-# the jax monitoring events whose durations DispatchStats keeps, by their
-# last path component (jax/_src/dispatch.py, jax/_src/compiler.py).
-# backend_compile wraps the persistent-cache lookup, so cache_retrieval
-# is a PART of it, not an addend.
-_COMPILE_EVENTS = ("jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
-                   "backend_compile_duration", "cache_retrieval_time_sec")
+# the jax monitoring events that make one program ready, by their last
+# path component (jax/_src/dispatch.py log_elapsed_time), in the order
+# they fire for a program, each with the field of its record.  The
+# backend compile wraps the persistent-cache lookup, so the cache's
+# ``cache_retrieval_time_sec`` is a PART of it, not an addend.
+_READY_FIELDS = {"jaxpr_trace_duration": "trace_s",
+                 "jaxpr_to_mlir_module_duration": "lower_s",
+                 "backend_compile_duration": "compile_s"}
+_READY_ORDER = tuple(_READY_FIELDS.values())
+_RETRIEVAL = "cache_retrieval_time_sec"
 
 
 class DispatchStats:
@@ -75,6 +80,8 @@ class DispatchStats:
     _phase_local = threading.local()
     _xla_compiles = 0
     _compile_seconds: Dict[str, float] = {}
+    _programs: deque = deque(maxlen=MAX_PROGRAMS)
+    _ready_local = threading.local()    # .stack, .open: see _on_ready_end
     _listener_installed = False
 
     @classmethod
@@ -176,29 +183,143 @@ class DispatchStats:
             d["ici_bytes"] += int(ici_bytes)
             d["dcn_bytes"] += int(dcn_bytes)
 
+    # -- programs made ready (jax monitoring) -----------------------------
+
     @classmethod
     def install_xla_listener(cls) -> None:
-        """Idempotent: register a jax monitoring listener that counts
-        backend compiles (the '/jax/core/compile/backend_compile_
-        duration' event — one per XLA executable actually built) and
-        sums the seconds of each ``_COMPILE_EVENTS`` event."""
+        """Idempotent: listen to the jax monitoring events that make a
+        program ready.  JAX brackets a program's tracing, its lowering
+        to MLIR and its backend compile (which holds the persistent-cache
+        lookup) each in ``dispatch.log_elapsed_time``, which reports the
+        start as a scalar and the end as a time span, synchronously on
+        the thread that makes the program; a persistent-cache hit or
+        write fires an event inside the backend compile.  From these the
+        listeners count backend compiles (``xla_compiles``: one per XLA
+        executable actually built) and keep one record per program
+        (``programs``), whose seconds are ``compile_seconds``'."""
         with cls._lock:
             if cls._listener_installed:
                 return
             cls._listener_installed = True
         from jax._src import monitoring
+        monitoring.register_scalar_listener(cls._on_ready_start)
+        monitoring.register_event_time_span_listener(cls._on_ready_end)
+        monitoring.register_event_listener(cls._on_cache_event)
+        monitoring.register_event_duration_secs_listener(cls._on_duration)
 
-        def on_event(event: str, duration: float, **kw) -> None:
-            name = event.rsplit("/", 1)[-1]
-            if name not in _COMPILE_EVENTS:
-                return
+    @classmethod
+    def _ready_state(cls):
+        """This thread's open events (``stack``, innermost last) and, by
+        nesting depth, the program being assembled there (``open``)."""
+        st = cls._ready_local
+        if not hasattr(st, "stack"):
+            st.stack, st.open = [], {}
+        return st
+
+    @staticmethod
+    def _frame(event: str = "") -> Dict[str, Any]:
+        return {"event": event, "carved": 0.0, "cache": "uncached",
+                "retrieve_s": None}
+
+    @classmethod
+    def _open_compile(cls) -> Optional[Dict[str, Any]]:
+        """The backend compile open on this thread, if it is the
+        innermost open event."""
+        stack = cls._ready_state().stack
+        if stack and stack[-1]["event"].endswith("/backend_compile_duration"):
+            return stack[-1]
+        return None
+
+    @classmethod
+    def _on_ready_start(cls, event: str, value: float, **kw) -> None:
+        if event.rsplit("/", 1)[-1] in _READY_FIELDS:
+            cls._ready_state().stack.append(cls._frame(event))
+
+    @classmethod
+    def _on_cache_event(cls, event: str, **kw) -> None:
+        name = event.rsplit("/", 1)[-1]
+        if name in ("cache_hits", "cache_misses"):
+            frame = cls._open_compile()
+            if frame is not None:
+                frame["cache"] = "hit" if name == "cache_hits" \
+                    else "compiled"
+
+    @classmethod
+    def _on_duration(cls, event: str, duration: float, **kw) -> None:
+        if event.rsplit("/", 1)[-1] != _RETRIEVAL:
+            return
+        with cls._lock:
+            cls._compile_seconds[_RETRIEVAL] = \
+                cls._compile_seconds.get(_RETRIEVAL, 0.0) + float(duration)
+        frame = cls._open_compile()
+        if frame is not None:
+            frame["retrieve_s"] = float(duration)
+
+    @classmethod
+    def _on_ready_end(cls, event: str, start: float, end: float,
+                      fun_name: str = "", **kw) -> None:
+        """One of a program's three events ended.  Its own seconds are
+        its duration less what it holds of programs made ready inside it
+        (an eager op run while tracing is a program of its own), so no
+        second is counted twice; a nested trace that compiles nothing (a
+        jitted function traced into its caller) stays in the seconds of
+        the event that encloses it.  A program's events follow each
+        other at one depth, and its backend compile closes it; an event
+        that comes again before that closes the program before, which is
+        recorded (``cache`` "traced") where nothing encloses it."""
+        field = _READY_FIELDS.get(event.rsplit("/", 1)[-1])
+        if field is None:
+            return
+        st = cls._ready_state()
+        frame = st.stack.pop() if st.stack and \
+            st.stack[-1]["event"] == event else cls._frame()
+        depth = len(st.stack)
+        if depth:
+            st.stack[-1]["carved"] += frame["carved"]
+        for d in [d for d in st.open if d > depth]:
+            del st.open[d]
+        rank = _READY_ORDER.index(field)
+        prog = st.open.get(depth)
+        if prog is not None and rank <= prog["rank"]:
+            del st.open[depth]
+            if not depth:
+                cls._publish(prog, "traced")
+            prog = None
+        if prog is None:
+            prog = st.open[depth] = {"start": start, "trace_s": 0.0,
+                                     "lower_s": 0.0, "compile_s": 0.0,
+                                     "retrieve_s": None,
+                                     "at": TimeLine.open_span()}
+        prog[field] = (end - start) - frame["carved"]
+        prog.update(rank=rank, end=end, fun=fun_name)
+        if field == "compile_s":
             with cls._lock:
-                cls._compile_seconds[name] = \
-                    cls._compile_seconds.get(name, 0.0) + float(duration)
-                if name == "backend_compile_duration":
-                    cls._xla_compiles += 1
+                cls._xla_compiles += 1
+            del st.open[depth]
+            prog["retrieve_s"] = frame["retrieve_s"]
+            cls._publish(prog, frame["cache"])
+            if depth:
+                st.stack[-1]["carved"] += \
+                    prog["trace_s"] + prog["lower_s"] + prog["compile_s"]
 
-        monitoring.register_event_duration_secs_listener(on_event)
+    @classmethod
+    def _publish(cls, prog: Dict[str, Any], cache: str) -> None:
+        """A program's record: into the table and, as span
+        ``exec.ready``, into the ring."""
+        parent, job = prog["at"]
+        ev = {"ns": int(prog["start"] * 1e9), "kind": "exec",
+              "what": "ready", "thread": threading.get_ident(),
+              "dur_ns": int((prog["end"] - prog["start"]) * 1e9),
+              "id": TimeLine.new_id(), "parent": parent, "job": job,
+              "fun": prog["fun"], "trace_s": prog["trace_s"],
+              "lower_s": prog["lower_s"], "compile_s": prog["compile_s"],
+              "cache": cache, "retrieve_s": prog["retrieve_s"]}
+        with cls._lock:
+            for name, field in _READY_FIELDS.items():
+                cls._compile_seconds[name] = \
+                    cls._compile_seconds.get(name, 0.0) + ev[field]
+            cls._programs.append(ev)
+        TimeLine.add(ev)
 
     @classmethod
     def xla_compiles(cls) -> int:
@@ -207,12 +328,35 @@ class DispatchStats:
 
     @classmethod
     def compile_seconds(cls) -> Dict[str, float]:
-        """Seconds this process spent getting programs ready, summed per
-        jax monitoring event since ``install_xla_listener``: tracing,
+        """Seconds this process spent getting programs ready since
+        ``install_xla_listener``, by jax monitoring event: tracing,
         lowering to MLIR, the backend compile (which holds the
-        persistent-cache lookup) and, of that, the cache retrievals."""
+        persistent-cache lookup) and, of that, the cache retrievals.
+        A second inside a nested event counts once.  The first three
+        are ``programs()``' seconds, summed."""
         with cls._lock:
             return dict(cls._compile_seconds)
+
+    @classmethod
+    def programs(cls) -> List[Dict[str, Any]]:
+        """One record per program made ready since
+        ``install_xla_listener`` (the newest ``MAX_PROGRAMS``), oldest
+        first: ``fun`` (JAX's name, ``jit(f)``), ``trace_s`` (the
+        outermost trace: jitted calls traced into it are not added
+        again), ``lower_s``, ``compile_s`` (the backend compile, a cache
+        retrieval inside it); ``cache``: "hit" (loaded from the
+        persistent compile cache, ``retrieve_s`` of it), "compiled"
+        (compiled and written to that cache: the next process loads
+        it), "uncached" (compiled and not kept: no cache, or a compile
+        quicker than ``jax_persistent_cache_min_compile_time_secs``, so
+        every process compiles it again) or "traced" (no backend compile
+        followed: an ``eval_shape``, a ``lower()`` alone); ``ns`` and
+        ``dur_ns`` (the first event's start to the last one's end, on
+        the ring's clock); ``parent`` and ``job`` (the ``TimeLine`` span
+        open on the thread that made it).  The same dicts are the
+        ring's ``exec.ready`` spans."""
+        with cls._lock:
+            return [dict(p) for p in cls._programs]
 
     @classmethod
     def snapshot(cls) -> Dict[str, Any]:
@@ -234,13 +378,14 @@ class DispatchStats:
                     "stats_pack": statpack.stats(),
                     "xla_compiles": cls._xla_compiles,
                     "compile_seconds": dict(cls._compile_seconds),
+                    "programs": [dict(p) for p in cls._programs],
                     "xla_listener": cls._listener_installed}
 
     @classmethod
     def reset(cls) -> None:
-        """Zero the per-phase counters (the global xla_compiles counter
-        and compile_seconds keep running — monotone process-lifetime
-        totals)."""
+        """Zero the per-phase counters (the global xla_compiles counter,
+        compile_seconds and programs keep running — monotone
+        process-lifetime totals)."""
         with cls._lock:
             cls._compiles.clear()
             cls._dispatches.clear()
@@ -260,7 +405,9 @@ class TimeLine:
     SPANS (``span``), which add ``dur_ns``, ``id``, ``parent`` and
     ``job``.  A span's duration is HOST time between entering and
     leaving the ``with`` block; device time comes from the ``h2o.*``
-    named scopes in a profile, never from a sync added to a span."""
+    named scopes in a profile, never from a sync added to a span.
+    ``exec.ready`` spans (``DispatchStats.programs``) come from JAX's
+    own clock and are in no profile."""
 
     _events: deque = deque(maxlen=MAX_EVENTS)
     _lock = threading.Lock()
@@ -269,10 +416,8 @@ class TimeLine:
 
     @classmethod
     def record(cls, kind: str, what: str, **info) -> None:
-        ev = {"ns": time.time_ns(), "kind": kind, "what": what,
-              "thread": threading.get_ident(), **info}
-        with cls._lock:
-            cls._events.append(ev)
+        cls.add({"ns": time.time_ns(), "kind": kind, "what": what,
+                 "thread": threading.get_ident(), **info})
 
     @classmethod
     @contextlib.contextmanager
@@ -309,8 +454,25 @@ class TimeLine:
         finally:
             ev["dur_ns"] = time.perf_counter_ns() - t0
             stack.pop()
-            with cls._lock:
-                cls._events.append(ev)
+            cls.add(ev)
+
+    @classmethod
+    def open_span(cls) -> tuple:
+        """``(id, job)`` of the innermost span open on this thread, or
+        ``(None, None)``."""
+        stack = getattr(cls._open, "stack", None)
+        return stack[-1] if stack else (None, None)
+
+    @classmethod
+    def new_id(cls) -> int:
+        return next(cls._ids)
+
+    @classmethod
+    def add(cls, ev: Dict[str, Any]) -> None:
+        """One event into the ring: a point event, a closed span, or a
+        span assembled from JAX's own events (``exec.ready``)."""
+        with cls._lock:
+            cls._events.append(ev)
 
     @classmethod
     def snapshot(cls) -> List[Dict[str, Any]]:

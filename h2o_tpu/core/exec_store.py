@@ -286,49 +286,47 @@ class ExecStore:
             if fn is not None:
                 self._insert(k, fn, aot=True)
                 return fn
-        with TimeLine.span("exec", "compile", phase=phase):
-            # build outside the lock: tracing can be slow and may itself
-            # dispatch; a rare concurrent double-build is harmless (last
-            # writer wins, both executables are correct)
-            import jax
-            jkw = dict(jit_kwargs or {})
-            if dn:
-                if donate_argnums:
-                    jkw.setdefault("donate_argnums", tuple(donate_argnums))
-                if donate_argnames:
-                    jkw.setdefault("donate_argnames",
-                                   tuple(donate_argnames))
-            # graftlint: disable=GL603  the store IS the sanctioned jit
-            # point: entries are LRU-bounded, donation-policed, counted
-            fn = jax.jit(build(), **jkw)
-            if args is not None:
-                try:
-                    lowered = fn.lower(*args, **(kwargs or {}))
-                    compiled = lowered.compile()
-                except Exception as e:  # noqa: BLE001 — AOT is an
-                    # optimisation; the jit wrapper stays correct (and the
-                    # XLA persistent compile cache still warms the backend
-                    # half)
-                    log.debug("AOT lowering failed for %s (%r); keeping "
-                              "the jit-level entry", phase, e)
-                    self._insert(k, fn, aot=False)
-                    self._note_audit_compile(phase, key, args)
-                    DispatchStats.note_compile(phase)
-                    return fn
-                if disk_key is not None:
-                    self._disk_store(disk_key, compiled)
-                if _audit_enabled():
-                    self._record_audit(phase, key, lowered, compiled,
-                                       declared=bool(donate_argnums or
-                                                     donate_argnames),
-                                       resolved=dn, args=args)
-                fn = compiled
-                self._insert(k, fn, aot=True)
-            else:
+        # build outside the lock: tracing can be slow and may itself
+        # dispatch; a rare concurrent double-build is harmless (last
+        # writer wins, both executables are correct)
+        import jax
+        jkw = dict(jit_kwargs or {})
+        if dn:
+            if donate_argnums:
+                jkw.setdefault("donate_argnums", tuple(donate_argnums))
+            if donate_argnames:
+                jkw.setdefault("donate_argnames", tuple(donate_argnames))
+        # graftlint: disable=GL603  the store IS the sanctioned jit
+        # point: entries are LRU-bounded, donation-policed, counted
+        fn = jax.jit(build(), **jkw)
+        if args is not None:
+            try:
+                lowered = fn.lower(*args, **(kwargs or {}))
+                compiled = lowered.compile()
+            except Exception as e:  # noqa: BLE001 — AOT is an
+                # optimisation; the jit wrapper stays correct (and the
+                # XLA persistent compile cache still warms the backend
+                # half)
+                log.debug("AOT lowering failed for %s (%r); keeping "
+                          "the jit-level entry", phase, e)
                 self._insert(k, fn, aot=False)
-            self._note_audit_compile(phase, key, args)
-            DispatchStats.note_compile(phase)
-            return fn
+                self._note_audit_compile(phase, key, args)
+                DispatchStats.note_compile(phase)
+                return fn
+            if disk_key is not None:
+                self._disk_store(disk_key, compiled)
+            if _audit_enabled():
+                self._record_audit(phase, key, lowered, compiled,
+                                   declared=bool(donate_argnums or
+                                                 donate_argnames),
+                                   resolved=dn, args=args)
+            fn = compiled
+            self._insert(k, fn, aot=True)
+        else:
+            self._insert(k, fn, aot=False)
+        self._note_audit_compile(phase, key, args)
+        DispatchStats.note_compile(phase)
+        return fn
 
     # -- graftlint IR-audit hooks (H2O_TPU_AUDIT) ---------------------------
 

@@ -10,6 +10,9 @@
   names ``h2o.bin.*``.
 - ``DispatchStats.compile_seconds()`` keeps the durations jax hands the
   listener; the OOM ladder leaves ``safety.*`` point events.
+- every program JAX makes ready leaves one ``DispatchStats.programs()``
+  record, which is also an ``exec.ready`` span: its seconds are
+  ``compile_seconds()``' addends, its ``parent`` the span that asked.
 """
 
 import re
@@ -22,6 +25,9 @@ import pytest
 
 from h2o_tpu.core.diag import DispatchStats, TimeLine
 from h2o_tpu.core.frame import Frame, Vec, T_CAT
+
+_ADDENDS = ("jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+            "backend_compile_duration")
 
 
 def _spans(kind=None):
@@ -326,3 +332,160 @@ def test_compile_cache_key_holds_the_names_not_the_directory(cl):
     assert re.sub(pattern, "", cloud.__file__) == "h2o_tpu/core/cloud.py"
     # a file outside the checkout keeps its name
     assert re.sub(pattern, "", re.__file__) == re.__file__
+
+
+# ---------------------------------------------------- programs made ready
+
+def _mark():
+    """A point to count from: every record made after it has a larger
+    ``id``; and the summed seconds of ``compile_seconds()``' addends."""
+    DispatchStats.install_xla_listener()
+    return TimeLine.new_id(), _ready_seconds()
+
+
+def _ready_seconds():
+    secs = DispatchStats.compile_seconds()
+    return sum(secs.get(k, 0.0) for k in _ADDENDS)
+
+
+def _made_since(mark):
+    """This thread's records since ``mark``."""
+    return [p for p in DispatchStats.programs() if p["id"] > mark
+            and p["thread"] == threading.get_ident()]
+
+
+def _vec(n):
+    # a device array that no program made
+    return jax.device_put(np.arange(n, dtype=np.float32))
+
+
+def _jit_once():
+    f, x = jax.jit(lambda x: jnp.sin(x) * 1.375 + 0.625), _vec(11)
+    return lambda: f(x).block_until_ready(), ["jit(<lambda>)"]
+
+
+def _called_again():
+    f, x = jax.jit(lambda x: jnp.sin(x) * 1.125 - 0.375), _vec(11)
+    f(x).block_until_ready()
+    return lambda: f(x).block_until_ready(), []
+
+
+def _eager_op():
+    x = jax.device_put(np.ones((7, 13, 19), np.float32))
+    return lambda: (x * 3.0).block_until_ready(), ["jit(multiply)"]
+
+
+def _nested_jit():
+    inner = jax.jit(lambda x: x * 2.75)
+
+    @jax.jit
+    def outer(x):
+        return inner(x).sum() + inner(x + 1.0).sum() + jnp.sum(x)
+    x = _vec(9)
+    return lambda: outer(x).block_until_ready(), ["jit(outer)"]
+
+
+@pytest.mark.parametrize("case", [_jit_once, _called_again, _eager_op,
+                                  _nested_jit],
+                         ids=["jit_once", "called_again", "eager_op",
+                              "nested_jit"])
+def test_each_program_made_ready_leaves_one_record(cl, case):
+    from jax._src import monitoring
+    run, funs = case()
+    traces = []
+
+    def raw(event, duration, **kw):
+        if event.endswith("/jaxpr_trace_duration"):
+            traces.append(duration)
+
+    mark, secs0 = _mark()
+    n0 = DispatchStats.xla_compiles()
+    monitoring.register_event_duration_secs_listener(raw)
+    try:
+        run()
+    finally:
+        monitoring.unregister_event_duration_listener(raw)
+    made = _made_since(mark)
+    assert [p["fun"] for p in made] == funs
+    assert DispatchStats.xla_compiles() - n0 >= len(funs)
+    # the records' seconds are what compile_seconds() grew by
+    assert sum(p["trace_s"] + p["lower_s"] + p["compile_s"]
+               for p in made) == pytest.approx(_ready_seconds() - secs0,
+                                                abs=1e-9)
+    for p in made:
+        assert p["trace_s"] > 0 and p["lower_s"] > 0 and p["compile_s"] > 0
+        assert p["cache"] in ("hit", "compiled", "uncached")
+        assert p["trace_s"] + p["lower_s"] + p["compile_s"] <= \
+            p["dur_ns"] / 1e9 + 1e-6
+    if case is _nested_jit:
+        # the inner traces fired events of their own, which the outer
+        # trace holds: counted once
+        assert len(traces) > 1 and made[0]["trace_s"] < sum(traces)
+    # each record is also a span of the ring
+    ring = {e["id"]: e for e in TimeLine.snapshot()
+            if (e["kind"], e["what"]) == ("exec", "ready")}
+    for p in made:
+        assert ring[p["id"]] == p
+
+
+def test_a_program_records_the_span_that_asked_for_it(cl):
+    f, x = jax.jit(lambda x: jnp.cos(x) * 0.8125), _vec(13)
+    mark, _ = _mark()
+    with TimeLine.span("job", "run", job="job_ready"):
+        with TimeLine.span("t", "ask") as ask:
+            f(x).block_until_ready()
+    rec, = _made_since(mark)
+    assert rec["parent"] == ask["id"] and rec["job"] == "job_ready"
+    assert rec["thread"] == ask["thread"]
+    # on the ring's clock it lies inside the span that asked
+    assert ask["ns"] - 1000 <= rec["ns"]
+    assert rec["ns"] + rec["dur_ns"] <= ask["ns"] + ask["dur_ns"] + 1000
+
+
+def test_persistent_cache_reads_compiled_then_hit(cl, tmp_path):
+    from jax._src import compilation_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    old = {k: getattr(jax.config, k) for k in keys}
+    f, x = (lambda x: jnp.tanh(x) * 1.0625 - 0.25), _vec(17)
+    mark, _ = _mark()
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        compilation_cache.reset_cache()
+        # one call line for both: the cache key holds the caller's
+        # location; clear_caches() is a new process, as far as the
+        # program can tell
+        for _ in range(2):
+            jax.jit(f)(x).block_until_ready()
+            jax.clear_caches()
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    first, again = [p for p in _made_since(mark)
+                    if p["fun"] == "jit(<lambda>)"]
+    assert first["cache"] == "compiled" and first["retrieve_s"] is None
+    assert again["cache"] == "hit"
+    assert 0 < again["retrieve_s"] <= again["compile_s"]
+
+
+def test_gbm_train_records_its_programs_under_the_spans_that_asked(cl, rng):
+    from h2o_tpu.models.tree.gbm import GBM
+    fr = _toy_frame(rng, n=520, c=7)
+    DispatchStats.install_xla_listener()
+    jax.clear_caches()              # the block program is traced again
+    TimeLine.clear()
+    GBM(ntrees=2, max_depth=3, seed=5, score_tree_interval=1).train(
+        y="y", training_frame=fr)
+    spans = _spans()
+    assert not [e for e in spans if (e["kind"], e["what"]) ==
+                ("exec", "compile")]
+    root, = [e for e in spans if (e["kind"], e["what"]) == ("job", "run")]
+    launches = {e["id"] for e in spans
+                if (e["kind"], e["what"]) == ("train", "block.launch")}
+    blocks = [e for e in spans if (e["kind"], e["what"]) == ("exec", "ready")
+              and e["fun"] == "jit(_train_forest_impl)"]
+    assert blocks
+    assert {e["parent"] for e in blocks} <= launches
+    assert {e["job"] for e in blocks} == {root["job"]}
