@@ -571,12 +571,13 @@ def _hist_fp() -> str:
 
 def _route_gather_impl(bins, lf, col, bitset, na_left, do_split, thr,
                        cat_choice, *, L, Bd):
-    """The engine's per-level GATHER router on the adaptive path — the
-    reference the matmul router must match bitwise."""
-    from h2o_tpu.models.tree.jit_engine import _gather_route_level
+    """The engine's per-level router on the adaptive path, in the form
+    the level's shape picks — the reference the matmul router must
+    match bitwise."""
+    from h2o_tpu.models.tree.jit_engine import _route_level
     s = {"col": col, "bitset": bitset > 0.5, "na_left": na_left > 0.5}
-    go, do = _gather_route_level(bins, lf, s, do_split, Bd, cat_choice,
-                                 True, thr, Bd)
+    go, do = _route_level(bins, lf, s, do_split, Bd, cat_choice,
+                          True, thr, Bd)
     return jnp.stack([go, do], axis=1).astype(jnp.float32)
 
 
@@ -625,7 +626,7 @@ def _mm_run(v: str, w: dict):
 def _mm_fp() -> str:
     from h2o_tpu.models.tree import jit_engine as je
     return ",".join(code_fingerprint(f) for f in (
-        je._mm_route_level, je._mm_pick, je._gather_route_level))
+        je._mm_route_level, je._mm_pick, je._route_level))
 
 
 def _sib_on_impl(bins, slot, stats_, parent, *, L, B):

@@ -274,7 +274,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     uninterrupted forest bit-for-bit.
     """
     from h2o_tpu.models.tree.jit_engine import (resolve_train_levers,
-                                                train_forest)
+                                                route_plan, train_forest)
     from h2o_tpu.models.tree.shared_tree import (rng_key_from_np,
                                                  rng_key_to_np)
 
@@ -399,6 +399,9 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         may_stop or recovery is not None or scorer is not None) else None
     launched = done
     no_donate = False       # latched by the OOM ladder: retries re-read F
+    # a tree's routed levels, and those the select form routes: the rule
+    # the engine applies to each level's static shape, on the host
+    route_levels, route_select_levels = route_plan(train_kwargs)
 
     def _launch(off: int, n: int) -> Dict:
         nonlocal F, block, no_donate
@@ -439,7 +442,9 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
             nonlocal no_donate
             no_donate = True
 
-        with TimeLine.span("train", "block.launch", t0=prior_trees + off):
+        with TimeLine.span("train", "block.launch", t0=prior_trees + off,
+                           route_levels=route_levels,
+                           route_select_levels=route_select_levels):
             tf = oom_ladder("tree.block", attempt, shrink=shrink,
                             on_oom=on_oom)
             F = tf.f_final

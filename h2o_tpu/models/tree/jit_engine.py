@@ -37,6 +37,19 @@ TWO ENGINES, ONE OUTPUT CONTRACT:
 fits inside ``max_live_leaves`` (2^(D-1) <= cap — the two engines
 build IDENTICAL trees in that regime), frontier beyond.  Depth is
 still sanity-clamped at ``H2O_TPU_MAX_TREE_DEPTH`` (default 30).
+
+ROUTING (``_route_level``, one rule for both engines): each row picks
+its node's split record from the level's L nodes by compare-select-sums
+over the left sets packed into W uint32 words (about L * (W + 1)
+candidates a row, a few passes over the rows), not by per-row gathers,
+which the chip issues one access at a time.  The form is a static
+function of (L, W): select up to ``ROUTE_SELECT_MAX`` = 20,480
+candidates, gather past it.  Read standalone on a v5e chip (PERF.md
+section 6, PR 41): at 128 nodes the select takes 11.1 / 41.6 ms a level
+where the gather takes 146 / 295 ms (5.25M x 28 rows at 256 slots /
+11.5M x 13 at 354); the gather wins from 4,096 nodes at 256 slots and
+2,048 at 354.  So every level of a depth-8 tree selects, and a frontier
+of thousands of nodes gathers.
 """
 
 from __future__ import annotations
@@ -256,25 +269,128 @@ def _mm_route_level(bins, lf, s, do_split, L: int, Bd: int, cat_choice,
     return go_left, do_lf
 
 
-def _gather_route_level(bins, lf, s, do_split, Bd: int, cat_choice=None,
-                        adaptive: bool = False, thr_leaf=None, F: int = -1):
-    """A level's routing from the level's split record, gather form —
-    the ONE statement of the rule during growth (both engines, uplift
-    and the tuner's probe call it; ``_mm_route_level`` is its bitwise
-    twin): returns (go_left, do_split[lf]).  A row takes its leaf's
-    split column's bin; a bitset split looks the bin up in the leaf's
-    left set (slot ``Bd`` = NA), an adaptive numeric split compares it
-    with the leaf's fine-bin threshold and sends bin ``F`` (NA) by the
-    leaf's ``na_left``."""
-    c = s["col"][lf]
-    b = pick_bin(bins, c)
+# The routing forms' crossover, in candidates a row: a level of L nodes
+# whose left sets pack into W 32-bit words costs the select form about
+# L * (W + 1) compare-selects a row, the gather form a fixed number of
+# per-row accesses whatever L is.  One level standalone on a v5e chip,
+# int32 bins, ms select / gather (PERF.md section 6, PR 41):
+#   5.25M x 28, 256 slots (W 8):  L 128 11.1 / 146; 1024 40.8 / 148;
+#                                 2048 81.1 / 148;  4096 159 / 148
+#   11.5M x 13, 354 slots (W 12): L 128 41.6 / 295; 1024 146 / 303;
+#                                 2048 350 / 303;   4096 693 / 303
+# so the select wins up to about 23,000 candidates at W 12 and 34,000 at
+# W 8; the rule keeps it to 20,480, under both.
+ROUTE_SELECT_MAX = 20480
+
+
+def route_words(n_slots: int) -> int:
+    """32-bit words one node's left set of ``n_slots`` bits packs into."""
+    return -(-int(n_slots) // 32)
+
+
+def route_selects(L: int, n_slots: int) -> bool:
+    """Whether a level of ``L`` nodes with ``n_slots``-bit left sets is
+    routed by the select form (else by per-row gathers): the crossover
+    ``ROUTE_SELECT_MAX`` on ``L * (W + 1)``, a static shape rule."""
+    return L * (route_words(n_slots) + 1) <= ROUTE_SELECT_MAX
+
+
+def route_plan(kw: Dict):
+    """``(levels, select_levels)`` of one tree of ``train_forest(**kw)``:
+    the levels growth routes and how many of them take the select form,
+    from the (L, slots) each engine's level hands ``_route_level`` (the
+    matmul router's levels are not the select's), counted on the host
+    for the ``train.block.launch`` span."""
+    D, B = int(kw["max_depth"]), int(kw["nbins"])
+    kleaves = int(kw.get("kleaves") or 0)
+    adaptive = bool(kw.get("adaptive"))
+    F = int(kw.get("fine_nbins") or B)
+    widths = frontier_plan(D, kleaves) if kleaves > 0 else \
+        [2 ** d for d in range(D)]
+    selects = 0
+    for d, L in enumerate(widths):
+        Bd = max(B, F >> d) if adaptive else B
+        mm = bool(kw.get("mm_route")) and Bd < _MM_ROUTE_MAX_TABLE and \
+            (2 * L if kleaves > 0 else L) <= _MM_ROUTE_MAX_TABLE
+        selects += not mm and route_selects(L, Bd + 1)
+    return D, selects
+
+
+def _pick(idx, table):
+    """``table[idx]`` for every row, by a compare-select-sum over the
+    table's entries (``ops/binpack.pick_bin``'s idiom): one fused loop
+    over the rows, no per-row gather.  Exact: one entry matches an
+    index in range (none matches one out of range: 0)."""
+    keys = jnp.arange(table.shape[0], dtype=idx.dtype)
+    return jnp.sum(jnp.where(keys[None, :] == idx[:, None], table[None, :],
+                             0), axis=1, dtype=table.dtype)
+
+
+def _pack_words(bitset):
+    """(L, S) bool left sets -> (L, W) uint32: bit ``j`` of a node's set
+    is bit ``j & 31`` of its word ``j >> 5``."""
+    L, S = bitset.shape
+    W = route_words(S)
+    bits = jnp.pad(bitset, ((0, 0), (0, 32 * W - S))).reshape(L, W, 32)
+    return jnp.sum(bits.astype(jnp.uint32) <<
+                   jnp.arange(32, dtype=jnp.uint32), axis=2,
+                   dtype=jnp.uint32)
+
+
+def _route_level(bins, lf, s, do_split, Bd: int, cat_choice=None,
+                 adaptive: bool = False, thr_leaf=None, F: int = -1):
+    """A level's routing from the level's split record — the ONE
+    statement of the rule during growth (both engines, uplift and the
+    tuner's probe call it; ``_mm_route_level`` is its bitwise twin):
+    returns (go_left, do_split[lf]).  A row takes its node's split
+    column's bin; a bitset split looks the bin up in the node's left set
+    (slot ``Bd`` = NA), an adaptive numeric split compares it with the
+    node's fine-bin threshold and sends bin ``F`` (NA) by the node's
+    ``na_left``.  ``lf`` is each row's node on the level, 0 for a row
+    the caller masks.
+
+    Two forms of one rule, bit for bit the same output on every row.
+    The SELECT form packs each node's left set into W uint32 words and
+    its column, ``do_split``, ``na_left`` and ``cat_choice`` into one
+    int32, then picks a row's record by compare-select-sums over the L
+    nodes (and its word over the L * W words): integer selects with one
+    match, each one loop over the rows.  The GATHER form indexes the
+    (L,) and (L, S) tables per row, which the chip issues one access at
+    a time: 0.05-0.15 s a level at 5.25M rows, 0.10-0.30 s at 11.5M,
+    whatever L is.  The level's static shape picks the form
+    (``route_selects``, under the crossover read on the chip: every
+    level of a depth-8 tree at 256 or 354 slots selects; a frontier of
+    2,048 nodes at 354 slots or 4,096 at 256 gathers)."""
+    L, S = s["bitset"].shape
+    if not route_selects(L, S):
+        c = s["col"][lf]
+        b = pick_bin(bins, c)
+        if adaptive:
+            gset = s["bitset"][lf, jnp.minimum(b, Bd)]
+            gthr = jnp.where(b == F, s["na_left"][lf], b < thr_leaf[lf])
+            go_left = jnp.where(cat_choice[lf], gset, gthr)
+        else:
+            go_left = s["bitset"][lf, b]
+        return go_left, do_split[lf]
+    col = s["col"].astype(jnp.int32)
+    flags = do_split.astype(jnp.int32)
     if adaptive:
-        gset = s["bitset"][lf, jnp.minimum(b, Bd)]
-        gthr = jnp.where(b == F, s["na_left"][lf], b < thr_leaf[lf])
-        go_left = jnp.where(cat_choice[lf], gset, gthr)
+        flags = flags + 2 * cat_choice.astype(jnp.int32) + \
+            4 * s["na_left"].astype(jnp.int32)
+    rec = _pick(lf, col * 8 + flags)
+    b = pick_bin(bins, rec >> 3)
+    # the gather clamps an index past the set's last slot to it
+    bb = jnp.minimum(b.astype(jnp.int32), min(Bd, S - 1) if adaptive
+                     else S - 1)
+    W = route_words(S)
+    word = _pick(lf * W + (bb >> 5), _pack_words(s["bitset"]).reshape(-1))
+    gset = ((word >> (bb & 31).astype(jnp.uint32)) & 1) > 0
+    if adaptive:
+        gthr = jnp.where(b == F, (rec & 4) > 0, b < _pick(lf, thr_leaf))
+        go_left = jnp.where((rec & 2) > 0, gset, gthr)
     else:
-        go_left = s["bitset"][lf, b]
-    return go_left, do_split[lf]
+        go_left = gset
+    return go_left, (rec & 1) > 0
 
 
 def _node_val(wg, wh, w, newton: bool, reg_lambda: float = 0.0):
@@ -511,7 +627,7 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
                     bins, lf, s, do_split, L, Bd if adaptive else B,
                     cat_choice, adaptive, thr_leaf, F)
             else:
-                go_left, do_lf = _gather_route_level(
+                go_left, do_lf = _route_level(
                     bins, lf, s, do_split, Bd, cat_choice, adaptive,
                     thr_leaf, F)
             child = 2 * lf + jnp.where(go_left, 0, 1)
@@ -727,7 +843,7 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
                     bins, sl, s, do_split, L, Bd if adaptive else B,
                     cat_choice, adaptive, thr_leaf, F)
             else:
-                go_left, do_sl = _gather_route_level(
+                go_left, do_sl = _route_level(
                     bins, sl, s, do_split, Bd, cat_choice, adaptive,
                     thr_leaf, F)
             cand = 2 * sl + jnp.where(go_left, 0, 1)

@@ -30,8 +30,7 @@ from h2o_tpu.models import metrics as mm
 from h2o_tpu.models.model import DataInfo, Model, ModelBuilder
 from h2o_tpu.core.autotune import hist_bucket
 from h2o_tpu.models.tree import shared_tree as st
-from h2o_tpu.models.tree.jit_engine import (_gather_route_level,
-                                            frontier_plan)
+from h2o_tpu.models.tree.jit_engine import _route_level, frontier_plan
 from h2o_tpu.ops.histogram import histogram_build_traced, pallas_env_enabled
 
 EPS = 1e-6
@@ -226,7 +225,7 @@ def _train_uplift_forest(bins, treat, yv, w, active, key, *, ntrees: int,
                               jnp.arange(L_next, dtype=jnp.int32), -1))
                 act = slot >= 0
                 sl = jnp.maximum(slot, 0)
-                go_left, do_sl = _gather_route_level(bins, sl, s, do, B)
+                go_left, do_sl = _route_level(bins, sl, s, do, B)
                 cand = 2 * sl + jnp.where(go_left, 0, 1)
                 new_slot = jnp.where(act & do_sl, inv[cand], -1)
                 slot = jnp.where(act, new_slot, slot)

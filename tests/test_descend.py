@@ -1,5 +1,5 @@
 """The one walk down a built tree (``ops/descend.py``) and the one
-routing rule of growth (``jit_engine._gather_route_level``).
+routing rule of growth (``jit_engine._route_level``).
 
 - ``value[descend(...)]``, summed over the trees, is the numpy MOJO
   scorer's output for every row, over {dense heap, frontier ``child``
@@ -132,7 +132,7 @@ def test_gather_route_equals_matmul_route(cl, adaptive):
 
     @jax.jit
     def both(bins, lf, s, do_split, thr, cat_choice):
-        return (je._gather_route_level(bins, lf, s, do_split, Bd,
+        return (je._route_level(bins, lf, s, do_split, Bd,
                                        cat_choice, adaptive, thr, Bd),
                 je._mm_route_level(bins, lf, s, do_split, L, Bd,
                                    cat_choice, adaptive, thr, Bd))

@@ -4,7 +4,8 @@
 they grow a tree and return each row's final node (``pos``); the
 trainer's F update is ``value[pos]``, no descent of the tree just grown.
 Held here, for every engine and routing flavour a ``train_forest`` call
-can take:
+can take (the select form of ``_route_level`` at these shapes, its
+gather form with the crossover pinned low, the matmul router):
 
 - the positions growth returns are the nodes ``ops/descend.descend``
   reaches over the arrays the same call stored, ON EVERY ROW: the rows a
@@ -113,6 +114,14 @@ CASES = {
                                    histogram_type="UniformAdaptive",
                                    nbins=20, nbins_top_level=64),
         {"H2O_TPU_MATMUL_ROUTE": "1", "H2O_TPU_MAX_LIVE_LEAVES": "4"}, 0),
+    # the routing crossover pinned low: every level gathers (min_rows
+    # 4.0 keeps these programs apart from the select arm's in jit's cache)
+    "mixed_enum_na_gather_route": (
+        _mixed_frame, lambda: _gbm(nbins_cats=512, min_rows=4.0), {}, 0),
+    "mixed_enum_na_adaptive_gather_route": (
+        _mixed_frame, lambda: _gbm(histogram_type="UniformAdaptive",
+                                   nbins=20, nbins_top_level=64,
+                                   nbins_cats=512, min_rows=4.0), {}, 0),
 }
 
 
@@ -203,6 +212,8 @@ def test_growth_leaves_every_row_where_a_descent_would(
     frame, build, env, rtol = CASES[case]
     for k, v in env.items():
         monkeypatch.setenv(k, v)
+    if case.endswith("_gather_route"):
+        monkeypatch.setattr(je, "ROUTE_SELECT_MAX", 0)
     fr = frame(rng)
     blocks = _recorded_blocks(monkeypatch)
     model = build().train(y="y", training_frame=fr)
@@ -212,6 +223,13 @@ def test_growth_leaves_every_row_where_a_descent_would(
     assert frontier == ("H2O_TPU_MAX_LIVE_LEAVES" in env)
     assert kw0["mm_route"] == ("H2O_TPU_MATMUL_ROUTE" in env)
     assert kw0["adaptive"] == ("adaptive" in case)
+    # which levels the select form routes: all but the matmul router's
+    # (every level of a 32-bin table; none past its 128-bin limit, as the
+    # mixed frame's) and the pinned gather's
+    levels, selects = je.route_plan(kw0)
+    assert levels == kw0["max_depth"]
+    assert selects == (0 if case == "matmul_route" or
+                       case.endswith("_gather_route") else levels)
     active = np.asarray(kw0["active"])
     assert (~active).any() and active.sum() >= ROWS * 0.9
 
