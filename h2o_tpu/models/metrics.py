@@ -72,6 +72,22 @@ def _score_histogram(b, wy, wn, nbins: int):
     return pos, neg
 
 
+_SUM_BLOCK = 1024      # rows of one partial sum (one (8, 128) tile)
+
+
+def _sum_rows(x):
+    """Sum of an (R,) float32 vector as partial sums of ``_SUM_BLOCK``
+    rows, then the sum of the partials.  One reduction over millions of
+    rows adds each lane's rows one after another on the chip, and a term
+    many rows share (a forest of pure leaves: ``-log(EPS)`` on every row
+    it gets wrong) rounds the same way at every add: the sum drifts by
+    1e-5 to 1e-4 of itself at 5M rows.  The barrier keeps the compiler
+    from folding the two reductions back into one."""
+    pad = (-x.shape[0]) % _SUM_BLOCK
+    parts = jnp.sum(jnp.pad(x, (0, pad)).reshape(-1, _SUM_BLOCK), axis=1)
+    return jnp.sum(jax.lax.optimization_barrier(parts))
+
+
 @functools.partial(jax.jit, static_argnames=("nbins",))
 @jax.named_scope("h2o.score.metrics")
 def _binomial_kernel(p, y, w, valid, nbins: int = _NBINS_AUC):
@@ -82,10 +98,10 @@ def _binomial_kernel(p, y, w, valid, nbins: int = _NBINS_AUC):
     wsum = jnp.maximum(jnp.sum(w), EPS)
     # where-form, not y*log(p)+(1-y)*log(1-p): p can round to exactly 0/1
     # in f32 and 0*log(0) would poison the sum with NaN
-    logloss = jnp.sum(-w * jnp.where(y > 0.5,
-                                     jnp.log(jnp.maximum(p, EPS)),
-                                     jnp.log(jnp.maximum(1.0 - p, EPS))))
-    mse = jnp.sum(w * (y - p) ** 2)
+    logloss = _sum_rows(-w * jnp.where(y > 0.5,
+                                       jnp.log(jnp.maximum(p, EPS)),
+                                       jnp.log(jnp.maximum(1.0 - p, EPS))))
+    mse = _sum_rows(w * (y - p) ** 2)
     b = jnp.clip((p * nbins).astype(jnp.int32), 0, nbins - 1)
     pos, neg = _score_histogram(b, w * y, w * (1 - y), nbins)
     ymean = jnp.sum(w * y) / wsum

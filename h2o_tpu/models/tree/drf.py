@@ -40,6 +40,25 @@ def raw_from_votes(F, ntrees: int, dom, threshold: float = 0.5):
     return jnp.concatenate([label[:, None], P], axis=1)
 
 
+def raw_from_oob(O, dom, threshold: float = 0.5):
+    """Carried out-of-bag votes (``TrainedForest.oob``: K vote sums, then
+    the trees a row was out of the bag of) -> raw predictions, each row
+    the mean of the votes of the trees that did not see it."""
+    K = O.shape[1] - 1
+    votes, count = O[:, :K], jnp.maximum(O[:, K:], 1.0)
+    # unanimous votes of 1 mean 1 exactly (the chip's division misses
+    # x / x by up to two ulps, and a wrong vote's log-loss is most
+    # sensitive near 1: ``jit_engine._node_val``)
+    return raw_from_votes(jnp.where(votes == count, 1.0, votes / count), 1,
+                          dom, threshold)
+
+
+def oob_weights(O, w):
+    """``w`` where a row was out of some tree's bag, else 0: a row every
+    tree saw has no out-of-bag prediction and counts in no metric."""
+    return jnp.where(O[:, -1] > 0, w, 0.0)
+
+
 class DRFModel(Model):
     algo = "drf"
 
@@ -159,10 +178,25 @@ class DRF(ModelBuilder):
         ic_np = np.asarray(binned.is_cat)
 
         F_train = None      # the votes the driver carried, on every row
+        # H2O-3 scores a random forest's training frame on the rows each
+        # tree left out of its bag: the tree driver carries those votes (with
+        # no bag, sample_rate 1, every tree sees every row and the
+        # training metrics are those of all the votes)
+        bagged = float(p["sample_rate"]) < 1.0
+        oob0 = None
+        if bagged:
+            # row-sharded as the trainer returns its carries (one program
+            # for the first block and the next)
+            from h2o_tpu.core.cloud import cloud
+            from h2o_tpu.core.landing import reshard_rows
+            rows = cloud().matrix_sharding()
+            F0 = reshard_rows(F0, rows)
+            oob0 = reshard_rows(jnp.zeros((R, K + 1), jnp.float32), rows)
+        O_train = None      # the out-of-bag votes the tree driver carried
 
-        def make_model(sc, bs, vl, ch, n_new, F_final):
-            nonlocal F_train
-            F_train = F_final
+        def make_model(sc, bs, vl, ch, n_new, F_final, oob=None):
+            nonlocal F_train, O_train
+            F_train, O_train = F_final, oob
             if ckpt is not None:
                 sc = np.concatenate([co["split_col"], sc]) if n_new \
                     else np.asarray(co["split_col"])
@@ -238,16 +272,22 @@ class DRF(ModelBuilder):
                 return lambda Fv, ntot: proto.metrics_from_raw(
                     raw_from_votes(Fv, ntot, dom_sc), frame, w=w_sc)
 
+            def oob_metrics(O, _ntot):
+                return proto.metrics_from_raw(raw_from_oob(O, dom_sc),
+                                              train, w=oob_weights(O, w))
+
+            train_metrics = oob_metrics if bagged else metrics_on(train, w)
             if fold_model:
-                # both metrics from the one carried F (``GBM._fit``)
+                # the in-fold metrics from the out-of-bag votes, the
+                # holdout's from every tree's (``GBM._fit``)
                 scorer = IncrementalScorer(
-                    metrics_on(train, w),
+                    train_metrics,
                     holdout_metrics=metrics_on(train, cv.holdout),
-                    holdout_rows=cv.holdout_rows)
+                    holdout_rows=cv.holdout_rows, oob=bagged)
             elif valid is None:
-                # the trainer's carried F holds the raw votes on every
-                # row of this frame: the driver scores each block on it
-                scorer = IncrementalScorer(metrics_on(train))
+                # the trainer carries the out-of-bag votes on every row
+                # of this frame: the tree driver scores each block on them
+                scorer = IncrementalScorer(train_metrics, oob=bagged)
             else:
                 bins_sc, prepared = st.bin_validation_frame(
                     job, valid, di.x, proto.output["domains"], binned)
@@ -255,28 +295,33 @@ class DRF(ModelBuilder):
                 if prior:
                     F_sc = F_sc + st.forest_score_out(bins_sc, co, depth)
                 scorer = IncrementalScorer(
-                    metrics_on(train), bins_sc, F_sc, depth,
+                    train_metrics, bins_sc, F_sc, depth,
                     fine_na=binned.fine, valid_metrics=metrics_on(valid),
-                    prepared=prepared, ntrees=prior)
+                    prepared=prepared, ntrees=prior, oob=bagged)
         job.update(0.05, f"training {int(p['ntrees']) - prior} trees")
         model = run_tree_driver(job, p, train_kwargs, F0, self.rng_key(),
                                 make_model, scorer, kind,
                                 prior_trees=prior,
                                 recovery=getattr(self, "_recovery", None),
-                                data_frame=train)
-        if fold_model:
-            with TimeLine.span("train", "final_metrics",
-                               source="carried_F"):
-                cv.raw = raw_from_votes(
-                    F_train, int(model.output["ntrees_actual"]),
-                    model.output.get("response_domain"))
+                                data_frame=train, oob0=oob0)
+        dom = model.output.get("response_domain")
+        with TimeLine.span("train", "final_metrics",
+                           source="carried_oob" if bagged else "carried_F"):
+            if bagged:
                 model.output["training_metrics"] = model.metrics_from_raw(
-                    cv.raw, train, w=w)
+                    raw_from_oob(O_train, dom), train,
+                    w=oob_weights(O_train, w))
+            else:
+                model.output["training_metrics"] = model.metrics_from_raw(
+                    raw_from_votes(F_train,
+                                   int(model.output["ntrees_actual"]), dom),
+                    train, w=w)
+            if fold_model:
+                cv.raw = raw_from_votes(
+                    F_train, int(model.output["ntrees_actual"]), dom)
                 model.output["validation_metrics"] = \
                     model.metrics_from_raw(cv.raw, train, w=cv.holdout)
-            return model
-        with TimeLine.span("train", "final_metrics", source="rescore"):
-            model.output["training_metrics"] = model.model_metrics(train)
+                return model
             if valid is not None:
                 model.output["validation_metrics"] = \
                     st.final_validation_metrics(model, valid, scorer)

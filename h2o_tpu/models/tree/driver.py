@@ -103,6 +103,10 @@ class IncrementalScorer:
     them like any row) the second metrics are read from the SAME carried
     F under the holdout weights (H2O-3's ``cv_makeFoldValid`` frame):
     nothing is binned, nothing descended, no F kept.
+
+    With ``oob`` (DRF) ``to_metrics`` is handed the block's carried
+    out-of-bag votes (``tf.oob``: vote sums and tree counts) instead of
+    its F, as H2O-3 scores a random forest's training frame.
     """
 
     def __init__(self, to_metrics: Callable, bins=None, F_init=None,
@@ -110,8 +114,9 @@ class IncrementalScorer:
                  valid_metrics: Optional[Callable] = None,
                  prepared=None, ntrees: int = 0,
                  holdout_metrics: Optional[Callable] = None,
-                 holdout_rows: int = 0):
+                 holdout_rows: int = 0, oob: bool = False):
         self.to_metrics = to_metrics
+        self.oob = oob
         self.valid_metrics = valid_metrics
         self.holdout_metrics = holdout_metrics
         self.holdout_rows = holdout_rows
@@ -131,7 +136,9 @@ class IncrementalScorer:
     def source(self) -> str:
         """Where ``score`` finds the stopping frame's F: field of span
         train.block.score."""
-        return "descent" if self.is_validation else "carried_F"
+        if self.is_validation:
+            return "descent"
+        return "carried_oob" if self.oob else "carried_F"
 
     def add(self, sc, bs, vl, ch=None, th=None, na=None) -> None:
         from h2o_tpu.core.cloud import donation_enabled
@@ -154,7 +161,8 @@ class IncrementalScorer:
         """``[(prefix, metrics)]`` of the forest up to and including
         block ``tf``: the training frame's, then the validation frame's
         where there is one."""
-        out = [("training_", self.to_metrics(tf.f_final, ntrees_total))]
+        out = [("training_", self.to_metrics(
+            tf.oob if self.oob else tf.f_final, ntrees_total))]
         if self.holdout_metrics is not None:
             out.append(("validation_",
                         self.holdout_metrics(tf.f_final, ntrees_total)))
@@ -202,9 +210,10 @@ def _fit_rows(arr: np.ndarray, want: int) -> np.ndarray:
 
 _CKPT_LISTS = ("scs", "bss", "vls", "chs", "gns", "nws", "ths", "nas")
 
-# TrainedForest fields pulled to the host per block (child may be None)
+# TrainedForest fields pulled to the host per block (child, frontier may
+# be None)
 _BLOCK_FIELDS = ("split_col", "bitset", "value", "child", "node_gain",
-                 "node_w", "thr_bin", "na_left", "varimp")
+                 "node_w", "thr_bin", "na_left", "varimp", "frontier")
 
 
 def _start_host_pull(tf) -> None:
@@ -238,6 +247,17 @@ def _split_counts(sc, bs, th, na, is_cat) -> Dict[str, int]:
             "na_left_splits": int((na_left & split).sum())}
 
 
+def _frontier_counts(fr: np.ndarray) -> Dict[str, int]:
+    """A pulled block's sparse-frontier counters (``TrainedForest.
+    frontier``), summed over its trees: the children the cap cut to
+    leaves, the children of split nodes above the last level, and the
+    levels at which the cap cut."""
+    tot = fr.reshape(-1, 3).sum(axis=0)
+    return {"frontier_cut": int(tot[0]),
+            "frontier_split_children": int(tot[1]),
+            "frontier_levels": int(tot[2])}
+
+
 def _warn_empty_roots(job, node_w: np.ndarray) -> None:
     """A tree whose ROOT covers no row was grown from an empty histogram
     table: with rows to train on that is a fault of the table's build,
@@ -257,14 +277,16 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                     scorer: Optional[IncrementalScorer],
                     kind: str, prior_trees: int = 0,
                     t_start: float = None, recovery=None,
-                    data_frame=None) -> object:
+                    data_frame=None, oob0=None) -> object:
     """Train ``p['ntrees']`` total trees (``prior_trees`` of which already
     exist on a checkpoint), scoring every ``score_tree_interval`` trees when
     early stopping / periodic scoring / a runtime budget is requested.
 
     make_model(sc, bs, vl, ch, n_new, F_final) -> Model; arrays are the
     NEW trees only (the builder prepends checkpoint trees itself); ch is
-    None for dense-heap trees.
+    None for dense-heap trees.  With ``oob0`` (DRF: (R, K + 1) zeros or
+    a resumed carry) the out-of-bag votes are carried block to block
+    like F, and make_model is also handed the last block's as ``oob=``.
 
     ``recovery`` (core/recovery.py Recovery): when attached, the driver
     runs in blocks regardless of scoring and saves an iteration-level
@@ -283,6 +305,11 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     # the same (possibly autotuner-probed) executable, and a probe only
     # ever runs before the first block, never mid-forest
     train_kwargs = resolve_train_levers(dict(train_kwargs))
+    if train_kwargs.get("kleaves"):
+        # the frontier's split search sorts no bins when no column is
+        # categorical: decided here, on the host, once for the forest
+        train_kwargs["numeric_only"] = not np.asarray(
+            train_kwargs["is_cat"], bool).any()
     # surface the resolved stats carrier on the job (clients see which
     # numeric contract — f32 reference vs quantized int — trained the
     # forest, same visibility rule as effective_max_depth)
@@ -302,6 +329,12 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
             for v in data_frame.vecs:
                 if v._data is not None:
                     mm.demote(v)
+
+    def oob_kw(carry):
+        return {} if carry is None else {"oob0": carry}
+
+    def oob_out(carry):
+        return {} if carry is None else {"oob": carry}
 
     ntrees = int(p["ntrees"]) - prior_trees
     if prior_trees and ntrees <= 0:
@@ -331,12 +364,13 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         tf = oom_ladder(
             "tree.block",
             lambda: train_forest(F0=F0, key=key, ntrees=max(ntrees, 0),
-                                 t0=prior_trees, **train_kwargs))
+                                 t0=prior_trees, **oob_kw(oob0),
+                                 **train_kwargs))
         model = make_model(np.asarray(tf.split_col), np.asarray(tf.bitset),
                            np.asarray(tf.value),
                            np.asarray(tf.child)
                            if tf.child is not None else None,
-                           max(ntrees, 0), tf.f_final)
+                           max(ntrees, 0), tf.f_final, **oob_out(tf.oob))
         model.output["scoring_history"] = []
         prior_vi = model.output.get("varimp")
         vi = np.asarray(tf.varimp)
@@ -359,7 +393,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     scs, bss, vls, chs = (lists[n] for n in ("scs", "bss", "vls", "chs"))
     gns, nws, ths, nas = (lists[n] for n in ("gns", "nws", "ths", "nas"))
     vi_total = None
-    F = F0
+    F, OOB = F0, oob0
     done = 0
     if recovery is not None:
         st = recovery.load_iteration()
@@ -371,6 +405,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                 st.get("block") == block:
             done = int(st["done"])
             F = jnp.asarray(_fit_rows(st["F"], int(F0.shape[0])))
+            if OOB is not None and st.get("oob") is not None:
+                OOB = jnp.asarray(_fit_rows(st["oob"], int(F0.shape[0])))
             key = rng_key_from_np(st["key"])
             for n in _CKPT_LISTS:
                 lists[n].extend(st["lists"][n])
@@ -404,7 +440,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     route_levels, route_select_levels = route_plan(train_kwargs)
 
     def _launch(off: int, n: int) -> Dict:
-        nonlocal F, block, no_donate
+        nonlocal F, OOB, block, no_donate
         # Slice-loss choke point: a lost/preempted slice surfaces HERE,
         # at the block dispatch, as a RESUMABLE interrupt — every
         # already-absorbed block is durably checkpointed, the job layer
@@ -417,7 +453,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         # master key (jit_engine), so every block receives the SAME
         # master key and any partition — including an OOM-degraded
         # halving below — reproduces the identical forest bitwise.
-        F_in = F
+        F_in, OOB_in = F, OOB
         state = {"n": n}
 
         def attempt():
@@ -425,7 +461,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                                 t0=prior_trees + off,
                                 donate=False if no_donate
                                 else donate_launch,
-                                **train_kwargs)
+                                **oob_kw(OOB_in), **train_kwargs)
 
         def shrink() -> bool:
             # OOM-ladder rung (b): halve the block; the smaller quantum
@@ -447,7 +483,7 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                            route_select_levels=route_select_levels):
             tf = oom_ladder("tree.block", attempt, shrink=shrink,
                             on_oom=on_oom)
-            F = tf.f_final
+            F, OOB = tf.f_final, tf.oob
             _start_host_pull(tf)
         TimeLine.record("dispatch", "tree_block_launch",
                         t0=prior_trees + off, n=state["n"])
@@ -481,6 +517,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                 vi = np.asarray(tf.varimp)
                 pulled.update(_split_counts(scs[-1], bss[-1], ths[-1],
                                             nas[-1], is_cat_host))
+                if tf.frontier is not None:
+                    pulled.update(_frontier_counts(np.asarray(tf.frontier)))
             _warn_empty_roots(job, nws[-1])
             TimeLine.record("dispatch", "tree_block_materialize",
                             t0=prior_trees + cur["off"], n=n)
@@ -520,6 +558,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                         {"kind": "tree", "prior_trees": prior_trees,
                          "ntrees_target": ntrees, "block": block,
                          "done": done, "F": np.asarray(tf.f_final),
+                         "oob": np.asarray(tf.oob)
+                         if tf.oob is not None else None,
                          "key": rng_key_to_np(cur["key_after"]),
                          "lists": lists, "vi_total": vi_total, "sk": sk,
                          # a training-frame scorer's F is "F" above
@@ -569,12 +609,13 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
                 # discard the speculative block: its trees are not part
                 # of the model; roll the carry back to the last kept
                 # block (valid — speculative launches never donate F0)
-                F = tf.f_final
+                F, OOB = tf.f_final, tf.oob
                 pend = None
             break
     model = make_model(np.concatenate(scs), np.concatenate(bss),
                        np.concatenate(vls),
-                       np.concatenate(chs) if chs else None, done, F)
+                       np.concatenate(chs) if chs else None, done, F,
+                       **oob_out(OOB))
     model.output["scoring_history"] = sk.events
     _set_node_array(model, "node_gain", np.concatenate(gns))
     _set_node_array(model, "node_w", np.concatenate(nws))

@@ -31,7 +31,12 @@ TWO ENGINES, ONE OUTPUT CONTRACT:
   sparse CompressedTree (hex/tree/DTree.java:891-935 compress():
   cost scales with actual leaves, not 2^depth): histograms are
   (K_live, C, B+1, 4) however deep the tree goes, so stock DRF's
-  default max_depth=20 trains unclamped with bounded memory.
+  default max_depth=20 trains unclamped with bounded memory.  Its deep
+  levels (64 nodes and more) build the histogram by node windows over
+  rows sorted by node (``ops/histogram.histogram_window_traced``: the
+  cost does not grow with the frontier's width), and the levels from
+  ``frontier_loop_start`` on run as ONE loop body at the cap's width,
+  so a depth-20 tree compiles as about seven levels.
 
 ``train_forest`` picks the engine statically: dense when every level
 fits inside ``max_live_leaves`` (2^(D-1) <= cap — the two engines
@@ -60,10 +65,11 @@ import jax
 import jax.numpy as jnp
 
 from h2o_tpu.models.distributions import get_distribution
-from h2o_tpu.models.tree.shared_tree import find_splits
+from h2o_tpu.models.tree.shared_tree import find_splits, node_sq_err
 from h2o_tpu.ops import statpack
 from h2o_tpu.ops.binpack import pick_bin
 from h2o_tpu.ops.histogram import histogram_build_traced as _shard_histogram
+from h2o_tpu.ops.histogram import histogram_window_traced, window_level
 
 EPS = 1e-10
 
@@ -307,6 +313,10 @@ def route_plan(kw: Dict):
     F = int(kw.get("fine_nbins") or B)
     widths = frontier_plan(D, kleaves) if kleaves > 0 else \
         [2 ** d for d in range(D)]
+    if kleaves > 0:
+        # the frontier engine's looped levels run at the cap's width
+        d0 = frontier_loop_start(D, kleaves, B, F, adaptive)
+        widths = widths[:d0] + [kleaves] * (D - d0)
     selects = 0
     for d, L in enumerate(widths):
         Bd = max(B, F >> d) if adaptive else B
@@ -338,7 +348,8 @@ def _pack_words(bitset):
 
 
 def _route_level(bins, lf, s, do_split, Bd: int, cat_choice=None,
-                 adaptive: bool = False, thr_leaf=None, F: int = -1):
+                 adaptive: bool = False, thr_leaf=None, F: int = -1,
+                 carry=None):
     """A level's routing from the level's split record — the ONE
     statement of the rule during growth (both engines, uplift and the
     tuner's probe call it; ``_mm_route_level`` is its bitwise twin):
@@ -347,31 +358,54 @@ def _route_level(bins, lf, s, do_split, Bd: int, cat_choice=None,
     (slot ``Bd`` = NA), an adaptive numeric split compares it with the
     node's fine-bin threshold and sends bin ``F`` (NA) by the node's
     ``na_left``.  ``lf`` is each row's node on the level, 0 for a row
-    the caller masks.
+    the caller masks.  ``carry`` ((L, k) int32) are further values each
+    row takes from its node, returned third (the frontier engine's next
+    slots of a node's two children).
 
     Two forms of one rule, bit for bit the same output on every row.
-    The SELECT form packs each node's left set into W uint32 words and
-    its column, ``do_split``, ``na_left`` and ``cat_choice`` into one
-    int32, then picks a row's record by compare-select-sums over the L
-    nodes (and its word over the L * W words): integer selects with one
-    match, each one loop over the rows.  The GATHER form indexes the
-    (L,) and (L, S) tables per row, which the chip issues one access at
-    a time: 0.05-0.15 s a level at 5.25M rows, 0.10-0.30 s at 11.5M,
-    whatever L is.  The level's static shape picks the form
+    Both pack each node's column, ``do_split``, ``na_left`` and
+    ``cat_choice`` into one int32 record and its left set into W uint32
+    words.  The SELECT form picks a row's record by compare-select-sums
+    over the L nodes (and its word over the L * W words): integer
+    selects with one match, each one loop over the rows.  The GATHER
+    form takes a row's whole node (record, threshold, words, carry) by
+    ONE row gather of an (L, 2 + W + k) table, which the chip issues one
+    access a row (the gathers it replaced, one a field, read 0.05-0.15 s
+    a level at 5.25M rows, 0.10-0.30 s at 11.5M: PERF.md §6).  The
+    level's static shape picks the form
     (``route_selects``, under the crossover read on the chip: every
     level of a depth-8 tree at 256 or 354 slots selects; a frontier of
     2,048 nodes at 354 slots or 4,096 at 256 gathers)."""
     L, S = s["bitset"].shape
+    W = route_words(S)
     if not route_selects(L, S):
-        c = s["col"][lf]
-        b = pick_bin(bins, c)
+        col = s["col"].astype(jnp.int32)
+        flags = do_split.astype(jnp.int32)
         if adaptive:
-            gset = s["bitset"][lf, jnp.minimum(b, Bd)]
-            gthr = jnp.where(b == F, s["na_left"][lf], b < thr_leaf[lf])
-            go_left = jnp.where(cat_choice[lf], gset, gthr)
+            flags = flags + 2 * cat_choice.astype(jnp.int32) + \
+                4 * s["na_left"].astype(jnp.int32)
+        parts = [(col * 8 + flags)[:, None],
+                 (thr_leaf if adaptive else col).astype(jnp.int32)[:, None],
+                 jax.lax.bitcast_convert_type(_pack_words(s["bitset"]),
+                                              jnp.int32)]
+        if carry is not None:
+            parts.append(carry.astype(jnp.int32))
+        row = jnp.concatenate(parts, axis=1)[lf]         # one row gather
+        rec = row[:, 0]
+        b = pick_bin(bins, rec >> 3)
+        bb = jnp.minimum(b.astype(jnp.int32), min(Bd, S - 1) if adaptive
+                         else S - 1)
+        words = jax.lax.bitcast_convert_type(row[:, 2:2 + W], jnp.uint32)
+        word = jnp.sum(jnp.where(jnp.arange(W)[None, :] == (bb >> 5)[:, None],
+                                 words, 0), axis=1, dtype=jnp.uint32)
+        gset = ((word >> (bb & 31).astype(jnp.uint32)) & 1) > 0
+        if adaptive:
+            gthr = jnp.where(b == F, (rec & 4) > 0, b < row[:, 1])
+            go_left = jnp.where((rec & 2) > 0, gset, gthr)
         else:
-            go_left = s["bitset"][lf, b]
-        return go_left, do_split[lf]
+            go_left = gset
+        out = (go_left, (rec & 1) > 0)
+        return out if carry is None else out + (row[:, 2 + W:],)
     col = s["col"].astype(jnp.int32)
     flags = do_split.astype(jnp.int32)
     if adaptive:
@@ -382,7 +416,6 @@ def _route_level(bins, lf, s, do_split, Bd: int, cat_choice=None,
     # the gather clamps an index past the set's last slot to it
     bb = jnp.minimum(b.astype(jnp.int32), min(Bd, S - 1) if adaptive
                      else S - 1)
-    W = route_words(S)
     word = _pick(lf * W + (bb >> 5), _pack_words(s["bitset"]).reshape(-1))
     gset = ((word >> (bb & 31).astype(jnp.uint32)) & 1) > 0
     if adaptive:
@@ -390,13 +423,83 @@ def _route_level(bins, lf, s, do_split, Bd: int, cat_choice=None,
         go_left = jnp.where((rec & 2) > 0, gset, gthr)
     else:
         go_left = gset
-    return go_left, (rec & 1) > 0
+    out = (go_left, (rec & 1) > 0)
+    if carry is None:
+        return out
+    return out + (jnp.stack([_pick(lf, carry[:, j])
+                             for j in range(carry.shape[1])], axis=1),)
+
+
+# Counter-based draws (DRF).  Tree t's row bag and each node's mtries
+# columns are a pure function of integers, so a plain reference can
+# restate them bit for bit and any block partition draws the same:
+#
+#   mix(x)   = lowbias32: x ^= x >> 16; x *= 0x7feb352d; x ^= x >> 15;
+#              x *= 0x846ca68b; x ^= x >> 16          (uint32, wrapping)
+#   hash(w1, ..., wn) = h_n, h_0 = mix(k0), h_1 = mix(h_0 ^ k1),
+#              h_{i+1} = mix(h_i ^ w_i)               (k0, k1: the master
+#              key's two words, (0, seed mod 2**32) for a builder's seed)
+#   bag:     row r is in tree t's bag iff hash(1, t, r) >> 8 <
+#            floor(sample_rate * 2**24)
+#   mtries:  column c is allowed at the node in slot s of level d iff
+#            fewer than k columns j have v_j < v_c, or v_j == v_c and
+#            j < c, where v_j = hash(2, t, d, s, j) >> 8
+#
+# t is the absolute tree index, r the row's index in the frame, s the
+# node's frontier slot (a dense level's node index).
+BAG_STREAM, MTRIES_STREAM = 1, 2
+
+
+def _mix32(x):
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(0x846CA68B)
+    return x ^ (x >> 16)
+
+
+def counter_hash(seed_words, *words):
+    """``hash(words)`` of the comment above, broadcast over the words."""
+    h = _mix32(seed_words[1] ^ _mix32(seed_words[0]))
+    for w in words:
+        h = _mix32(h ^ jnp.asarray(w).astype(jnp.uint32))
+    return h
+
+
+def seed_words(key):
+    """The master key's two uint32 words (a typed key or a raw one)."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    return key.reshape(-1)[-2:].astype(jnp.uint32)
+
+
+def counter_bag(words, t, R: int, sample_rate: float):
+    """(R,) bool: tree ``t``'s row bag."""
+    cut = jnp.uint32(int(sample_rate * (1 << 24)))
+    rows = jnp.arange(R, dtype=jnp.uint32)
+    return (counter_hash(words, BAG_STREAM, t, rows) >> 8) < cut
+
+
+def counter_mtries(words, t, d: int, L: int, C: int, k: int):
+    """(L, C) bool: the mtries columns of each node of level ``d``."""
+    slot = jnp.arange(L, dtype=jnp.uint32)[:, None]
+    col = jnp.arange(C, dtype=jnp.uint32)[None, :]
+    v = counter_hash(words, MTRIES_STREAM, t, d, slot, col) >> 8  # (L, C)
+    before = (v[:, None, :] < v[:, :, None]) | (
+        (v[:, None, :] == v[:, :, None]) &
+        (jnp.arange(C)[None, None, :] < jnp.arange(C)[None, :, None]))
+    return jnp.sum(before, axis=2) < k
 
 
 def _node_val(wg, wh, w, newton: bool, reg_lambda: float = 0.0):
-    denom = jnp.maximum(wh + reg_lambda, EPS) if newton \
-        else jnp.maximum(w, EPS)
-    return wg / denom
+    if newton:
+        return wg / jnp.maximum(wh + reg_lambda, EPS)
+    # the node's mean response.  x / x is 1 exactly, which the chip's
+    # division (a reciprocal, then a product) misses by up to two ulps: a
+    # pure node of a random forest would vote 1 - 2**-23, and the votes'
+    # out-of-bag mean would round near 1, where a wrong vote's log-loss
+    # is most sensitive
+    return jnp.where((wg == w) & (w > 0), 1.0, wg / jnp.maximum(w, EPS))
 
 
 def sibling_subtract_enabled() -> bool:
@@ -442,8 +545,23 @@ def _hist_level_with_sibling(bins, slot, stats, L: int, B: int, cfg,
     return jnp.stack([left, right], axis=1).reshape(L, *left.shape[1:])
 
 
+def _level_mtries(key, draws, d: int, L: int, C: int, k_cols: int):
+    """(key, (L, C) allowed columns) of one level: the counter rule where
+    ``draws`` = (seed words, tree index) is given, else uniforms from
+    ``key``."""
+    if k_cols >= C:
+        return key, jnp.ones((L, C), bool)
+    if draws is not None:
+        return key, counter_mtries(draws[0], draws[1], d, L, C, k_cols)
+    key, sub = jax.random.split(key)
+    r = jax.random.uniform(sub, (L, C))
+    kth = jnp.sort(r, axis=1)[:, k_cols - 1][:, None]
+    return key, r <= kth
+
+
 def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
-                      tree_col_mask=None, mono=None, inv_scale=None):
+                      tree_col_mask=None, mono=None, inv_scale=None,
+                      draws=None):
     """Traceable single-tree build.  Returns (split_col, bitset, value,
     varimp, node_gain, node_w, thr, na_left, pos), shapes (H,), (H, B+1),
     (H,), (C,), (H,) x4, (R,) with H = 2^(D+1)-1.
@@ -533,13 +651,7 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
             hist_f = hist if inv_scale is None else \
                 statpack.dequant_table(hist, inv_scale)
         with jax.named_scope("h2o.tree.split"):
-            if k_cols < C:
-                key, sub = jax.random.split(key)
-                r = jax.random.uniform(sub, (L, C))
-                kth = jnp.sort(r, axis=1)[:, k_cols - 1][:, None]
-                col_allowed = r <= kth
-            else:
-                col_allowed = jnp.ones((L, C), bool)
+            key, col_allowed = _level_mtries(key, draws, d, L, C, k_cols)
             if tree_col_mask is not None:
                 col_allowed = col_allowed & tree_col_mask[None, :]
             s = find_splits(hist_f, is_cat, col_allowed,
@@ -643,22 +755,31 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
 
 
 def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
-                        tree_col_mask=None, mono=None, inv_scale=None):
+                        tree_col_mask=None, mono=None, inv_scale=None,
+                        draws=None):
     """Traceable single-tree build with a CAPPED live frontier.
 
     Like ``build_tree_traced`` but the per-level leaf set is bounded by
     cfg["max_live_leaves"]: when a level's split children outnumber the
     cap, the children with the largest residual impurity (wgg − wg²/w,
     the upper bound on any further split's SE reduction) stay live and
-    the rest finalize as leaves.  Below the cap the two builders produce
-    identical trees (the selection is the identity there).
+    the rest finalize as leaves: ``top_k``, a tie to the lower child
+    index, and the kept children take their frontier slots in child
+    order.  Below the cap the two builders produce identical trees (the
+    selection is the identity there).
 
     Nodes live in a pool of ``pool_size(D, cap)`` slots with an explicit
     left-``child`` pointer (right = left+1) — the sparse-CompressedTree
-    analog (reference hex/tree/DTree.java:891-935).  Returns
+    analog (reference hex/tree/DTree.java:891-935); a child the cap cut
+    to a leaf holds ``child`` -2 (every reader takes a negative pointer
+    for a leaf).  Levels of ``histogram.window_level`` width take the
+    window form of the histogram (``histogram_window_traced``: cost
+    bounded by a node window, not by the level's width).  Returns
     (split_col (N,), bitset (N, B+1), value (N,), child (N,),
-    varimp (C,), node_gain (N,), node_w (N,), thr (N,), na_left (N,),
-    pos (R,)).
+    varimp (C,), frontier (3,), node_gain (N,), node_w (N,), thr (N,),
+    na_left (N,), pos (R,)); ``frontier`` counts the children the cap
+    cut, the children of split nodes above the last level, and the
+    levels whose children outnumbered the cap.
 
     ``pos`` is every row's final node as a pool id, as in
     ``build_tree_traced``: all R rows are routed (``slot0`` -1 only
@@ -675,56 +796,73 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
     reg_lambda = cfg.get("reg_lambda", 0.0)
     widths = frontier_plan(D, cap)
     N = 1 + 2 * sum(widths)
-
-    # pool arrays + one trash slot at index N (empty frontier slots write
-    # there; duplicates all carry inert -1/0 payloads)
-    split_col = jnp.full((N + 1,), -1, jnp.int32)
-    bitset = jnp.zeros((N + 1, B + 1), bool)
-    value = jnp.zeros((N + 1,), jnp.float32)
-    child = jnp.full((N + 1,), -1, jnp.int32)
-    node_gain = jnp.zeros((N + 1,), jnp.float32)
-    node_w = jnp.zeros((N + 1,), jnp.float32)  # per-node cover (TreeSHAP)
-    thr_pool = jnp.full((N + 1,), -1, jnp.int32)   # adaptive numeric thr
-    na_pool = jnp.zeros((N + 1,), bool)
-    varimp = jnp.zeros((C,), jnp.float32)
-
-    frontier = jnp.zeros((1,), jnp.int32)          # pool ids of live leaves
-    grown = slot0 >= 0                             # rows the histograms see
-    slot = jnp.zeros(slot0.shape, jnp.int32)       # per-row frontier slot
-    pos = jnp.zeros(slot0.shape, jnp.int32)        # per-row pool id
-    use_mono = bool(cfg.get("use_mono")) and mono is not None
-    lo_b = jnp.full((1,), -jnp.inf, jnp.float32)
-    hi_b = jnp.full((1,), jnp.inf, jnp.float32)
-    base = 1                                       # next free pool slot
-
     adaptive = bool(cfg.get("adaptive", False))
     F = int(cfg.get("fine_nbins") or B)
     random_mode = bool(cfg.get("hist_random", False))
-    if adaptive:
-        rlo, rhi = _adaptive_ranges_init(1, C, F)
-
+    use_mono = bool(cfg.get("use_mono")) and mono is not None
     sib = bool(cfg.get("sibling", True)) and not adaptive
-    prev_hist = prev_do = None
-    for d in range(D):                             # static unroll
-        L = widths[d]
-        Bd = max(B, F >> d) if adaptive else B
+    d0 = frontier_loop_start(D, cap, B, F, adaptive)
+
+    # pool arrays + one trash slot at index N (empty frontier slots write
+    # there; duplicates all carry inert -1/0 payloads), and room past it
+    # for the looped levels' child runs, which are written at the cap's
+    # width (their tails hold the same inert payloads)
+    P = N + 1 + (2 * cap if d0 < D else 0)
+    st = dict(
+        split_col=jnp.full((P,), -1, jnp.int32),
+        bitset=jnp.zeros((P, B + 1), bool),
+        value=jnp.zeros((P,), jnp.float32),
+        child=jnp.full((P,), -1, jnp.int32),
+        node_gain=jnp.zeros((P,), jnp.float32),
+        node_w=jnp.zeros((P,), jnp.float32),     # per-node cover (TreeSHAP)
+        thr_pool=jnp.full((P,), -1, jnp.int32),  # adaptive numeric thr
+        na_pool=jnp.zeros((P,), bool),
+        varimp=jnp.zeros((C,), jnp.float32),
+        frontier=jnp.zeros((1,), jnp.int32),     # pool ids of live leaves
+        slot=jnp.zeros(slot0.shape, jnp.int32),  # per-row frontier slot
+        pos=jnp.zeros(slot0.shape, jnp.int32),   # per-row pool id
+        key=key,
+        cut=jnp.int32(0), split_children=jnp.int32(0),
+        capped=jnp.int32(0))
+    if use_mono:
+        st["lo_b"] = jnp.full((1,), -jnp.inf, jnp.float32)
+        st["hi_b"] = jnp.full((1,), jnp.inf, jnp.float32)
+    if adaptive:
+        st["rlo"], st["rhi"] = _adaptive_ranges_init(1, C, F)
+    grown = slot0 >= 0                             # rows the histograms see
+
+    def level(st, d, L, Ln, Bd, base, last, capped_lvl, sib_prev=None):
+        """One level of ``L`` frontier slots whose children start at pool
+        id ``base``; ``Ln`` slots on the next level.  ``d``, ``base``,
+        ``last`` (the tree's last level) and ``capped_lvl`` (its split
+        children may outnumber the next level) are Python values on an
+        unrolled level and traced ones in the loop."""
+        st = dict(st)
+        frontier, slot = st["frontier"], st["slot"]
         with jax.named_scope("h2o.tree.route"):
             hslot = jnp.where(grown, slot, -1)
+        roff = None
         if adaptive:
             with jax.named_scope("h2o.tree.split"):
-                key, sub = jax.random.split(key)
-                roff = _rand_offsets(sub, L, C, rlo, rhi, random_mode)
+                st["key"], sub = jax.random.split(st["key"])
+                roff = _rand_offsets(sub, L, C, st["rlo"], st["rhi"],
+                                     random_mode)
+        fine_map = (st["rlo"], st["rhi"], roff, is_cat, F) if adaptive \
+            else None
+        if window_level(L):
+            hist = histogram_window_traced(bins, hslot, stats, L, Bd,
+                                           cfg["bf16"], fine_map=fine_map)
+        elif adaptive:
             hist = _shard_histogram(
                 bins, hslot, stats, L, Bd, cfg["block_rows"], cfg["bf16"],
-                fine_map=(rlo, rhi, roff, is_cat, F),
-                pallas=cfg.get("pallas"))
-        elif sib and d >= 1 and L == 2 * widths[d - 1]:
+                fine_map=fine_map, pallas=cfg.get("pallas"))
+        elif sib_prev is not None:
             # uncapped transition: children sit at 2*parent+{0,1} in
             # parent order (identity selection), so the dense sibling
             # subtraction applies verbatim; capped levels (top_k
             # reshuffles slots) fall back to the full histogram
             hist = _hist_level_with_sibling(bins, hslot, stats, L, B, cfg,
-                                            prev_hist, prev_do)
+                                            *sib_prev)
         else:
             hist = _shard_histogram(bins, hslot, stats, L, B,
                                     cfg["block_rows"], cfg["bf16"],
@@ -734,20 +872,16 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
             hist_f = hist if inv_scale is None else \
                 statpack.dequant_table(hist, inv_scale)
         with jax.named_scope("h2o.tree.split"):
-            if k_cols < C:
-                key, sub = jax.random.split(key)
-                r = jax.random.uniform(sub, (L, C))
-                kth = jnp.sort(r, axis=1)[:, k_cols - 1][:, None]
-                col_allowed = r <= kth
-            else:
-                col_allowed = jnp.ones((L, C), bool)
+            st["key"], col_allowed = _level_mtries(st["key"], draws, d, L, C,
+                                                   k_cols)
             if tree_col_mask is not None:
                 col_allowed = col_allowed & tree_col_mask[None, :]
             s = find_splits(hist_f, is_cat, col_allowed,
                             min_rows=cfg["min_rows"],
                             min_split_improvement=cfg["min_split_improvement"],
                             mono=mono, use_mono=use_mono, newton=newton,
-                            reg_lambda=reg_lambda)
+                            reg_lambda=reg_lambda,
+                            natural=bool(cfg.get("numeric_only")))
             live = s["leaf"]["w"] > 0
             do_split = s["do_split"] & live
             term = live & ~do_split
@@ -758,6 +892,7 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
             rvals = _node_val(s["right"]["wg"], s["right"]["wh"],
                               s["right"]["w"], newton, reg_lambda)
             if use_mono:
+                lo_b, hi_b = st["lo_b"], st["hi_b"]
                 leaf_vals = jnp.clip(leaf_vals, lo_b, hi_b)
                 lvals = jnp.clip(lvals, lo_b, hi_b)
                 rvals = jnp.clip(rvals, lo_b, hi_b)
@@ -770,64 +905,82 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
                 lo_c = jnp.stack([l_lo, r_lo], axis=1).reshape(2 * L)
                 hi_c = jnp.stack([l_hi, r_hi], axis=1).reshape(2 * L)
 
-            varimp = varimp.at[s["col"]].add(
+            st["varimp"] = st["varimp"].at[s["col"]].add(
                 jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0))
             # write this level's frontier nodes into the pool (scatter at
             # traced pool ids; trash-slot writes are inert)
             gain_pos = jnp.where(do_split, jnp.maximum(s["gain"], 0.0), 0.0)
             child_ptr = base + 2 * jnp.arange(L, dtype=jnp.int32)
-            split_col = split_col.at[frontier].set(
+            st["split_col"] = st["split_col"].at[frontier].set(
                 jnp.where(do_split, s["col"], -1))
             cat_choice = is_cat[s["col"]]
             if adaptive:
-                thr_leaf = _numeric_thr(s, rlo, rhi, roff, Bd)
+                thr_leaf = _numeric_thr(s, st["rlo"], st["rhi"], roff, Bd)
                 num_split = do_split & ~cat_choice
-                thr_pool = thr_pool.at[frontier].set(
+                st["thr_pool"] = st["thr_pool"].at[frontier].set(
                     jnp.where(num_split, thr_leaf, -1))
-                na_pool = na_pool.at[frontier].set(num_split & s["na_left"])
+                st["na_pool"] = st["na_pool"].at[frontier].set(
+                    num_split & s["na_left"])
                 bset_store = jnp.concatenate(
                     [s["bitset"][:, :B], s["bitset"][:, Bd: Bd + 1]], axis=1)
                 bset_w = bset_store & (do_split & cat_choice)[:, None]
             else:
                 thr_leaf = None
                 bset_w = s["bitset"] & do_split[:, None]
-            bitset = bitset.at[frontier].set(bset_w)
-            value = value.at[frontier].set(jnp.where(term, leaf_vals, 0.0))
-            child = child.at[frontier].set(jnp.where(do_split, child_ptr, -1))
-            node_gain = node_gain.at[frontier].set(gain_pos)
-            node_w = node_w.at[frontier].set(
+            st["bitset"] = st["bitset"].at[frontier].set(bset_w)
+            st["value"] = st["value"].at[frontier].set(
+                jnp.where(term, leaf_vals, 0.0))
+            st["child"] = st["child"].at[frontier].set(
+                jnp.where(do_split, child_ptr, -1))
+            st["node_gain"] = st["node_gain"].at[frontier].set(gain_pos)
+            st["node_w"] = st["node_w"].at[frontier].set(
                 jnp.where(live, s["leaf"]["w"], 0.0))
             # pre-write child values at their (fresh, contiguous) pool slots
             cvals = jnp.stack([lvals, rvals], axis=1).reshape(2 * L)
             cmask = jnp.repeat(do_split, 2)
-            value = jax.lax.dynamic_update_slice(
-                value, jnp.where(cmask, cvals, 0.0), (base,))
+            st["value"] = jax.lax.dynamic_update_slice(
+                st["value"], jnp.where(cmask, cvals, 0.0), (base,))
             cw = jnp.stack([s["left"]["w"], s["right"]["w"]],
                            axis=1).reshape(2 * L)
-            node_w = jax.lax.dynamic_update_slice(
-                node_w, jnp.where(cmask, cw, 0.0), (base,))
+            st["node_w"] = jax.lax.dynamic_update_slice(
+                st["node_w"], jnp.where(cmask, cw, 0.0), (base,))
 
-        if d + 1 < D:
+        if last is not True:
             with jax.named_scope("h2o.tree.split"):
-                L_next = widths[d + 1]
                 # best-first frontier selection: keep the children with the
                 # most residual impurity; the rest are finished leaves
-                se_l = s["left"]["wgg"] - s["left"]["wg"] ** 2 / \
-                    jnp.maximum(s["left"]["w"], EPS)
-                se_r = s["right"]["wgg"] - s["right"]["wg"] ** 2 / \
-                    jnp.maximum(s["right"]["w"], EPS)
+                se_l, se_r = (node_sq_err(s[k]["w"], s[k]["wg"], s[k]["wgg"])
+                              for k in ("left", "right"))
                 cse = jnp.stack([se_l, se_r], axis=1).reshape(2 * L)
                 ckey = jnp.where(cmask, jnp.maximum(cse, 0.0), -jnp.inf)
-                if 2 * L <= L_next:
-                    sel = jnp.arange(2 * L, dtype=jnp.int32)  # identity: dense
+                ident = jnp.arange(Ln, dtype=jnp.int32)   # identity: dense
+                if capped_lvl is False:
+                    sel = ident
                 else:
-                    _, sel = jax.lax.top_k(ckey, L_next)
-                    sel = sel.astype(jnp.int32)
+                    # the kept children take the first slots in child
+                    # order (top_k fills past them with -inf candidates)
+                    kv, top = jax.lax.top_k(ckey, Ln)
+                    top = top.astype(jnp.int32)
+                    top = jnp.sort(jnp.where(kv > -jnp.inf, top,
+                                             top + 2 * L)) % (2 * L)
+                    sel = top if capped_lvl is True else \
+                        jnp.where(capped_lvl, top, ident)
                 sel_valid = jnp.take(ckey, sel) > -jnp.inf
-                frontier = jnp.where(sel_valid, base + sel, N)
+                st["frontier"] = jnp.where(sel_valid, base + sel, N)
                 inv = jnp.full((2 * L,), -1, jnp.int32).at[sel].set(
                     jnp.where(sel_valid,
-                              jnp.arange(L_next, dtype=jnp.int32), -1))
+                              jnp.arange(Ln, dtype=jnp.int32), -1))
+                on = jnp.logical_not(last)
+                if capped_lvl is not False:
+                    # children the cap cut to leaves: child -2, counted
+                    lost = cmask & (inv < 0) & capped_lvl & on
+                    st["child"] = jax.lax.dynamic_update_slice(
+                        st["child"], jnp.where(lost, -2, -1), (base,))
+                    st["cut"] = st["cut"] + jnp.sum(lost, dtype=jnp.int32)
+                    st["capped"] = st["capped"] + \
+                        jnp.any(lost).astype(jnp.int32)
+                st["split_children"] = st["split_children"] + jnp.where(
+                    on, jnp.sum(cmask, dtype=jnp.int32), 0)
         with jax.named_scope("h2o.tree.route"):
             # route EVERY row on the frontier, grown-on or not, the last
             # level included: a split parent's rows follow the split to a
@@ -842,38 +995,89 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
                 go_left, do_sl = _mm_route_level(
                     bins, sl, s, do_split, L, Bd if adaptive else B,
                     cat_choice, adaptive, thr_leaf, F)
-            else:
+            elif last is True:
                 go_left, do_sl = _route_level(
                     bins, sl, s, do_split, Bd, cat_choice, adaptive,
                     thr_leaf, F)
+            else:
+                # each row takes its node's two children's next slots
+                # with the node's record: no per-row lookup of its child
+                go_left, do_sl, nxt = _route_level(
+                    bins, sl, s, do_split, Bd, cat_choice, adaptive,
+                    thr_leaf, F, carry=inv.reshape(L, 2))
             cand = 2 * sl + jnp.where(go_left, 0, 1)
             moved = active & do_sl
-            pos = jnp.where(moved, base + cand, pos)
-            if d + 1 < D:
+            st["pos"] = jnp.where(moved, base + cand, st["pos"])
+            if last is not True:
                 if mm:
                     candhot = cand[:, None] == jnp.arange(2 * L)[None, :]
                     inv_c = _mm_pick(candhot, inv.astype(jnp.float32)[:, None]
                                      )[:, 0].astype(jnp.int32)
                 else:
-                    inv_c = inv[cand]
-                slot = jnp.where(moved, inv_c, -1)
-        if d + 1 < D:
+                    inv_c = jnp.where(go_left, nxt[:, 0], nxt[:, 1])
+                st["slot"] = jnp.where(moved, inv_c, -1)
+        if last is not True:
             with jax.named_scope("h2o.tree.split"):
                 if use_mono:
-                    lo_b = jnp.take(lo_c, sel)
-                    hi_b = jnp.take(hi_c, sel)
+                    st["lo_b"] = jnp.take(lo_c, sel)
+                    st["hi_b"] = jnp.take(hi_c, sel)
                 if adaptive:
-                    new_lo, new_hi = _refine_ranges(hist_f, rlo, rhi, roff,
-                                                    Bd)
+                    new_lo, new_hi = _refine_ranges(hist_f, st["rlo"],
+                                                    st["rhi"], roff, Bd)
                     clo, chi = _child_ranges(new_lo, new_hi, s, thr_leaf,
                                              is_cat, do_split)
-                    rlo = jnp.take(clo, sel, axis=0)
-                    rhi = jnp.take(chi, sel, axis=0)
-        prev_hist, prev_do = hist, do_split
-        base += 2 * L
+                    st["rlo"] = jnp.take(clo, sel, axis=0)
+                    st["rhi"] = jnp.take(chi, sel, axis=0)
+        return st, (hist, do_split)
 
-    return (split_col[:N], bitset[:N], value[:N], child[:N], varimp,
-            node_gain[:N], node_w[:N], thr_pool[:N], na_pool[:N], pos)
+    base, prev = 1, None                           # next free pool slot
+    for d in range(d0):                            # static unroll
+        L = widths[d]
+        Ln = widths[d + 1] if d + 1 < D else 0
+        sib_prev = prev if (sib and d >= 1 and L == 2 * widths[d - 1]) \
+            else None
+        st, prev = level(st, d, L, Ln, max(B, F >> d) if adaptive else B,
+                         base, d + 1 == D, 2 * L > Ln, sib_prev)
+        base += 2 * L
+    if d0 < D:
+        # the levels from d0 on share one shape, the cap's: ONE compiled
+        # body in a loop, each level's arrays padded to the cap (an empty
+        # slot holds no row and splits nothing)
+        def pad(a, fill):
+            return jnp.concatenate([a, jnp.full((cap - a.shape[0],) +
+                                                a.shape[1:], fill, a.dtype)])
+        st["frontier"] = pad(st["frontier"], N)
+        if use_mono:
+            st["lo_b"], st["hi_b"] = pad(st["lo_b"], -jnp.inf), \
+                pad(st["hi_b"], jnp.inf)
+        if adaptive:
+            st["rlo"], st["rhi"] = pad(st["rlo"], 0), pad(st["rhi"], F - 1)
+        st["base"], st["width"] = jnp.int32(base), jnp.int32(widths[d0])
+
+        def body(d, st):
+            b, w = st["base"], st["width"]
+            st, _ = level(st, d, cap, cap, B, b, d == D - 1, 2 * w > cap)
+            st["base"], st["width"] = b + 2 * w, jnp.minimum(2 * w, cap)
+            return st
+
+        st = jax.lax.fori_loop(d0, D, body, st)
+    return (st["split_col"][:N], st["bitset"][:N], st["value"][:N],
+            st["child"][:N], st["varimp"],
+            jnp.stack([st["cut"], st["split_children"], st["capped"]]),
+            st["node_gain"][:N], st["node_w"][:N], st["thr_pool"][:N],
+            st["na_pool"][:N], st["pos"])
+
+
+def frontier_loop_start(depth: int, cap: int, nbins: int, fine: int,
+                        adaptive: bool) -> int:
+    """The first level the sparse-frontier engine runs in its loop at the
+    cap's shape: the first whose width takes the window form of the
+    histogram and whose bucket count is ``nbins`` (an adaptive tree's
+    halving schedule is over); ``depth`` where there is none."""
+    for d, L in enumerate(frontier_plan(depth, cap)):
+        if window_level(L) and (not adaptive or (fine >> d) <= nbins):
+            return d
+    return depth
 
 
 def _hist_bucket(args, kwargs):
@@ -932,6 +1136,12 @@ class TrainedForest(NamedTuple):
     thr_bin: jax.Array     # (T, K, N) adaptive numeric thr (-1 = bitset)
     na_left: jax.Array     # (T, K, N) NA direction for thr splits
     child: object = None   # (T, K, N) left-child pool ptrs; None = dense
+    # (R, K + 1) mode "drf": each row's out-of-bag vote sums and the
+    # number of trees it was out of the bag of, carried like F
+    oob: object = None
+    # (T, K, 3) sparse-frontier engine: children the cap cut, children
+    # of split nodes above the last level, levels the cap cut at
+    frontier: object = None
 
 
 def train_forest(*args, sibling: Optional[bool] = None,
@@ -1013,7 +1223,8 @@ _TF_STATIC = ("dist_name", "K", "ntrees", "max_depth", "nbins",
               "col_sample_rate_per_tree", "use_mono",
               "kleaves", "custom_dist", "sibling",
               "adaptive", "fine_nbins", "hist_random",
-              "hist_pallas", "mm_route", "stats_dtype", "mesh_fp")
+              "hist_pallas", "mm_route", "stats_dtype", "mesh_fp",
+              "numeric_only")
 
 
 def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
@@ -1036,7 +1247,8 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
                  hist_pallas: bool = False,
                  mm_route: bool = False,
                  stats_dtype: str = "f32",
-                 mesh_fp=None) -> TrainedForest:
+                 mesh_fp=None, oob0=None,
+                 numeric_only: bool = False) -> TrainedForest:
     """The WHOLE forest training loop as one XLA program.
 
     ``mesh_fp`` is a STATIC fingerprint of the cloud mesh, unused in the
@@ -1049,7 +1261,10 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
     mode="gbm": boosting — stats from distribution gradients at current F,
     f updated after each iteration, leaf values scaled by learn_rate.
     mode="drf": bagging — stats fixed on the response, no f update (F output
-    accumulates raw votes; caller divides by ntrees).
+    accumulates raw votes; caller divides by ntrees); bags and mtries
+    columns by the counter rule (``counter_bag``, ``counter_mtries``),
+    and with ``oob0`` ((R, K + 1), like F0) each row's out-of-bag vote
+    sums and tree count carried beside F (``TrainedForest.oob``).
     kleaves=0: dense heap engine; >0: sparse-frontier engine with that
     live-leaf cap (module docstring).  ``sibling`` (static; resolved by
     the train_forest wrapper) enables histogram sibling subtraction.
@@ -1058,7 +1273,10 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
     pre-lever reference (no quantization noise is even DRAWN, so the
     program is identical), "int16"/"int8" quantize each tree's stats
     with stochastic rounding (ops/statpack.py) and run the whole level
-    loop on exact int32 tables.
+    loop on exact int32 tables.  ``numeric_only`` (static; the driver
+    sets it from the host's ``is_cat`` for the sparse-frontier engine
+    alone): no column is categorical, so the frontier's split search
+    sorts no bins (``find_splits(natural=True)``).
     """
     cfg = dict(max_depth=max_depth, nbins=nbins, k_cols=k_cols,
                newton=newton, min_rows=min_rows,
@@ -1067,7 +1285,8 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
                use_mono=use_mono, max_live_leaves=kleaves,
                sibling=sibling, adaptive=adaptive,
                fine_nbins=fine_nbins, hist_random=hist_random,
-               pallas=hist_pallas, mm_route=mm_route)
+               pallas=hist_pallas, mm_route=mm_route,
+               numeric_only=numeric_only)
     R = bins.shape[0]
 
     def stats_for(kcls, F):
@@ -1102,8 +1321,15 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
     qmax = (statpack.stats_qmax(R, stats_dtype)
             if stats_dtype != "f32" else 0)
 
-    def tree_step(F, xs):
+    # DRF draws its bags and mtries columns by the counter rule, and
+    # carries each row's out-of-bag votes where the caller hands ``oob0``
+    counter = mode == "drf"
+    words = seed_words(key) if counter else None
+
+    def tree_step(carry, xs):
+        F, oob = (carry, None) if oob0 is None else carry
         t_idx, key_t = xs
+        draws = (words, t_idx.astype(jnp.uint32)) if counter else None
         # the tree's inputs: its keys, row/column samples, and (below)
         # the per-row statistics
         with jax.named_scope("h2o.tree.stats"):
@@ -1116,16 +1342,20 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
                 tree_cols = rc <= kth
             else:
                 tree_cols = None
-            samp = jnp.where(
-                jax.random.uniform(ks, (R,)) < sample_rate, True, False) \
-                if sample_rate < 1.0 else jnp.ones((R,), bool)
+            if sample_rate >= 1.0:
+                samp = jnp.ones((R,), bool)
+            elif counter:
+                samp = counter_bag(words, draws[1], R, sample_rate)
+            else:
+                samp = jnp.where(
+                    jax.random.uniform(ks, (R,)) < sample_rate, True, False)
             leaf0 = jnp.where(samp & active, 0, -1).astype(jnp.int32)
         scale = learn_rate * (learn_rate_annealing ** t_idx) \
             if mode == "gbm" else 1.0
         if mode == "gbm" and dist_name == "multinomial":
             scale = scale * (K - 1) / K
-        scs, bss, vls, chs, preds, vis, gns, nws, ths, nas = \
-            [], [], [], [], [], [], [], [], [], []
+        scs, bss, vls, chs, frs, preds, vis, gns, nws, ths, nas = \
+            [], [], [], [], [], [], [], [], [], [], []
         for kcls in range(K):                    # static unroll over classes
             with jax.named_scope("h2o.tree.stats"):
                 kc, kk = jax.random.split(kc)
@@ -1140,13 +1370,15 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
                 else:
                     inv_sc = None
             if kleaves > 0:
-                sc, bs, vl, ch, vi, gn, nw, th, na, pos = build_tree_frontier(
-                    bins, stats, leaf0, kk, is_cat, cfg, tree_cols,
-                    mono=mono, inv_scale=inv_sc)
+                sc, bs, vl, ch, vi, fr, gn, nw, th, na, pos = \
+                    build_tree_frontier(bins, stats, leaf0, kk, is_cat, cfg,
+                                        tree_cols, mono=mono,
+                                        inv_scale=inv_sc, draws=draws)
+                frs.append(fr)
             else:
                 sc, bs, vl, vi, gn, nw, th, na, pos = build_tree_traced(
                     bins, stats, leaf0, kk, is_cat, cfg, tree_cols,
-                    mono=mono, inv_scale=inv_sc)
+                    mono=mono, inv_scale=inv_sc, draws=draws)
                 ch = None
             with jax.named_scope("h2o.tree.split"):
                 vl = vl * scale
@@ -1165,13 +1397,18 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
                 preds.append(vl[pos])
         with jax.named_scope("h2o.tree.predict"):
             F = F + jnp.stack(preds, axis=1)
+            if oob is not None:
+                # the rows out of this tree's bag take its votes
+                out_bag = (active & ~samp).astype(jnp.float32)[:, None]
+                oob = oob + jnp.concatenate(
+                    [jnp.stack(preds, axis=1) * out_bag, out_bag], axis=1)
         with jax.named_scope("h2o.tree.split"):
             out = (jnp.stack(scs), jnp.stack(bss), jnp.stack(vls),
                    sum(vis), jnp.stack(gns), jnp.stack(nws),
                    jnp.stack(ths), jnp.stack(nas))
             if kleaves > 0:
-                out = out + (jnp.stack(chs),)
-        return F, out
+                out = out + (jnp.stack(chs), jnp.stack(frs))
+        return (F if oob is None else (F, oob)), out
 
     # Per-tree keys fold the ABSOLUTE tree index into the forest master
     # key (not a per-block split): tree t's stream depends only on
@@ -1184,14 +1421,25 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
         ti = jnp.arange(ntrees, dtype=jnp.int32) + jnp.int32(t0)
         keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(ti)
         ts = ti.astype(jnp.float32)
-    F_final, outs = jax.lax.scan(tree_step, F0, (ts, keys))
+    carry, outs = jax.lax.scan(tree_step, F0 if oob0 is None
+                               else (F0, oob0), (ts, keys))
+    F_final, oob = (carry, None) if oob0 is None else carry
+    if oob0 is not None:
+        # the carries leave row-sharded, as the caller hands them in: the
+        # next block's call is then the same program
+        from h2o_tpu.core.cloud import cloud
+        from jax.sharding import NamedSharding
+        rows = NamedSharding(cloud().mesh, cloud().data_pspec(None))
+        F_final = jax.lax.with_sharding_constraint(F_final, rows)
+        oob = jax.lax.with_sharding_constraint(oob, rows)
     if kleaves > 0:
-        sc, bs, vl, vi, gn, nw, th, na, ch = outs
+        sc, bs, vl, vi, gn, nw, th, na, ch, fr = outs
     else:
-        (sc, bs, vl, vi, gn, nw, th, na), ch = outs, None
+        (sc, bs, vl, vi, gn, nw, th, na), ch, fr = outs, None, None
     with jax.named_scope("h2o.tree.split"):
         vi = jnp.sum(vi, axis=0)
-    return TrainedForest(sc, bs, vl, F_final, vi, gn, nw, th, na, ch)
+    return TrainedForest(sc, bs, vl, F_final, vi, gn, nw, th, na, ch, oob,
+                         fr)
 
 
 # The donating/non-donating executable pair over this one traced body

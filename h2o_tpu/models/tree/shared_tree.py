@@ -516,13 +516,22 @@ def rng_key_from_np(data: np.ndarray):
 # split finding
 # ---------------------------------------------------------------------------
 
+def node_sq_err(w, wg, wgg):
+    """A node's squared error about its mean, ``wgg - wg**2 / w``, none
+    at all for a pure node of a 0/1 response (``wg == w``, or ``wg`` 0):
+    the chip's division misses ``wg / w`` = 1 by up to two ulps, and the
+    residue would pass for a split's gain and split a pure node."""
+    return wgg - wg * jnp.where(wg == w, 1.0, wg / jnp.maximum(w, EPS))
+
+
 @functools.partial(jax.jit, static_argnames=("min_rows", "use_mono",
-                                             "newton", "reg_lambda"))
+                                             "newton", "reg_lambda",
+                                             "natural"))
 @jax.named_scope("h2o.tree.split")
 def find_splits(hist, is_cat, col_allowed, min_rows: float = 10.0,
                 min_split_improvement: float = 1e-5, mono=None,
                 use_mono: bool = False, newton: bool = False,
-                reg_lambda: float = 0.0):
+                reg_lambda: float = 0.0, natural: bool = False):
     """Best split per leaf from (L, C, B+1, 4) histograms.
 
     Returns per-leaf: do_split, col, bitset (B+1 left-membership incl NA
@@ -535,6 +544,11 @@ def find_splits(hist, is_cat, col_allowed, min_rows: float = 10.0,
     direction are rejected; the builder additionally clamps child values
     to parent bounds (the XGBoost two-part scheme this engine's
     force_newton path matches).
+
+    ``natural`` (static): no column is categorical, so each column's bins
+    are scanned in their own order and nothing is sorted (the caller
+    decides on the host: ``numeric_only`` of the sparse-frontier engine,
+    whose looped levels would sort the cap's whole (L, C, B) table).
 
     ``hist`` must be f32: a quantized build (ops/statpack.py) must
     dequantize ONCE per level at the table — never per row and never
@@ -552,18 +566,21 @@ def find_splits(hist, is_cat, col_allowed, min_rows: float = 10.0,
 
     # order bins: numeric -> natural, categorical -> by mean gradient
     with jax.named_scope("h2o.tree.split.order"):
-        mean = wg[..., :B] / jnp.maximum(w[..., :B], EPS)
-        empty = w[..., :B] <= 0
-        key = jnp.where(empty, jnp.inf, mean)
-        natural = jnp.broadcast_to(
-            jnp.arange(B, dtype=jnp.float32)[None, None, :], key.shape)
-        order = jnp.argsort(jnp.where(is_cat[None, :, None], key, natural),
-                            axis=2)                          # (L, C, B)
+        if natural:
+            sw, swg, swgg, swh = (x[..., :B] for x in (w, wg, wgg, wh))
+        else:
+            mean = wg[..., :B] / jnp.maximum(w[..., :B], EPS)
+            empty = w[..., :B] <= 0
+            key = jnp.where(empty, jnp.inf, mean)
+            ident = jnp.broadcast_to(
+                jnp.arange(B, dtype=jnp.float32)[None, None, :], key.shape)
+            order = jnp.argsort(jnp.where(is_cat[None, :, None], key,
+                                          ident), axis=2)      # (L, C, B)
 
-        def sort_take(x):
-            return jnp.take_along_axis(x[..., :B], order, axis=2)
+            def sort_take(x):
+                return jnp.take_along_axis(x[..., :B], order, axis=2)
 
-        sw, swg, swgg, swh = map(sort_take, (w, wg, wgg, wh))
+            sw, swg, swgg, swh = map(sort_take, (w, wg, wgg, wh))
     # the scan over ordered prefixes: sums, gains, the arg-max
     with jax.named_scope("h2o.tree.split.scan"):
         cw, cwg, cwgg, cwh = (jnp.cumsum(x, axis=2)
@@ -575,7 +592,9 @@ def find_splits(hist, is_cat, col_allowed, min_rows: float = 10.0,
         tot_wh = cwh[..., -1] + nawh
 
         def se(w_, wg_, wgg_):
-            return wgg_ - wg_ ** 2 / jnp.maximum(w_, EPS)
+            if newton:
+                return wgg_ - wg_ ** 2 / jnp.maximum(w_, EPS)
+            return node_sq_err(w_, wg_, wgg_)
 
         se_parent = se(tot_w, tot_wg, tot_wgg)               # (L, C)
 
@@ -625,8 +644,11 @@ def find_splits(hist, is_cat, col_allowed, min_rows: float = 10.0,
     # gather chosen column's per-leaf arrays
     li = jnp.arange(L)
     with jax.named_scope("h2o.tree.split.order"):
-        order_c = order[li, col]                              # (L, B)
-        rank = jnp.argsort(order_c, axis=1)                   # inverse perm
+        if natural:
+            rank = jnp.broadcast_to(jnp.arange(B)[None, :], (L, B))
+        else:
+            order_c = order[li, col]                          # (L, B)
+            rank = jnp.argsort(order_c, axis=1)               # inverse perm
     bitset_bins = rank <= split_b[:, None]                    # (L, B)
     bitset = jnp.concatenate([bitset_bins, na_left[:, None]], axis=1)
 

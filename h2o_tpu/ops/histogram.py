@@ -123,10 +123,12 @@ def onehot_width(B1: int) -> int:
 
 
 def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
-                mm_dtype=jnp.float32):
+                mm_dtype=jnp.float32,
+                scopes=("h2o.tree.hist.onehot", "h2o.tree.hist.contract")):
     """One row block's histogram: (C*(B+1), L*S).  ``nbins`` may exceed
     the data's (``histogram_build_traced`` asks for ``onehot_width``):
-    a bin no row carries comes back zero.
+    a bin no row carries comes back zero.  ``scopes``: the device scopes
+    of the one-hot build and of the contraction.
 
     bins_blk:  (R, C) packed int (uint8/int16/int32) in [0, B] (B = NA
                bucket) — the one-hot compare below promotes against the
@@ -147,7 +149,7 @@ def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
     C = bins_blk.shape[1]
     S = stats_blk.shape[1]
     quantized = jnp.issubdtype(stats_blk.dtype, jnp.integer)
-    with jax.named_scope("h2o.tree.hist.onehot"):
+    with jax.named_scope(scopes[0]):
         leafhot = (leaf_blk[:, None] == jnp.arange(n_leaves)[None, :])
         # zero stats of inactive rows BEFORE the product: padded rows carry
         # NaN payloads and 0 * NaN would poison the accumulator (the
@@ -159,7 +161,7 @@ def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
         binhot = (bins_blk[:, :, None] ==
                   jnp.arange(B1)[None, None, :]).reshape(-1, C * B1)
         # (R, C*B1)
-    with jax.named_scope("h2o.tree.hist.contract"):
+    with jax.named_scope(scopes[1]):
         if quantized:
             # integer MXU path: one-hot cast to the SAME narrow carrier
             # in-register (values are 0/1 — exact), int32 accumulator.
@@ -200,9 +202,15 @@ def map_buckets(bins_blk, leaf_blk, lo, hi, off, is_cat, nbins: int,
     # the matrix ever lands in HBM
     bins_blk = widen_bins(bins_blk)
     lf = jnp.maximum(leaf_blk, 0)
-    lo_b = lo[lf]                                # (R, C)
-    hi_b = hi[lf]
-    o_b = off[lf]
+    return _bucket_rows(bins_blk, lo[lf], hi[lf], off[lf], is_cat, nbins,
+                        fine_na)
+
+
+def _bucket_rows(bins_blk, lo_b, hi_b, o_b, is_cat, nbins: int,
+                 fine_na: int):
+    """``map_buckets``' arithmetic on each row's own (R, C) node ranges
+    (the widen is a no-op on a block ``map_buckets`` widened)."""
+    bins_blk = widen_bins(bins_blk)
     span = jnp.maximum(hi_b - lo_b + 1, 1)
     x = jnp.clip(bins_blk - lo_b, 0, span - 1)
     nb = jnp.clip((x * nbins + o_b) // span, 0, nbins - 1)
@@ -339,6 +347,129 @@ def histogram_build_traced(bins, leaf, stats, n_leaves: int, nbins: int,
         h = run(bins, leaf, stats, *extra)          # (C*B1, L*S)
         return (h.reshape(C, B1, n_leaves, S)
                  .transpose(2, 0, 1, 3))            # (L, C, B+1, S)
+
+
+# The window form of a level's histogram (``histogram_window_traced``):
+# levels of at least WINDOW_MIN_LEAVES nodes (the sparse-frontier
+# engine's deep levels) sort their rows by node, and each block of
+# WINDOW_ROWS rows is contracted against the WINDOW_LEAVES nodes from the
+# block's first row's node on, so a level costs rows x columns x bins x
+# WINDOW_LEAVES x S whatever its width.
+WINDOW_MIN_LEAVES = 64
+WINDOW_LEAVES = 128
+WINDOW_ROWS = 4096
+
+
+def window_level(n_leaves: int) -> bool:
+    """Whether a frontier level of ``n_leaves`` nodes takes the window
+    form (a static rule on the level's shape)."""
+    return n_leaves >= WINDOW_MIN_LEAVES
+
+
+def histogram_window_traced(bins, leaf, stats, n_leaves: int, nbins: int,
+                            bf16: bool = False, fine_map=None):
+    """``histogram_build_traced``'s (L, C, B+1, S) table, built by node
+    windows instead of one contraction of every row against every node.
+
+    Each device sorts its rows by node (``h2o.tree.partition``: rows the
+    level does not see, ``leaf`` < 0, sort last) and finds where each
+    node's rows start.  A ``while`` over the sorted rows then takes a
+    block of at most WINDOW_ROWS rows whose nodes lie in ``[s0, s0 +
+    W)``, ``s0`` the node of its first row, gathers their bins and
+    statistics (partition again) and contracts the block's one-hot
+    against the W nodes of its window only (``h2o.tree.hist.window``),
+    adding the (C*B1, W*S) result into the table at column ``s0 * S``.
+    A block ends early where the next row's node is past the window, so
+    the blocks number at most rows / WINDOW_ROWS + L / W.  With
+    ``fine_map`` each row's node ranges are picked from the window's W
+    rows of the (L, C) tables by a one-hot contraction (exact: integer
+    values under 2**24), not by a per-row gather.  The table is the same
+    sums as the one-hot form's, in another order of addition."""
+    mesh = cloud().mesh
+    C, S = bins.shape[1], stats.shape[1]
+    B1 = nbins + 1
+    B1p = onehot_width(B1)
+    W = min(WINDOW_LEAVES, n_leaves)
+    blk = WINDOW_ROWS
+    L = n_leaves
+    if fine_map is None:
+        extra_specs, extra = (), ()
+    else:
+        lo, hi, off, is_cat_m, fine_na = fine_map
+        extra_specs = (P(), P(), P(), P())
+        extra = (lo, hi, off, is_cat_m)
+    mmd = jnp.bfloat16 if bf16 else jnp.float32
+    quantized = jnp.issubdtype(stats.dtype, jnp.integer)
+    scopes = ("h2o.tree.hist.window", "h2o.tree.hist.window")
+
+    dp = cloud().data_pspec
+    @functools.partial(shard_map_compat, mesh=mesh,
+                       in_specs=(dp(None), dp(), dp(None)) + extra_specs,
+                       out_specs=P(), check_vma=False)
+    def run(b_sh, l_sh, s_sh, *rep):
+        R = b_sh.shape[0]
+        with jax.named_scope("h2o.tree.partition"):
+            key = jnp.where(l_sh >= 0, l_sh, L).astype(jnp.int32)
+            skey, perm = jax.lax.sort(
+                (key, jnp.arange(R, dtype=jnp.int32)), num_keys=1,
+                is_stable=True)
+            starts = jnp.searchsorted(
+                skey, jnp.arange(L + 1, dtype=jnp.int32),
+                side="left").astype(jnp.int32)
+            n_rows = starts[L]
+            # pads: a block read past the last row, and a window past the
+            # last node, stay in range
+            skey = jnp.concatenate([skey, jnp.full((blk,), L, jnp.int32)])
+            perm = jnp.concatenate([perm, jnp.zeros((blk,), jnp.int32)])
+            starts = jnp.concatenate([starts, jnp.full((W,), n_rows,
+                                                        jnp.int32)])
+        if fine_map is not None:
+            tab = jnp.pad(jnp.concatenate(rep[:3], axis=1).astype(
+                jnp.float32), ((0, W), (0, 0)))            # (L+W, 3C)
+
+        def body(carry):
+            r0, acc = carry
+            s0 = skey[r0]
+            r1 = jnp.minimum(r0 + blk, starts[s0 + W])
+            kb = jax.lax.dynamic_slice(skey, (r0,), (blk,))
+            live = jnp.arange(blk, dtype=jnp.int32) < r1 - r0
+            local = jnp.where(live, kb - s0, -1)
+            with jax.named_scope("h2o.tree.partition"):
+                idx = jax.lax.dynamic_slice(perm, (r0,), (blk,))
+                bb, sb = b_sh[idx], s_sh[idx]
+            with jax.named_scope(scopes[0]):
+                if fine_map is not None:
+                    tw = jax.lax.dynamic_slice(tab, (s0, 0), (W, 3 * C))
+                    hot = local[:, None] == jnp.arange(W)[None, :]
+                    rng = jnp.round(jax.lax.dot_general(
+                        hot.astype(jnp.float32), tw,
+                        (((1,), (0,)), ((), ())),
+                        precision=jax.lax.Precision.HIGHEST)).astype(
+                            jnp.int32)
+                    bb = _bucket_rows(bb, rng[:, :C], rng[:, C:2 * C],
+                                      rng[:, 2 * C:], rep[3], nbins,
+                                      fine_na)
+            h = _block_hist(bb, local, sb, W, B1p - 1, mmd, scopes)
+            with jax.named_scope(scopes[1]):
+                cur = jax.lax.dynamic_slice(acc, (0, s0 * S),
+                                            (C * B1p, W * S))
+                acc = jax.lax.dynamic_update_slice(acc, cur + h,
+                                                   (0, s0 * S))
+            return r1, acc
+
+        with jax.named_scope(scopes[1]):
+            init = jnp.zeros((C * B1p, (L + W) * S),
+                             jnp.int32 if quantized else jnp.float32)
+            _, acc = jax.lax.while_loop(lambda c: c[0] < n_rows, body,
+                                        (jnp.int32(0), init))
+            acc = acc[:, :L * S]
+            if B1p != B1:
+                acc = acc.reshape(C, B1p, -1)[:, :B1].reshape(C * B1, -1)
+            return hpsum(acc, "hist.table")
+
+    with jax.named_scope(scopes[1]):
+        h = run(bins, leaf, stats, *extra)
+        return h.reshape(C, B1, L, S).transpose(2, 0, 1, 3)
 
 
 _histogram_build_jit = jax.jit(

@@ -226,7 +226,9 @@ def test_drf_final_span_says_it_rescored(cl, rng):
         y="y", x=XS, training_frame=fr)
     final, = [e for e in TimeLine.snapshot() if "dur_ns" in e
               and (e["kind"], e["what"]) == ("train", "final_metrics")]
-    assert final["source"] == "rescore"
+    # nothing is scored again: a forest's training metrics are read from
+    # the out-of-bag votes the trainer carried, as H2O-3 reports them
+    assert final["source"] == "carried_oob"
 
 
 # ------------------------------------------- the per-block scorer's history
@@ -272,12 +274,18 @@ SCORED = {
         ntrees=6, score_tree_interval=3), None, 1e-6),
     "xgboost": ("binomial", lambda fr: _xgboost(
         learn_rate=1.0, score_tree_interval=1), None, 0),
-    "drf_binomial": ("binomial", lambda fr: _drf(), None, 0),
-    "drf_multinomial": ("multinomial", lambda fr: _drf(), None, 0),
-    "drf_regression_donating": ("regression", lambda fr: _drf(), "1", 0),
+    # a forest with no bag (sample_rate 1) reports every tree's votes; a
+    # bagged one its out-of-bag votes, which no descent of the whole
+    # forest reproduces: both of its jobs report the same carried ones
+    "drf_binomial": ("binomial", lambda fr: _drf(sample_rate=1.0), None, 0),
+    "drf_multinomial": ("multinomial", lambda fr: _drf(sample_rate=1.0),
+                        None, 0),
+    "drf_regression_donating": ("regression",
+                                lambda fr: _drf(sample_rate=1.0), "1", 0),
     "drf_sample_rate_interval_2": ("binomial", lambda fr: _drf(
         sample_rate=0.5, score_tree_interval=2), None, 1e-6),
 }
+OUT_OF_BAG = {"drf_sample_rate_interval_2"}
 
 
 def _count_forest_score(monkeypatch):
@@ -329,7 +337,8 @@ def test_scoring_history_is_that_of_a_descending_scorer(
     TimeLine.clear()
     carried = build(fr).train(y="y", x=XS, training_frame=fr)
     spans = _score_spans()
-    assert spans and {e["source"] for e in spans} == {"carried_F"}
+    assert spans and {e["source"] for e in spans} == {
+        "carried_oob" if case in OUT_OF_BAG else "carried_F"}
     # the block loop descended no finished tree
     assert not _inside(calls, spans)
 
@@ -355,6 +364,11 @@ def test_scoring_history_is_that_of_a_descending_scorer(
     for g, w in zip(got, want):
         assert set(g) == set(w) and len(g) >= 2
         assert g["number_of_trees"] == w["number_of_trees"]
+        if case in OUT_OF_BAG:
+            # each row scored only by the trees that did not see it: worse
+            # than every tree's votes on the rows they were grown on
+            assert g["logloss"] > w["logloss"], (case, g, w)
+            continue
         for k in g:
             if rtol:
                 assert g[k] == pytest.approx(w[k], rel=rtol, abs=1e-9), k

@@ -162,7 +162,7 @@ def job_and_oracle(request, cl):
     fold = plain_folds(scheme, n, 7, fr, y)
     oracle = oracle_cv(cls, dict(params, seed=7), fr, fold, x, weights)
     return dict(model=model, events=events, fold=fold, oracle=oracle,
-                frame=fr, cl=cl)
+                frame=fr, cl=cl, algo=algo)
 
 
 def _dkv(cl, key):
@@ -239,7 +239,10 @@ def test_fold_models_score_their_holdout_from_the_carried_F(job_and_oracle):
                           rel=2e-6)
     scores = [e for e in j["events"] if e["what"] == "block.score"]
     folds = [e for e in scores if e["holdout_rows"]]
-    assert folds and all(e["source"] == "carried_F" for e in folds)
+    # a random forest's in-fold metrics are its out-of-bag votes'; the
+    # holdout's come from the same carry
+    src = "carried_oob" if j["algo"] == "drf" else "carried_F"
+    assert folds and all(e["source"] == src for e in folds)
     assert sorted({e["holdout_rows"] for e in folds}) == sorted(
         set(np.bincount(j["fold"]).tolist()))
 

@@ -158,7 +158,9 @@ def test_a_frontier_past_the_crossover_gathers(slots):
     kw = dict(max_depth=14, nbins=slots - 1, kleaves=4096, adaptive=False,
               fine_nbins=0, mm_route=False)
     levels, selects = je.route_plan(kw)
-    widths = je.frontier_plan(14, 4096)
+    # the levels from the loop's start on run at the cap's width
+    d0 = je.frontier_loop_start(14, 4096, slots - 1, 0, False)
+    widths = je.frontier_plan(14, 4096)[:d0] + [4096] * (14 - d0)
     assert levels == 14 and 0 < selects < 14
     assert selects == sum(je.route_selects(L, slots) for L in widths)
 
@@ -212,11 +214,18 @@ def test_the_plan_counts_what_the_trace_does(monkeypatch, engine, D, B,
     build = je.build_tree_frontier if engine == "frontier" else \
         je.build_tree_traced
     seen = _traced_levels(monkeypatch, build, cfg)
+    widths = je.frontier_plan(D, cap) if cap else [2 ** d for d in range(D)]
+    if cap:
+        # the frontier engine's levels from d0 on are one traced loop
+        # body at the cap's width
+        d0 = je.frontier_loop_start(D, cap, B, F, adaptive)
+        assert 0 < d0 < D
+        seen = seen[:d0] + seen[d0:] * (D - d0)
+        widths = widths[:d0] + [cap] * (D - d0)
     assert len(seen) == D
     kw = dict(max_depth=D, nbins=B, kleaves=cap, adaptive=adaptive,
               fine_nbins=F, mm_route=False)
     assert je.route_plan(kw) == (D, sum(s for _, _, s in seen))
-    widths = je.frontier_plan(D, cap) if cap else [2 ** d for d in range(D)]
     assert [L for L, _, _ in seen] == widths
 
 

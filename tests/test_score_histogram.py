@@ -115,3 +115,17 @@ CASES = {
 @pytest.mark.parametrize("case", list(CASES))
 def test_score_histogram_matches_the_scatter(case):
     CASES[case]()
+
+
+@pytest.mark.parametrize("rows", [1, 1023, 1024, 70001])
+def test_row_sums_in_partials_equal_the_exact_sum(rows):
+    """``_sum_rows``: partial sums of ``_SUM_BLOCK`` rows, the rows left
+    over padded with zeros, then the sum of the partials; terms that many
+    rows share (a forest of pure leaves) are what a single chained
+    reduction on the chip rounds alike at every add."""
+    rng = np.random.default_rng(rows)
+    x = np.where(rng.random(rows) < 0.3, np.float32(34.538776),
+                 rng.random(rows, dtype=np.float32)).astype(np.float32)
+    got = float(mm._sum_rows(jnp.asarray(x)))
+    want = float(np.sum(x.astype(np.float64)))
+    assert got == pytest.approx(want, rel=2e-7, abs=1e-6)
