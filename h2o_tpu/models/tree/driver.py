@@ -296,7 +296,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     uninterrupted forest bit-for-bit.
     """
     from h2o_tpu.models.tree.jit_engine import (resolve_train_levers,
-                                                route_plan, train_forest)
+                                                route_plan, train_forest,
+                                                window_levels)
     from h2o_tpu.models.tree.shared_tree import (rng_key_from_np,
                                                  rng_key_to_np)
 
@@ -435,9 +436,11 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         may_stop or recovery is not None or scorer is not None) else None
     launched = done
     no_donate = False       # latched by the OOM ladder: retries re-read F
-    # a tree's routed levels, and those the select form routes: the rule
-    # the engine applies to each level's static shape, on the host
+    # a tree's routed levels, and those the select form routes; its
+    # window levels: the rules the engine applies to each level's static
+    # shape, on the host
     route_levels, route_select_levels = route_plan(train_kwargs)
+    n_window = window_levels(train_kwargs)
 
     def _launch(off: int, n: int) -> Dict:
         nonlocal F, OOB, block, no_donate
@@ -480,7 +483,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
 
         with TimeLine.span("train", "block.launch", t0=prior_trees + off,
                            route_levels=route_levels,
-                           route_select_levels=route_select_levels):
+                           route_select_levels=route_select_levels,
+                           window_levels=n_window):
             tf = oom_ladder("tree.block", attempt, shrink=shrink,
                             on_oom=on_oom)
             F, OOB = tf.f_final, tf.oob

@@ -34,7 +34,9 @@ TWO ENGINES, ONE OUTPUT CONTRACT:
   default max_depth=20 trains unclamped with bounded memory.  Its deep
   levels (64 nodes and more) build the histogram by node windows over
   rows sorted by node (``ops/histogram.histogram_window_traced``: the
-  cost does not grow with the frontier's width), and the levels from
+  cost does not grow with the frontier's width; a block gathers its
+  rows' bins as words packed once a block of trees by
+  ``binpack.pack_words``), and the levels from
   ``frontier_loop_start`` on run as ONE loop body at the cap's width,
   so a depth-20 tree compiles as about seven levels.
 
@@ -67,7 +69,7 @@ import jax.numpy as jnp
 from h2o_tpu.models.distributions import get_distribution
 from h2o_tpu.models.tree.shared_tree import find_splits, node_sq_err
 from h2o_tpu.ops import statpack
-from h2o_tpu.ops.binpack import pick_bin
+from h2o_tpu.ops.binpack import pack_words, pick_bin
 from h2o_tpu.ops.histogram import histogram_build_traced as _shard_histogram
 from h2o_tpu.ops.histogram import histogram_window_traced, window_level
 
@@ -301,6 +303,19 @@ def route_selects(L: int, n_slots: int) -> bool:
     return L * (route_words(n_slots) + 1) <= ROUTE_SELECT_MAX
 
 
+def _level_widths(kw: Dict):
+    """The width each level of one tree of ``train_forest(**kw)`` runs
+    at: ``2^d`` in the dense engine; in the frontier engine the plan's,
+    the looped levels at the cap's."""
+    D, B = int(kw["max_depth"]), int(kw["nbins"])
+    kleaves = int(kw.get("kleaves") or 0)
+    if kleaves <= 0:
+        return [2 ** d for d in range(D)]
+    d0 = frontier_loop_start(D, kleaves, B, int(kw.get("fine_nbins") or B),
+                             bool(kw.get("adaptive")))
+    return frontier_plan(D, kleaves)[:d0] + [kleaves] * (D - d0)
+
+
 def route_plan(kw: Dict):
     """``(levels, select_levels)`` of one tree of ``train_forest(**kw)``:
     the levels growth routes and how many of them take the select form,
@@ -311,12 +326,7 @@ def route_plan(kw: Dict):
     kleaves = int(kw.get("kleaves") or 0)
     adaptive = bool(kw.get("adaptive"))
     F = int(kw.get("fine_nbins") or B)
-    widths = frontier_plan(D, kleaves) if kleaves > 0 else \
-        [2 ** d for d in range(D)]
-    if kleaves > 0:
-        # the frontier engine's looped levels run at the cap's width
-        d0 = frontier_loop_start(D, kleaves, B, F, adaptive)
-        widths = widths[:d0] + [kleaves] * (D - d0)
+    widths = _level_widths(kw)
     selects = 0
     for d, L in enumerate(widths):
         Bd = max(B, F >> d) if adaptive else B
@@ -324,6 +334,15 @@ def route_plan(kw: Dict):
             (2 * L if kleaves > 0 else L) <= _MM_ROUTE_MAX_TABLE
         selects += not mm and route_selects(L, Bd + 1)
     return D, selects
+
+
+def window_levels(kw: Dict) -> int:
+    """The levels of one tree of ``train_forest(**kw)`` that build the
+    window histogram (the frontier engine's of ``window_level`` width),
+    counted on the host for the ``train.block.launch`` span."""
+    if int(kw.get("kleaves") or 0) <= 0:
+        return 0
+    return sum(window_level(L) for L in _level_widths(kw))
 
 
 def _pick(idx, table):
@@ -756,7 +775,7 @@ def build_tree_traced(bins, stats, leaf0, key, is_cat, cfg: Dict,
 
 def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
                         tree_col_mask=None, mono=None, inv_scale=None,
-                        draws=None):
+                        draws=None, packed=None):
     """Traceable single-tree build with a CAPPED live frontier.
 
     Like ``build_tree_traced`` but the per-level leaf set is bounded by
@@ -774,7 +793,9 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
     to a leaf holds ``child`` -2 (every reader takes a negative pointer
     for a leaf).  Levels of ``histogram.window_level`` width take the
     window form of the histogram (``histogram_window_traced``: cost
-    bounded by a node window, not by the level's width).  Returns
+    bounded by a node window, not by the level's width; ``packed``: the
+    bins as ``binpack.pack_words`` packs them, which its blocks gather).
+    Returns
     (split_col (N,), bitset (N, B+1), value (N,), child (N,),
     varimp (C,), frontier (3,), node_gain (N,), node_w (N,), thr (N,),
     na_left (N,), pos (R,)); ``frontier`` counts the children the cap
@@ -851,7 +872,8 @@ def build_tree_frontier(bins, stats, slot0, key, is_cat, cfg: Dict,
             else None
         if window_level(L):
             hist = histogram_window_traced(bins, hslot, stats, L, Bd,
-                                           cfg["bf16"], fine_map=fine_map)
+                                           cfg["bf16"], fine_map=fine_map,
+                                           words=packed)
         elif adaptive:
             hist = _shard_histogram(
                 bins, hslot, stats, L, Bd, cfg["block_rows"], cfg["bf16"],
@@ -1326,6 +1348,13 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
     counter = mode == "drf"
     words = seed_words(key) if counter else None
 
+    # the window levels gather their rows' bins as packed words: packed
+    # once here, outside the tree loop (the bins do not change)
+    packed = None
+    if window_levels(dict(cfg, kleaves=kleaves)):
+        with jax.named_scope("h2o.tree.partition"):
+            packed = pack_words(bins, fine_nbins or nbins)
+
     def tree_step(carry, xs):
         F, oob = (carry, None) if oob0 is None else carry
         t_idx, key_t = xs
@@ -1373,7 +1402,8 @@ def _train_forest_impl(bins, yv, w, active, F0, is_cat, key, *,
                 sc, bs, vl, ch, vi, fr, gn, nw, th, na, pos = \
                     build_tree_frontier(bins, stats, leaf0, kk, is_cat, cfg,
                                         tree_cols, mono=mono,
-                                        inv_scale=inv_sc, draws=draws)
+                                        inv_scale=inv_sc, draws=draws,
+                                        packed=packed)
                 frs.append(fr)
             else:
                 sc, bs, vl, vi, gn, nw, th, na, pos = build_tree_traced(

@@ -82,6 +82,31 @@ def widen_bins(b) -> jax.Array:
     return lax.convert_element_type(b, jnp.int32)
 
 
+def pack_words(bins, max_code: int) -> jax.Array:
+    """(R, C) bins in ``[0, max_code]`` -> uint32 words: (R, ceil(C / 2))
+    where every code fits 16 bits, column ``j`` the low half of word
+    ``j`` and column ``j + ceil(C / 2)`` its high half (zero where C is
+    odd); else (R, C), a code a word.  The form in which a window level's
+    blocks gather their rows (``ops/histogram.histogram_window_traced``):
+    the chip's gather reads element by element, so half the elements of
+    the int32 codes."""
+    b = lax.convert_element_type(bins, jnp.uint32)
+    if int(max_code) >= 1 << 16:
+        return b
+    C = b.shape[1]
+    nw = -(-C // 2)
+    hi = jnp.pad(b[:, nw:], ((0, 0), (0, 2 * nw - C)))
+    return b[:, :nw] | (hi << 16)
+
+
+def unpack_words(words, C: int, max_code: int) -> jax.Array:
+    """:func:`pack_words`' inverse on a row block: (R, C) int32 codes,
+    unpacked in-register like :func:`widen_bins`."""
+    if int(max_code) < 1 << 16:
+        words = jnp.concatenate([words & 0xFFFF, words >> 16], axis=1)
+    return lax.convert_element_type(words[:, :C], jnp.int32)
+
+
 def pick_bin(bins, c) -> jax.Array:
     """``bins[r, c[r]]`` for every row r: each row's bin in the column
     its node splits on, in the matrix's own dtype.  A compare-select

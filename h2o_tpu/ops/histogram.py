@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from h2o_tpu.core.cloud import cloud, hpsum, shard_map_compat
-from h2o_tpu.ops.binpack import widen_bins
+from h2o_tpu.ops.binpack import pack_words, unpack_words, widen_bins
 
 # stats slots
 W, WG, WGG, WH = 0, 1, 2, 3
@@ -367,7 +367,7 @@ def window_level(n_leaves: int) -> bool:
 
 
 def histogram_window_traced(bins, leaf, stats, n_leaves: int, nbins: int,
-                            bf16: bool = False, fine_map=None):
+                            bf16: bool = False, fine_map=None, words=None):
     """``histogram_build_traced``'s (L, C, B+1, S) table, built by node
     windows instead of one contraction of every row against every node.
 
@@ -375,11 +375,14 @@ def histogram_window_traced(bins, leaf, stats, n_leaves: int, nbins: int,
     level does not see, ``leaf`` < 0, sort last) and finds where each
     node's rows start.  A ``while`` over the sorted rows then takes a
     block of at most WINDOW_ROWS rows whose nodes lie in ``[s0, s0 +
-    W)``, ``s0`` the node of its first row, gathers their bins and
-    statistics (partition again) and contracts the block's one-hot
-    against the W nodes of its window only (``h2o.tree.hist.window``),
-    adding the (C*B1, W*S) result into the table at column ``s0 * S``.
-    A block ends early where the next row's node is past the window, so
+    W)``, ``s0`` the node of its first row, and contracts the block's
+    one-hot against the W nodes of its window only
+    (``h2o.tree.hist.window``), adding the (C*B1, W*S) result into the
+    table at column ``s0 * S``.  A block gathers its rows' statistics and
+    bins as ``binpack.pack_words(bins, max_code)`` packs them (``words``,
+    packed here when None: two codes a word, so half the elements of the
+    int32 bins) and unpacks the codes in-register (partition again).  A
+    block ends early where the next row's node is past the window, so
     the blocks number at most rows / WINDOW_ROWS + L / W.  With
     ``fine_map`` each row's node ranges are picked from the window's W
     rows of the (L, C) tables by a one-hot contraction (exact: integer
@@ -398,6 +401,9 @@ def histogram_window_traced(bins, leaf, stats, n_leaves: int, nbins: int,
         lo, hi, off, is_cat_m, fine_na = fine_map
         extra_specs = (P(), P(), P(), P())
         extra = (lo, hi, off, is_cat_m)
+    max_code = nbins if fine_map is None else fine_na
+    if words is None:
+        words = pack_words(bins, max_code)
     mmd = jnp.bfloat16 if bf16 else jnp.float32
     quantized = jnp.issubdtype(stats.dtype, jnp.integer)
     scopes = ("h2o.tree.hist.window", "h2o.tree.hist.window")
@@ -436,7 +442,8 @@ def histogram_window_traced(bins, leaf, stats, n_leaves: int, nbins: int,
             local = jnp.where(live, kb - s0, -1)
             with jax.named_scope("h2o.tree.partition"):
                 idx = jax.lax.dynamic_slice(perm, (r0,), (blk,))
-                bb, sb = b_sh[idx], s_sh[idx]
+                bb = unpack_words(b_sh[idx], C, max_code)
+                sb = s_sh[idx]
             with jax.named_scope(scopes[0]):
                 if fine_map is not None:
                     tw = jax.lax.dynamic_slice(tab, (s0, 0), (W, 3 * C))
@@ -468,7 +475,7 @@ def histogram_window_traced(bins, leaf, stats, n_leaves: int, nbins: int,
             return hpsum(acc, "hist.table")
 
     with jax.named_scope(scopes[1]):
-        h = run(bins, leaf, stats, *extra)
+        h = run(words, leaf, stats, *extra)
         return h.reshape(C, B1, L, S).transpose(2, 0, 1, 3)
 
 

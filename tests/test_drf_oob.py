@@ -4,7 +4,11 @@ engine's deep levels, on the CPU mesh.
 - the window form of a level's histogram (``histogram_window_traced``:
   rows sorted by node, each block contracted against a window of nodes)
   is the one-hot form's table, on random slots with rows out of the
-  level, for narrow and wide levels, global and adaptive grids;
+  level, for narrow and wide levels, global and adaptive grids, int32
+  codes up to the 1,024-bin grid's NA bucket in an odd number of
+  columns, a level no row is in, and node runs across block ends; a
+  window level sorts once and its blocks gather packed words, and the
+  launch span counts the window levels;
 - the counter-based bag and ``mtries`` draws equal their numpy
   restatement (the rule ``jit_engine.py`` states), and a forest built in
   blocks of one tree is the forest built in one block;
@@ -16,6 +20,8 @@ engine's deep levels, on the CPU mesh.
   ``benchmark/reference/drf.py``, and the program's cut counters are the
   cut children its trees hold.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,27 +37,46 @@ from h2o_tpu.ops.histogram import (histogram_build_traced,
 
 # ---- the window histogram ------------------------------------------------
 
-@pytest.mark.parametrize("L, adaptive", [(1, False), (9, True),
-                                         (64, False), (200, True),
-                                         (300, False)])
+def _case(L, adaptive, id=None, R=2048, C=4, F=64, dtype=np.int16,
+          out=0.2, sdtype=np.float32):
+    return pytest.param(L, adaptive, R, C, F, dtype, out, sdtype,
+                        id=id or f"{L}-{adaptive}")
+
+
+@pytest.mark.parametrize("L, adaptive, R, C, F, dtype, out, sdtype", [
+    _case(1, False), _case(9, True), _case(64, False), _case(200, True),
+    _case(300, False),
+    # int32 codes up to the NA bucket of a 1,024-bin fine grid, an odd
+    # column count: the packed words' pad column
+    _case(200, True, "fine1024-int32-C5", C=5, F=1024, dtype=np.int32),
+    # no row in the level: the block loop runs no block
+    _case(300, True, "no-row-in-level", F=1024, dtype=np.int32, out=1.0),
+    # 12,000 rows a device over 160 nodes: a window of 128 nodes holds
+    # three blocks' rows, so blocks end inside a node's run
+    _case(160, False, "runs-across-block-ends", R=96000, C=3),
+    # quantized statistics (an int16 carrier, an int32 table) beside
+    # uint8 codes
+    _case(150, False, "int16-statistics", C=7, dtype=np.uint8,
+          sdtype=np.int16)])
 def test_window_histogram_equals_the_one_hot_histogram(cl, rng, L,
-                                                       adaptive):
-    R, C, B, F = 2048, 4, 20, 64
+                                                       adaptive, R, C, F,
+                                                       dtype, out, sdtype):
+    B = 20
     nb = F if adaptive else B
-    bins = rng.integers(0, nb + 1, size=(R, C)).astype(np.int16)
+    bins = rng.integers(0, nb + 1, size=(R, C)).astype(dtype)
     slot = rng.integers(0, L, size=R).astype(np.int32)
-    slot[rng.uniform(size=R) < 0.2] = -1
+    slot[rng.uniform(size=R) < out] = -1
     # integer statistics: both forms' sums are exact in float32
     w = rng.integers(0, 3, size=R).astype(np.float32)
     yv = rng.integers(0, 2, size=R).astype(np.float32)
-    stats = np.stack([w, w * yv, w * yv, w], axis=1)
+    stats = np.stack([w, w * yv, w * yv, w], axis=1).astype(sdtype)
     fine_map = None
     if adaptive:
         lo = rng.integers(0, F // 2, size=(L, C)).astype(np.int32)
         hi = (lo + rng.integers(4, F // 2, size=(L, C))).astype(np.int32)
         fine_map = (jnp.asarray(lo), jnp.asarray(hi),
                     jnp.zeros((L, C), jnp.int32),
-                    jnp.asarray([False, True, False, False]), F)
+                    jnp.asarray(np.arange(C) == 1), F)
     nbins = B
 
     def both(b, s, st):
@@ -67,6 +92,40 @@ def test_window_histogram_equals_the_one_hot_histogram(cl, rng, L,
     np.testing.assert_array_equal(win, one)
     # every row the level sees is in the table once a column
     assert win[..., 0].sum() == w[slot >= 0].sum() * C
+
+
+def test_a_window_level_sorts_once_and_gathers_packed_words(cl, rng):
+    # one window level at cell 6's row width (28 columns of a 1,024-bin
+    # fine grid, 4 statistics), compiled on the CPU mesh: ONE sort, under
+    # ``h2o.tree.partition`` (``window_hist_roofline`` counts a slice's
+    # window levels by those sort events); a block gathers its rows'
+    # bins as 14 packed words, not as 28 int32 codes, and its statistics
+    R, C, L, F = 2048, 28, 300, 1024
+    lo = rng.integers(0, F // 2, size=(L, C)).astype(np.int32)
+
+    def level(b, s, st):
+        return histogram_window_traced(
+            b, s, st, L, 20, fine_map=(jnp.asarray(lo), jnp.asarray(lo + 8),
+                                       jnp.zeros((L, C), jnp.int32),
+                                       jnp.zeros((C,), bool), F))
+    hlo = jax.jit(level).lower(
+        rng.integers(0, F + 1, size=(R, C)).astype(np.int32),
+        rng.integers(-1, L, size=R).astype(np.int32),
+        np.ones((R, 4), np.float32)).compile().as_text()
+    sorts = [ln for ln in hlo.splitlines() if re.search(r"\bsort\(", ln)]
+    assert len(sorts) == 1 and "h2o.tree.partition" in sorts[0]
+    gathers = re.findall(r"(\w+)\[4096(?:,1)?,(\d+)\]\S* gather\(", hlo)
+    assert sorted(gathers) == [("f32", "4"), ("u32", "14")]
+
+
+def test_the_window_levels_of_a_default_forest():
+    # H2O-3's DRF at depth 20 on the 1,024-bin fine grid, cap 65,536:
+    # levels 0-5 hold 1-32 nodes, under the window's 64; levels 6-19 are
+    # window levels
+    kw = dict(max_depth=20, nbins=20, kleaves=65536, adaptive=True,
+              fine_nbins=1024)
+    assert je.window_levels(kw) == 14
+    assert je.window_levels(dict(kw, kleaves=0)) == 0
 
 
 # ---- the draws -------------------------------------------------------------
@@ -231,3 +290,7 @@ def test_a_small_default_forest_is_held_to_the_reference(cl, monkeypatch):
     assert nums["cut_tree1"] == pulls[0]["frontier_cut"] > 0
     assert all(e["frontier_split_children"] > e["frontier_cut"] and
                e["frontier_levels"] > 0 for e in pulls)
+    # a cap of 64 nodes: levels 6-19 are window levels, as at 65,536
+    launches = [e for e in TimeLine.snapshot() if "dur_ns" in e and
+                (e["kind"], e["what"]) == ("train", "block.launch")]
+    assert {e["window_levels"] for e in launches} == {14}
