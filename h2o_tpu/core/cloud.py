@@ -37,6 +37,7 @@ silently bypassed.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import re
 import threading
@@ -524,3 +525,53 @@ def hpsum_slices(x, tag: str = ""):
         return jax.lax.psum(x, SLICE_AXIS)
 
 
+def pad_rows(x, fill=0):
+    """``x`` padded at the end of its rows with ``fill`` to a non-zero
+    multiple of the cloud's shard count, what a ``shard_map`` over the
+    data axis takes; unchanged where its rows are one (a frame's always
+    are)."""
+    import jax.numpy as jnp
+    n, rows = cloud().n_nodes, x.shape[0]
+    pad = max(n, -(-rows // n) * n) - rows
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1),
+                   constant_values=fill)
+
+
+def hsum_rows(x, tag: str = "rows.sum"):
+    """Sum of a row-sharded (R, ...) array over its rows, computed where
+    the rows live: each shard sums its own, then one ``hpsum`` (no
+    collective the partitioner would put in on its own)."""
+    import jax.numpy as jnp
+    return _sum_rows_program(pad_rows(jnp.asarray(x)), tag=tag,
+                             mesh=cloud().mesh)
+
+
+@functools.partial(jax.jit, static_argnames=("tag", "mesh"))
+def _sum_rows_program(x, tag: str, mesh):
+    import jax.numpy as jnp
+    return shard_map_compat(
+        lambda s: hpsum(jnp.sum(s, axis=0), tag), mesh=mesh,
+        in_specs=(cloud().data_pspec(*([None] * (x.ndim - 1))),),
+        out_specs=P(), check_vma=False)(x)
+
+
+def hbroadcast_rows(row, rows: int):
+    """``(rows, *row.shape)`` float32 copies of one row, row-sharded over
+    the data axis as a frame's columns are: each shard makes its own
+    rows, no whole array is laid on one device, and a carry that starts
+    here has the sharding of the one a program hands back."""
+    c = cloud()
+    row = jax.numpy.asarray(row)
+    return _broadcast_rows_program(
+        row, rows=int(rows),
+        sharding=NamedSharding(c.mesh, c.data_pspec(*([None] * row.ndim))))
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "sharding"))
+def _broadcast_rows_program(row, rows: int, sharding):
+    import jax.numpy as jnp
+    return jax.lax.with_sharding_constraint(
+        jnp.broadcast_to(row[None], (rows,) + row.shape).astype(jnp.float32),
+        sharding)

@@ -22,6 +22,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import os
+import re
 import sys
 import threading
 import time
@@ -44,6 +45,34 @@ _READY_FIELDS = {"jaxpr_trace_duration": "trace_s",
                  "backend_compile_duration": "compile_s"}
 _READY_ORDER = tuple(_READY_FIELDS.values())
 _RETRIEVAL = "cache_retrieval_time_sec"
+
+# one collective operation of a compiled module's text, counted once: its
+# synchronous form or the ``-start`` half of an asynchronous pair
+_COLLECTIVE_OP = re.compile(
+    r"\s(?:all-reduce|all-gather|reduce-scatter|all-to-all|"
+    r"ragged-all-to-all|collective-permute|collective-broadcast)"
+    r"(?:-start)?\(")
+
+
+def unowned_collectives(hlo_text: str) -> int:
+    """Collective operations of a compiled module's text that no
+    ``h2o.coll.`` scope owns: those the partitioner put in (an operand it
+    gathered, a reduction it completed), not a ``core/cloud.py`` helper."""
+    return sum(1 for line in hlo_text.splitlines()
+               if _COLLECTIVE_OP.search(line) and "h2o.coll." not in line)
+
+
+def _executable_unowned(executable, devices) -> Optional[int]:
+    """``unowned_collectives`` over an executable's modules; 0 on one
+    device (no collective can be there: its text is not read), None
+    where the executable shows no text."""
+    if getattr(devices, "size", 1) <= 1:
+        return 0
+    try:
+        return sum(unowned_collectives(m.to_string())
+                   for m in executable.hlo_modules())
+    except Exception:  # noqa: BLE001 - a backend without module text
+        return None
 
 
 class DispatchStats:
@@ -83,6 +112,9 @@ class DispatchStats:
     _programs: deque = deque(maxlen=MAX_PROGRAMS)
     _ready_local = threading.local()    # .stack, .open: see _on_ready_end
     _listener_installed = False
+    # program key -> {collective kind: ICI bytes} of its explicit
+    # collectives, as their trace noted them
+    _program_ici: Dict[Any, int] = {}
 
     @classmethod
     def _bump(cls, d: Dict[str, int], phase: str, n: int = 1) -> None:
@@ -176,12 +208,39 @@ class DispatchStats:
         counts: a combine whose dcn_bytes grows with rows is the bug the
         two-level mesh exists to prevent."""
         p = phase if phase is not None else cls.current_phase()
+        open_ici = getattr(cls._phase_local, "ici", None)
+        if open_ici is not None:
+            open_ici[kind] = open_ici.get(kind, 0) + int(ici_bytes)
         with cls._lock:
             d = cls._collectives.setdefault(p, {}).setdefault(
                 kind, {"n": 0, "ici_bytes": 0, "dcn_bytes": 0})
             d["n"] += 1
             d["ici_bytes"] += int(ici_bytes)
             d["dcn_bytes"] += int(dcn_bytes)
+
+    @classmethod
+    def program_ici(cls, key, call):
+        """``(call(), {collective kind: ICI bytes})``: what the explicit
+        collectives of the program ``call`` runs ship, each collective
+        once as its trace holds it (one in a loop body counts once), as
+        the helpers noted them while it was traced on this thread; kept
+        under ``key`` for the calls that replay it untraced (empty where
+        this process never traced it here)."""
+        st = cls._phase_local
+        outer = getattr(st, "ici", None)
+        st.ici = {}
+        try:
+            out = call()
+        finally:
+            traced = st.ici
+            st.ici = outer
+            if outer is not None:
+                for k, v in traced.items():
+                    outer[k] = outer.get(k, 0) + v
+        with cls._lock:
+            if traced:
+                cls._program_ici[key] = traced
+            return out, dict(cls._program_ici.get(key, {}))
 
     # -- programs made ready (jax monitoring) -----------------------------
 
@@ -201,8 +260,21 @@ class DispatchStats:
             if cls._listener_installed:
                 return
             cls._listener_installed = True
-        from jax._src import monitoring
+        from jax._src import compiler, monitoring
         monitoring.register_scalar_listener(cls._on_ready_start)
+        compile_program = compiler.compile_or_get_cached
+
+        def compile_counted(backend, computation, devices, *a, **kw):
+            # inside the backend compile's event: the record it closes
+            # carries the count of the collectives no helper put there
+            exe = compile_program(backend, computation, devices, *a, **kw)
+            frame = cls._open_compile()
+            if frame is not None:
+                frame["gspmd_collectives"] = _executable_unowned(exe,
+                                                                 devices)
+            return exe
+
+        compiler.compile_or_get_cached = compile_counted
         monitoring.register_event_time_span_listener(cls._on_ready_end)
         monitoring.register_event_listener(cls._on_cache_event)
         monitoring.register_event_duration_secs_listener(cls._on_duration)
@@ -219,7 +291,7 @@ class DispatchStats:
     @staticmethod
     def _frame(event: str = "") -> Dict[str, Any]:
         return {"event": event, "carved": 0.0, "cache": "uncached",
-                "retrieve_s": None}
+                "retrieve_s": None, "gspmd_collectives": None}
 
     @classmethod
     def _open_compile(cls) -> Optional[Dict[str, Any]]:
@@ -297,6 +369,7 @@ class DispatchStats:
                 cls._xla_compiles += 1
             del st.open[depth]
             prog["retrieve_s"] = frame["retrieve_s"]
+            prog["gspmd_collectives"] = frame["gspmd_collectives"]
             cls._publish(prog, frame["cache"])
             if depth:
                 st.stack[-1]["carved"] += \
@@ -313,7 +386,8 @@ class DispatchStats:
               "id": TimeLine.new_id(), "parent": parent, "job": job,
               "fun": prog["fun"], "trace_s": prog["trace_s"],
               "lower_s": prog["lower_s"], "compile_s": prog["compile_s"],
-              "cache": cache, "retrieve_s": prog["retrieve_s"]}
+              "cache": cache, "retrieve_s": prog["retrieve_s"],
+              "gspmd_collectives": prog.get("gspmd_collectives")}
         with cls._lock:
             for name, field in _READY_FIELDS.items():
                 cls._compile_seconds[name] = \
@@ -350,7 +424,11 @@ class DispatchStats:
         it), "uncached" (compiled and not kept: no cache, or a compile
         quicker than ``jax_persistent_cache_min_compile_time_secs``, so
         every process compiles it again) or "traced" (no backend compile
-        followed: an ``eval_shape``, a ``lower()`` alone); ``ns`` and
+        followed: an ``eval_shape``, a ``lower()`` alone);
+        ``gspmd_collectives``: the compiled module's collective
+        operations that no ``h2o.coll.`` scope owns (``unowned_
+        collectives``: what the partitioner inserted; 0 on one device,
+        None where nothing was compiled); ``ns`` and
         ``dur_ns`` (the first event's start to the last one's end, on
         the ring's clock); ``parent`` and ``job`` (the ``TimeLine`` span
         open on the thread that made it).  The same dicts are the

@@ -47,8 +47,9 @@ from typing import Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from h2o_tpu.core.cloud import cloud
+from h2o_tpu.core.cloud import cloud, pad_rows
 from h2o_tpu.core.store import Key
 
 # Vec types (reference: water/fvec/Vec.java:207-212)
@@ -121,36 +122,58 @@ def _build_append_write(cap: int, ch: int):
     return kern
 
 
-@jax.jit
 def _rollups_matrix_kernel(matrix: jax.Array, rowvalid: jax.Array):
     """Fused single-pass rollup stats over ALL columns of a padded, sharded
     (rows, cols) matrix at once.
 
     Equivalent of the RollupStats MRTask (water/fvec/RollupStats.java), but
     batched column-wise: the reference computes rollups one Vec at a time
-    (one MRTask each); here one XLA program covers the whole frame, and the
-    row sharding makes every axis-0 reduction an ICI psum.  ``rowvalid``
+    (one MRTask each); here one XLA program covers the whole frame: each
+    shard reduces its own rows and the (cols,) partials meet in one
+    ``hpsum`` / ``hpmin`` / ``hpmax`` each.  ``rowvalid``
     is the row-validity predicate — a plain ``iota < nrows`` prefix for
     canonical frames, the per-shard-count mask for ragged ones — so the
-    kernel consumes sharded inputs as-is, no reshard or repack first.
+    kernel consumes sharded inputs as-is, no reshard or repack first
+    (rows are padded, not valid, to a multiple of the shard count where
+    they are not one).
     """
-    valid = rowvalid[:, None]
-    isna = jnp.isnan(matrix) & valid
-    ok = valid & ~isna
-    x = jnp.where(ok, matrix, 0.0)
-    cnt = jnp.sum(ok, axis=0)
-    nacnt = jnp.sum(isna, axis=0)
-    mean = jnp.sum(x, axis=0) / jnp.maximum(cnt, 1)
-    var = jnp.sum(jnp.where(ok, (matrix - mean[None, :]) ** 2, 0.0),
-                  axis=0) / jnp.maximum(cnt - 1, 1)
-    big = jnp.asarray(jnp.inf, matrix.dtype)
-    vmin = jnp.min(jnp.where(ok, matrix, big), axis=0)
-    vmax = jnp.max(jnp.where(ok, matrix, -big), axis=0)
-    zeros = jnp.sum(ok & (matrix == 0), axis=0)
-    isint = jnp.all(jnp.where(ok, matrix == jnp.round(matrix), True),
-                    axis=0)
-    return dict(cnt=cnt, nacnt=nacnt, mean=mean, sigma=jnp.sqrt(var),
-                min=vmin, max=vmax, zeros=zeros, isint=isint)
+    return _rollups_program(pad_rows(matrix), pad_rows(rowvalid, False),
+                            mesh=cloud().mesh)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh",))
+def _rollups_program(matrix, rowvalid, mesh):
+    from h2o_tpu.core.cloud import hpmax, hpmin, hpsum, shard_map_compat
+    dp = cloud().data_pspec
+
+    def run(matrix, rowvalid):
+        valid = rowvalid[:, None]
+        isna = jnp.isnan(matrix) & valid
+        ok = valid & ~isna
+        x = jnp.where(ok, matrix, 0.0)
+        cnt, nacnt, zeros = hpsum(jnp.stack([
+            jnp.sum(ok, axis=0), jnp.sum(isna, axis=0),
+            jnp.sum(ok & (matrix == 0), axis=0)]).astype(jnp.int32),
+            "rollups.counts")
+        mean = hpsum(jnp.sum(x, axis=0), "rollups.sum") / \
+            jnp.maximum(cnt, 1)
+        var = hpsum(jnp.sum(jnp.where(ok, (matrix - mean[None, :]) ** 2,
+                                      0.0), axis=0),
+                    "rollups.sum") / jnp.maximum(cnt - 1, 1)
+        big = jnp.asarray(jnp.inf, matrix.dtype)
+        vmin = hpmin(jnp.min(jnp.where(ok, matrix, big), axis=0),
+                     "rollups.range")
+        vmax = hpmax(jnp.max(jnp.where(ok, matrix, -big), axis=0),
+                     "rollups.range")
+        isint = hpmin(jnp.all(jnp.where(ok, matrix == jnp.round(matrix),
+                                        True), axis=0).astype(jnp.int32),
+                      "rollups.range") > 0
+        return dict(cnt=cnt, nacnt=nacnt, mean=mean, sigma=jnp.sqrt(var),
+                    min=vmin, max=vmax, zeros=zeros, isint=isint)
+
+    return shard_map_compat(run, mesh=mesh, in_specs=(dp(None), dp()),
+                            out_specs=P(), check_vma=False)(
+        matrix, rowvalid)
 
 
 @functools.partial(jax.jit, static_argnames=("nbins",))

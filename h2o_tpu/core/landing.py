@@ -72,24 +72,43 @@ def _row_sharding_for(arr_ndim: int) -> NamedSharding:
     return NamedSharding(c.mesh, c.data_pspec(*([None] * (arr_ndim - 1))))
 
 
-def _place(arr: np.ndarray, sh: NamedSharding) -> jax.Array:
+def _shard_piece(arr: np.ndarray, index, rows: int) -> np.ndarray:
+    """Shard ``index`` of ``arr`` padded to ``rows`` rows (NaN for a
+    float column, else 0): only a shard that reaches past the host rows
+    is copied to take its padding; no whole-array padded copy."""
+    rs = index[0] if index else slice(None)
+    start, stop, _ = rs.indices(rows)
+    if stop <= arr.shape[0]:
+        return arr[index]
+    have = arr[(slice(min(start, arr.shape[0]), arr.shape[0]),)
+               + tuple(index[1:])]
+    fill = np.nan if np.issubdtype(arr.dtype, np.floating) else 0
+    pad = [(0, (stop - start) - have.shape[0])] + [(0, 0)] * (arr.ndim - 1)
+    return np.pad(have, pad, constant_values=fill)
+
+
+def _place(arr: np.ndarray, sh: NamedSharding,
+           rows: Optional[int] = None) -> jax.Array:
     """Shard-direct placement: one device_put PER SHARD, assembled into
-    the global array — no whole-array staging on any single transfer.
+    the global array of ``rows`` rows (``arr``'s own by default; more
+    pads the last shards) — no whole-array staging on any single
+    transfer, and no whole-array padded copy on the host.
 
     On a two-level mesh this is also what keeps ingest SLICE-LOCAL: the
     sharding's device map sends each shard's rows straight to its home
     device inside its own ICI island, so DCN never carries raw rows on
     the way in — the host->device link is per-shard by construction."""
-    imap = sh.addressable_devices_indices_map(arr.shape)
+    shape = arr.shape if rows is None else (rows,) + arr.shape[1:]
+    imap = sh.addressable_devices_indices_map(shape)
     shards = []
     for d, index in imap.items():
-        piece = arr[index]
+        piece = _shard_piece(arr, index, shape[0])
         # graftlint: disable=GL304  the sanctioned landing layer itself
         shards.append(jax.device_put(piece, d))
         _note_transfer(int(piece.nbytes))
-    out = jax.make_array_from_single_device_arrays(arr.shape, sh, shards)
+    out = jax.make_array_from_single_device_arrays(shape, sh, shards)
     with _lock:
-        _counters["bytes_landed"] += int(arr.nbytes)
+        _counters["bytes_landed"] += int(np.prod(shape)) * arr.itemsize
     return out
 
 
@@ -105,15 +124,11 @@ def land_rows(host_array, sharding: Optional[NamedSharding] = None
     from h2o_tpu.core.cloud import cloud
     arr = np.asarray(host_array)
     q = cloud().row_multiple()
-    pad = (-arr.shape[0]) % q
-    if pad:
-        pad_width = [(0, pad)] + [(0, 0)] * (arr.ndim - 1)
-        fill = np.nan if np.issubdtype(arr.dtype, np.floating) else 0
-        arr = np.pad(arr, pad_width, constant_values=fill)
+    rows = arr.shape[0] + (-arr.shape[0]) % q
     sh = sharding if sharding is not None else _row_sharding_for(arr.ndim)
     with _lock:
         _counters["chunks_landed"] += 1
-    return _place(arr, sh)
+    return _place(arr, sh, rows)
 
 
 def reshard_rows(arr, sharding: Optional[NamedSharding] = None

@@ -17,6 +17,14 @@ import jax.numpy as jnp
 EPS = 1e-10
 
 
+def weighted_mean(y, w):
+    """sum(w * y) / max(sum(w), EPS) over the rows, each sum computed
+    where the rows live (``hsum_rows``)."""
+    from h2o_tpu.core.cloud import hsum_rows
+    return hsum_rows(w * y, "f0.sums") / jnp.maximum(
+        hsum_rows(w, "f0.sums"), EPS)
+
+
 class Distribution:
     """gradient/hessian are with respect to f (the link-scale prediction),
     following the classic gradient-boosting formulation the reference uses:
@@ -27,7 +35,7 @@ class Distribution:
 
     def init_f0(self, y, w):
         """Initial constant prediction on the link scale."""
-        m = jnp.sum(w * y) / jnp.maximum(jnp.sum(w), EPS)
+        m = weighted_mean(y, w)
         return self.link_fn(m)
 
     def link_fn(self, mu):
@@ -64,7 +72,7 @@ class Bernoulli(Distribution):
     link = "logit"
 
     def init_f0(self, y, w):
-        p = jnp.clip(jnp.sum(w * y) / jnp.maximum(jnp.sum(w), EPS),
+        p = jnp.clip(weighted_mean(y, w),
                      EPS, 1 - EPS)
         return jnp.log(p / (1 - p))
 
@@ -101,7 +109,7 @@ class Poisson(Distribution):
 
     def init_f0(self, y, w):
         return jnp.log(jnp.maximum(
-            jnp.sum(w * y) / jnp.maximum(jnp.sum(w), EPS), EPS))
+            weighted_mean(y, w), EPS))
 
     def link_fn(self, mu):
         return jnp.log(jnp.maximum(mu, EPS))
@@ -127,7 +135,7 @@ class Gamma(Distribution):
 
     def init_f0(self, y, w):
         return jnp.log(jnp.maximum(
-            jnp.sum(w * y) / jnp.maximum(jnp.sum(w), EPS), EPS))
+            weighted_mean(y, w), EPS))
 
     def link_fn(self, mu):
         return jnp.log(jnp.maximum(mu, EPS))
@@ -157,7 +165,7 @@ class Tweedie(Distribution):
 
     def init_f0(self, y, w):
         return jnp.log(jnp.maximum(
-            jnp.sum(w * y) / jnp.maximum(jnp.sum(w), EPS), EPS))
+            weighted_mean(y, w), EPS))
 
     def link_fn(self, mu):
         return jnp.log(jnp.maximum(mu, EPS))

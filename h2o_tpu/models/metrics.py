@@ -19,6 +19,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 _NBINS_AUC = 1024
 EPS = 1e-15
@@ -28,14 +29,16 @@ _LO_BITS = 5            # a bin is (hi, lo): hi = b >> 5, lo = b & 31
 
 def _score_histogram(b, wy, wn, nbins: int):
     """Weighted score histograms ``pos[k] = sum(wy[b == k])`` and ``neg``
-    likewise, by one two-level one-hot contraction a row block, not two
-    scatter-adds (a scatter-add of 5.25M rows into 1,024 slots is
-    serialised work on the chip: 46 ms a table, PERF.md §6 PR 39).
+    likewise, as one (nhi, 2 * nlo) table (``pos`` its first ``nlo``
+    columns, ``neg`` the rest, row-major), by one two-level one-hot
+    contraction a row block, not two scatter-adds (a scatter-add of 5.25M
+    rows into 1,024 slots is serialised work on the chip: 46 ms a table,
+    PERF.md §6).
 
     ``onehot(hi)`` (R, nhi) meets ``[onehot(lo) * wy, onehot(lo) * wn]``
-    (R, 2 * nlo) over the rows: (nhi, 2 * nlo) -> two (nbins,) tables.
-    The weight side stays float32 (HIGHEST; the one-hot side is exact in
-    any dtype), so integer weights give the scatter's tables bit for bit.
+    (R, 2 * nlo) over the rows.  The weight side stays float32 (HIGHEST;
+    the one-hot side is exact in any dtype), so integer weights give the
+    scatter's tables bit for bit.
     One contraction a block of ``_HIST_BLOCK`` rows: no whole-frame
     one-hot lands in HBM; the per-block partials are summed in float32."""
     nlo = 1 << _LO_BITS
@@ -67,9 +70,7 @@ def _score_histogram(b, wy, wn, nbins: int):
         # back all zero on the chip)
         acc = acc + part(*(jnp.pad(v[nblk * blk:], (0, blk - rem))
                            for v in (b, wy, wn)))
-    pos = acc[:, :nlo].reshape(-1)[:nbins]
-    neg = acc[:, nlo:].reshape(-1)[:nbins]
-    return pos, neg
+    return acc
 
 
 _SUM_BLOCK = 1024      # rows of one partial sum (one (8, 128) tile)
@@ -88,25 +89,54 @@ def _sum_rows(x):
     return jnp.sum(jax.lax.optimization_barrier(parts))
 
 
-@functools.partial(jax.jit, static_argnames=("nbins",))
+@functools.partial(jax.jit, static_argnames=("nbins", "mesh"))
 @jax.named_scope("h2o.score.metrics")
-def _binomial_kernel(p, y, w, valid, nbins: int = _NBINS_AUC):
-    """p: P(class 1); y: {0,1}; returns scalars + per-bin pos/neg counts."""
-    w = jnp.where(valid, w, 0.0)
-    y = jnp.where(valid, y, 0.0)
-    p = jnp.where(valid, p, 0.5)   # NaN-proof padded rows (0*NaN = NaN)
-    wsum = jnp.maximum(jnp.sum(w), EPS)
-    # where-form, not y*log(p)+(1-y)*log(1-p): p can round to exactly 0/1
-    # in f32 and 0*log(0) would poison the sum with NaN
-    logloss = _sum_rows(-w * jnp.where(y > 0.5,
-                                       jnp.log(jnp.maximum(p, EPS)),
-                                       jnp.log(jnp.maximum(1.0 - p, EPS))))
-    mse = _sum_rows(w * (y - p) ** 2)
-    b = jnp.clip((p * nbins).astype(jnp.int32), 0, nbins - 1)
-    pos, neg = _score_histogram(b, w * y, w * (1 - y), nbins)
-    ymean = jnp.sum(w * y) / wsum
-    return dict(logloss=logloss / wsum, mse=mse / wsum, pos=pos, neg=neg,
-                wsum=wsum, ymean=ymean)
+def _binomial_kernel(p, y, w, valid, *, mesh, nbins: int = _NBINS_AUC):
+    """p: P(class 1); y: {0,1}; returns scalars + per-bin pos/neg counts.
+
+    Computed where the rows live: each shard of ``mesh``'s data axis
+    scans its own rows (its score table and its partial sums), then one
+    ``hpsum`` of the (nhi, 2 * nlo) table and one of the four sums; no
+    row-length operand crosses between shards.  One device is the same
+    program over one shard.  The row count is a multiple of the shard
+    count (``binomial_kernel`` pads)."""
+    from h2o_tpu.core.cloud import cloud, hpsum, shard_map_compat
+    dp = cloud().data_pspec
+
+    @functools.partial(shard_map_compat, mesh=mesh,
+                       in_specs=(dp(),) * 4, out_specs=PartitionSpec(),
+                       check_vma=False)
+    def run(p, y, w, valid):
+        w = jnp.where(valid, w, 0.0)
+        y = jnp.where(valid, y, 0.0)
+        p = jnp.where(valid, p, 0.5)   # NaN-proof padded rows (0*NaN)
+        # where-form, not y*log(p)+(1-y)*log(1-p): p can round to
+        # exactly 0/1 in f32 and 0*log(0) would poison the sum with NaN
+        sums = jnp.stack([
+            _sum_rows(-w * jnp.where(y > 0.5,
+                                     jnp.log(jnp.maximum(p, EPS)),
+                                     jnp.log(jnp.maximum(1.0 - p, EPS)))),
+            _sum_rows(w * (y - p) ** 2), jnp.sum(w), jnp.sum(w * y)])
+        b = jnp.clip((p * nbins).astype(jnp.int32), 0, nbins - 1)
+        table = _score_histogram(b, w * y, w * (1 - y), nbins)
+        return hpsum(table, "score.hist"), hpsum(sums, "score.sums")
+
+    table, sums = run(p, y, w, valid)
+    nlo = 1 << _LO_BITS
+    pos = table[:, :nlo].reshape(-1)[:nbins]
+    neg = table[:, nlo:].reshape(-1)[:nbins]
+    wsum = jnp.maximum(sums[2], EPS)
+    return dict(logloss=sums[0] / wsum, mse=sums[1] / wsum, pos=pos,
+                neg=neg, wsum=wsum, ymean=sums[3] / wsum)
+
+
+def binomial_kernel(p, y, w, valid, nbins: int = _NBINS_AUC):
+    """``_binomial_kernel`` over the cloud's mesh, the rows padded at
+    ``valid`` False by ``pad_rows``."""
+    from h2o_tpu.core.cloud import cloud, pad_rows
+    p, y, w = (pad_rows(jnp.asarray(v)) for v in (p, y, w))
+    return _binomial_kernel(p, y, w, pad_rows(jnp.asarray(valid), False),
+                            nbins=nbins, mesh=cloud().mesh)
 
 
 def _auc_from_hist(pos: np.ndarray, neg: np.ndarray) -> Dict[str, float]:
@@ -330,7 +360,7 @@ def binomial_metrics(p1, y, w=None, valid=None,
             else jnp.ones(p1.shape, bool)
     valid = valid & ~jnp.isnan(y)
     w = jnp.ones_like(p1) if w is None else w
-    r = jax.tree.map(np.asarray, _binomial_kernel(p1, y, w, valid))
+    r = jax.tree.map(np.asarray, binomial_kernel(p1, y, w, valid))
     sweep = _auc_from_hist(r["pos"], r["neg"])
     data = dict(mse=float(r["mse"]), rmse=float(np.sqrt(r["mse"])),
                 logloss=float(r["logloss"]), nobs=float(r["wsum"]),

@@ -105,7 +105,10 @@ class DataInfo:
     def weights(self) -> jax.Array:
         if self.weights_name:
             return self.frame.vec(self.weights_name).data
-        return jnp.ones((self.frame.padded_rows,), jnp.float32)
+        # row-sharded as the frame's columns: each shard makes its own
+        # ones, no whole-frame vector on one device to move every call
+        from h2o_tpu.core.cloud import hbroadcast_rows
+        return hbroadcast_rows(1.0, self.frame.padded_rows)
 
     def offset(self) -> Optional[jax.Array]:
         return self.frame.vec(self.offset_name).data if self.offset_name \

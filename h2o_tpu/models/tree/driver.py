@@ -295,7 +295,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     because the random stream continues exactly, reproduces the
     uninterrupted forest bit-for-bit.
     """
-    from h2o_tpu.models.tree.jit_engine import (resolve_train_levers,
+    from h2o_tpu.models.tree.jit_engine import (program_signature,
+                                                resolve_train_levers,
                                                 route_plan, train_forest,
                                                 window_levels)
     from h2o_tpu.models.tree.shared_tree import (rng_key_from_np,
@@ -484,9 +485,15 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         with TimeLine.span("train", "block.launch", t0=prior_trees + off,
                            route_levels=route_levels,
                            route_select_levels=route_select_levels,
-                           window_levels=n_window):
-            tf = oom_ladder("tree.block", attempt, shrink=shrink,
-                            on_oom=on_oom)
+                           window_levels=n_window) as ev:
+            tf, ici = DispatchStats.program_ici(
+                ("tree.block", state["n"], donate_launch, no_donate,
+                 program_signature(train_kwargs)),
+                lambda: oom_ladder("tree.block", attempt, shrink=shrink,
+                                   on_oom=on_oom))
+            # a tree's collectives lie in the body of the scan over the
+            # block's trees: noted once, shipped once a tree
+            ev["ici_bytes"] = state["n"] * sum(ici.values())
             F, OOB = tf.f_final, tf.oob
             _start_host_pull(tf)
         TimeLine.record("dispatch", "tree_block_launch",

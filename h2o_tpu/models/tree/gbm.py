@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from h2o_tpu.core.cloud import hbroadcast_rows
 from h2o_tpu.core.diag import TimeLine
 from h2o_tpu.core.frame import Frame
 from h2o_tpu.models.distributions import get_distribution
@@ -237,7 +238,7 @@ class GBM(ModelBuilder):
         if ckpt is not None:
             f0 = jnp.asarray(co["f0"]) if dist_name == "multinomial" \
                 else jnp.asarray(co["f0"][:1])
-        F = jnp.broadcast_to(f0[None, :], (R, K)).astype(jnp.float32)
+        F = hbroadcast_rows(f0, R)
         offset = di.offset()
         if offset is not None:
             F = F + offset[:, None]

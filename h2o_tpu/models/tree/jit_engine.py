@@ -1236,6 +1236,23 @@ def train_forest(*args, sibling: Optional[bool] = None,
     return kernel_fallback("tree.block", run, pallas=hist_pallas)
 
 
+def program_signature(kwargs: Dict) -> tuple:
+    """A hashable stand-in for the key jit gives a block program of
+    ``train_forest(**kwargs)``: an array by its shape and dtype, any
+    other argument as it is (its ``repr`` where it cannot be hashed)."""
+    out = []
+    for k, v in sorted(kwargs.items()):
+        if hasattr(v, "shape") and hasattr(v, "dtype"):
+            v = ("array", tuple(v.shape), str(v.dtype))
+        else:
+            try:
+                hash(v)
+            except TypeError:
+                v = repr(v)
+        out.append((k, v))
+    return tuple(out)
+
+
 _TF_STATIC = ("dist_name", "K", "ntrees", "max_depth", "nbins",
               "k_cols", "newton", "sample_rate", "learn_rate",
               "learn_rate_annealing", "min_rows",

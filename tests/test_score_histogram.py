@@ -45,7 +45,7 @@ def _frame(rows, weights="unit", seed=0, invalid=0, edges=False):
 
 
 def _kernel_tables(p, y, w, valid):
-    r = mm._binomial_kernel(p, y, w, valid)
+    r = mm.binomial_kernel(p, y, w, valid)
     return np.asarray(r["pos"]), np.asarray(r["neg"])
 
 
@@ -88,8 +88,10 @@ def _metrics_equal(weights):
 
 
 def _no_scatter():
-    p = jnp.zeros((3 * BLK + 5,))
-    text = mm._binomial_kernel.lower(p, p, p, p > 0).as_text()
+    from h2o_tpu.core.cloud import cloud
+    p = jnp.zeros((3 * BLK + 8 * 5,))
+    text = mm._binomial_kernel.lower(p, p, p, p > 0,
+                                     mesh=cloud().mesh).as_text()
     assert "scatter" not in text
 
 

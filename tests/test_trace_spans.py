@@ -269,8 +269,12 @@ def test_scoring_and_binning_programs_name_their_scopes(cl):
         jnp.zeros((2, 1, H, B + 1), bool), jnp.zeros((2, 1, H)), depth=2)
     assert _scopes_in(values) == {"h2o.score.descent"}
     m = jnp.zeros((R, C), jnp.float32)
+    # the split points and the metric kernel reduce across shards through
+    # the named collectives, nothing else
     assert _scopes_in(st._quantile_split_points.lower(
-        m, jnp.int32(R), nbins=B)) == {"h2o.bin.quantile"}
+        m, jnp.int32(R), nbins=B, mesh=cl.mesh)) == {
+            "h2o.bin.quantile", "h2o.coll.quantile.count",
+            "h2o.coll.quantile.range", "h2o.coll.quantile.rank"}
     assert _scopes_in(st._col_min_max.lower(m, jnp.int32(R))) == \
         {"h2o.bin.quantile"}
     assert _scopes_in(st._bin_all.lower(
@@ -279,7 +283,9 @@ def test_scoring_and_binning_programs_name_their_scopes(cl):
     assert _scopes_in(driver._accum.lower(m, m)) == {"h2o.score.metrics"}
     p = jnp.zeros((R,))
     assert _scopes_in(metrics._binomial_kernel.lower(
-        p, p, p, p > 0)) == {"h2o.score.metrics"}
+        p, p, p, p > 0, mesh=cl.mesh)) == {
+            "h2o.score.metrics", "h2o.coll.score.hist",
+            "h2o.coll.score.sums"}
 
 
 # --------------------------------------------------------------- counters
