@@ -295,7 +295,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     because the random stream continues exactly, reproduces the
     uninterrupted forest bit-for-bit.
     """
-    from h2o_tpu.models.tree.jit_engine import (program_signature,
+    from h2o_tpu.models.tree.jit_engine import (hist_narrow_levels,
+                                                program_signature,
                                                 resolve_train_levers,
                                                 route_plan, train_forest,
                                                 window_levels)
@@ -438,10 +439,11 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
     launched = done
     no_donate = False       # latched by the OOM ladder: retries re-read F
     # a tree's routed levels, and those the select form routes; its
-    # window levels: the rules the engine applies to each level's static
-    # shape, on the host
+    # window levels and its levels of the narrow histogram form: the
+    # rules the engine applies to each level's static shape, on the host
     route_levels, route_select_levels = route_plan(train_kwargs)
     n_window = window_levels(train_kwargs)
+    n_narrow = hist_narrow_levels(train_kwargs)
 
     def _launch(off: int, n: int) -> Dict:
         nonlocal F, OOB, block, no_donate
@@ -485,7 +487,8 @@ def run_tree_driver(job, p: Dict, train_kwargs: Dict, F0, key,
         with TimeLine.span("train", "block.launch", t0=prior_trees + off,
                            route_levels=route_levels,
                            route_select_levels=route_select_levels,
-                           window_levels=n_window) as ev:
+                           window_levels=n_window,
+                           hist_narrow_levels=n_narrow) as ev:
             tf, ici = DispatchStats.program_ici(
                 ("tree.block", state["n"], donate_launch, no_donate,
                  program_signature(train_kwargs)),

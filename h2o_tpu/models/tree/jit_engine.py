@@ -71,7 +71,9 @@ from h2o_tpu.models.tree.shared_tree import find_splits, node_sq_err
 from h2o_tpu.ops import statpack
 from h2o_tpu.ops.binpack import pack_words, pick_bin
 from h2o_tpu.ops.histogram import histogram_build_traced as _shard_histogram
-from h2o_tpu.ops.histogram import histogram_window_traced, window_level
+from h2o_tpu.ops.histogram import (N_STATS, WINDOW_LEAVES, _pallas_eligible,
+                                   hist_form, histogram_window_traced,
+                                   window_level)
 
 EPS = 1e-10
 
@@ -343,6 +345,39 @@ def window_levels(kw: Dict) -> int:
     if int(kw.get("kleaves") or 0) <= 0:
         return 0
     return sum(window_level(L) for L in _level_widths(kw))
+
+
+def hist_narrow_levels(kw: Dict) -> int:
+    """The levels of one tree of ``train_forest(**kw)`` whose histogram
+    contracts in the narrow form (``histogram.hist_form``), from the
+    nodes each engine's level builds: the left children on a level
+    sibling subtraction completes, a window of nodes on a window level.
+    The quantized and bfloat16 tables, and a level the Pallas kernel
+    takes, have one form.  Counted on the host for the
+    ``train.block.launch`` span."""
+    if kw.get("bf16") or (kw.get("stats_dtype") or "f32") != "f32":
+        return 0
+    D, B = int(kw["max_depth"]), int(kw["nbins"])
+    C = int(kw["bins"].shape[1])
+    kleaves = int(kw.get("kleaves") or 0)
+    adaptive = bool(kw.get("adaptive"))
+    F = int(kw.get("fine_nbins") or B)
+    sib = bool(kw.get("sibling", True)) and not adaptive
+    widths = _level_widths(kw)
+    narrow = 0
+    for d, L in enumerate(widths):
+        if kleaves > 0 and window_level(L):
+            built = min(WINDOW_LEAVES, L)
+        else:
+            built = L // 2 if sib and d >= 1 and L == 2 * widths[d - 1] \
+                else L
+            Bd = max(B, F >> d) if adaptive else B
+            if kw.get("hist_pallas") and _pallas_eligible(
+                    C, Bd + 1, built, N_STATS, () if adaptive else None,
+                    allowed=True):
+                continue
+        narrow += hist_form(built * N_STATS) == "bf16x3"
+    return narrow
 
 
 def _pick(idx, table):

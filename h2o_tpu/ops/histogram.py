@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from h2o_tpu.core.cloud import cloud, hpsum, shard_map_compat
+from h2o_tpu.ops import statpack
 from h2o_tpu.ops.binpack import pack_words, unpack_words, widen_bins
 
 # stats slots
@@ -122,6 +123,34 @@ def onehot_width(B1: int) -> int:
     return B1 + (-B1 % 8)
 
 
+# The f32 contraction's two forms, by a static rule on its output width
+# (``hist_form``).  ``Precision.HIGHEST`` forms the products of the f32
+# statistics' three bfloat16 parts with the exact 0/1 one-hot in passes
+# of its own, and on a v5e a narrow level then costs what its one-hot
+# costs to stream, whatever its width.  The ``bf16x3`` form lays the
+# three parts side by side, one bfloat16 contraction of three times the
+# width with f32 accumulation: the same products in one pass.  One level
+# standalone on a v5e chip, ms HIGHEST / bf16x3, L*S 4 / 8 / 16 / 32 /
+# 64 / 128 / 256 (PERF.md section 6):
+#   5.25M x 28 x 256 (C*B1p 7,168):   116 / 67, 116 / 67, 117 / 68,
+#                                     119 / 88, 126 / 122, 197 / 202,
+#                                     349 / 371
+#   11.5M x 13 x 360 (C*B1p 4,680):   167 / 100, 168 / 100, 170 / 102,
+#                                     172 / 123, 179 / 183, 288 / 299,
+#                                     500 / 544
+#   5.25M x 28 x 1,025 adaptive:      1,488 / 1,375, 1,636 / 1,449,
+#                                     1,638 / 1,451 at 4 / 8 / 16
+# so the split form wins up to 32 output columns at every one-hot width
+# read, ties at 64 (within 3 %) and loses from 128 on.
+NARROW_LS_MAX = 32
+
+
+def hist_form(LS: int) -> str:
+    """The form of a (C*B1, L*S) f32 contraction with ``LS`` output
+    columns: ``bf16x3`` up to ``NARROW_LS_MAX``, ``highest`` past it."""
+    return "bf16x3" if LS <= NARROW_LS_MAX else "highest"
+
+
 def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
                 mm_dtype=jnp.float32,
                 scopes=("h2o.tree.hist.onehot", "h2o.tree.hist.contract")):
@@ -141,14 +170,24 @@ def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
                operands at the carrier itemsize, the (C*B1, L*S) table
                exact by the statpack qmax row bound
     mm_dtype:  matmul input dtype (f32 path only); bf16 is one MXU pass
-               where f32 (HIGHEST) is several, at the cost of ~3
-               mantissa digits on the per-row stats (the one-hot side
-               is exact either way).
+               where f32 is several, at the cost of ~3 mantissa digits
+               on the per-row stats (the one-hot side is exact either
+               way).
+
+    The f32 contraction takes one of two forms (``hist_form``): at
+    ``Precision.HIGHEST``, or, on a narrow level, as one bfloat16
+    contraction of the statistics' three exact bfloat16 parts
+    (``statpack.bf16_parts``) side by side, the three (C*B1, L*S)
+    results summed.  Both form every product exactly and accumulate in
+    f32; the table differs only in the order of its additions.
     """
     B1 = nbins + 1
     C = bins_blk.shape[1]
     S = stats_blk.shape[1]
+    LS = n_leaves * S
     quantized = jnp.issubdtype(stats_blk.dtype, jnp.integer)
+    split = (not quantized and mm_dtype == jnp.float32
+             and hist_form(LS) == "bf16x3")
     with jax.named_scope(scopes[0]):
         leafhot = (leaf_blk[:, None] == jnp.arange(n_leaves)[None, :])
         # zero stats of inactive rows BEFORE the product: padded rows carry
@@ -156,8 +195,14 @@ def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
         # quantized carrier has no NaN, but padded rows still must not
         # count; the weak 0 keeps the carrier dtype)
         stats_blk = jnp.where(leaf_blk[:, None] >= 0, stats_blk, 0)
-        a = (leafhot[:, :, None] * stats_blk[:, None, :]).reshape(
-            -1, n_leaves * S)                                 # (R, L*S)
+        if split:
+            parts = jnp.stack(statpack.bf16_parts(stats_blk),
+                              axis=1).astype(jnp.bfloat16)   # (R, 3, S)
+            a = jnp.where(leafhot[:, None, :, None],
+                          parts[:, :, None, :], 0).reshape(-1, 3 * LS)
+        else:
+            a = (leafhot[:, :, None] * stats_blk[:, None, :]).reshape(
+                -1, LS)                                       # (R, L*S)
         binhot = (bins_blk[:, :, None] ==
                   jnp.arange(B1)[None, None, :]).reshape(-1, C * B1)
         # (R, C*B1)
@@ -171,6 +216,12 @@ def _block_hist(bins_blk, leaf_blk, stats_blk, n_leaves: int, nbins: int,
                 binhot.astype(stats_blk.dtype), a,
                 dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)             # (C*B1, L*S)
+        if split:
+            o = jax.lax.dot_general(
+                binhot.astype(jnp.bfloat16), a,
+                dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).reshape(-1, 3, LS)
+            return o[:, 0] + (o[:, 1] + o[:, 2])              # (C*B1, L*S)
         # the one-hot side is exact in any dtype; HIGHEST keeps the f32
         # stats side f32 — a TPU's default precision rounds f32 operands
         # to bf16, which is what ``bf16`` asks for and f32 must not get

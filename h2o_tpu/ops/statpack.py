@@ -133,6 +133,23 @@ def dequant_table(table, inv_scale):
     return table.astype(jnp.float32) * inv_scale
 
 
+def bf16_parts(stats):
+    """f32 ``stats`` as three bfloat16 parts whose f32 sum is ``stats``
+    exactly: ``p1`` the value rounded to bfloat16's 8 significant bits,
+    ``p2`` the residual rounded so, ``p3`` what is left, which has at
+    most 8 significant bits of its own (exact down to 2**-103; below it
+    the last bits are subnormal, and XLA flushes them to zero).  A
+    product of a part with a 0/1 one-hot is exact in bfloat16 x bfloat16
+    -> f32, so one bfloat16 contraction of the three parts side by side
+    forms the products ``Precision.HIGHEST`` forms, in one pass.
+    Rounded by ``reduce_precision``, not by a convert round trip, which
+    XLA may fold away under excess precision."""
+    p1 = jax.lax.reduce_precision(stats, exponent_bits=8, mantissa_bits=7)
+    r = stats - p1
+    p2 = jax.lax.reduce_precision(r, exponent_bits=8, mantissa_bits=7)
+    return p1, p2, r - p2
+
+
 def widen_stats(q):
     """Sanctioned in-register widen of carrier stats to int32 (kernel
     bodies that need int32 operands before the dot; the convert fuses —
